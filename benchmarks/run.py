@@ -51,6 +51,10 @@ def main(argv=None) -> None:
     if args.adaptive:
         os.environ["REPRO_BENCH_ADAPTIVE"] = "1"
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         ablation_backfill,
         bench_batch_trials,

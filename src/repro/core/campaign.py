@@ -180,6 +180,28 @@ def _warm_plan_cache(keys: Sequence[Tuple[str, str, float, bool]]) -> None:
         _plans_for(*key)
 
 
+def _init_worker(keys: Sequence[Tuple[str, str, float, bool]]) -> None:
+    """Pool-worker initializer: pin the worker's JAX to the CPU, then warm
+    the plan cache.  A chip belongs to one process, and the parent may
+    hold it (an ``engine="batch"`` cell runs there); a worker that opened
+    the TPU backend would fail or hang.  Workers only run the numpy
+    engines — :func:`_runs_in_parent` keeps device specs out of the pool.
+    The environment is set before the worker imports JAX; a spawn child
+    may already have imported it with its re-imported ``__main__``, but
+    no backend is initialized yet, so the config update still holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+    _warm_plan_cache(keys)
+
+
+def _runs_in_parent(spec: TrialSpec) -> bool:
+    """Specs that run a device program: the batch engine, and the jitted
+    Terastal round.  They run in the parent, never in a pool worker."""
+    return spec.engine == "batch" or spec.round_kernel == "jax"
+
+
 #: test hook (tests/test_executor_crash.py): when set, :func:`run_trial`
 #: kills its process before simulating — "always" unconditionally, any
 #: other value is a sentinel path killed through exactly once (the first
@@ -396,8 +418,11 @@ class TrialExecutor:
     pool.  Semantics preserved from ``Campaign.run``:
 
     * fork start method when safe (workers inherit the parent's warm
-      offline-plan cache), spawn otherwise, with ``_warm_plan_cache`` as
-      the pool initializer primed with this campaign's cell keys;
+      offline-plan cache), spawn otherwise, with ``_init_worker`` as the
+      pool initializer: it pins the worker's JAX to the CPU and primes
+      the plan cache with this campaign's cell keys;
+    * specs that run a device program (:func:`_runs_in_parent`) run in
+      the parent, which is the one process that may hold the chip;
     * any pool-unavailability error (sandbox, no ``fork``, spawn without
       an importable ``__main__``) degrades to serial execution with a
       warning, never to a crash — results are identical either way
@@ -495,7 +520,7 @@ class TrialExecutor:
                 self._pool = concurrent.futures.ProcessPoolExecutor(
                     max_workers=self.max_workers,
                     mp_context=multiprocessing.get_context(method),
-                    initializer=_warm_plan_cache,
+                    initializer=_init_worker,
                     initargs=(self.cell_keys,),
                 )
             except _POOL_ERRORS as e:
@@ -506,6 +531,8 @@ class TrialExecutor:
 
     def submit(self, spec: TrialSpec):
         """Schedule one trial; returns a future-alike with ``result()``."""
+        if _runs_in_parent(spec):
+            return _ImmediateFuture(spec)
         pool = self._ensure_pool()
         if pool is not None:
             try:
@@ -581,8 +608,8 @@ class TrialExecutor:
     def map(self, specs: Sequence[TrialSpec], chunksize: int = 1) -> List[TrialResult]:
         """One-shot chunked map over a known grid (``Campaign.run``)."""
         specs = list(specs)
-        if any(s.engine == "batch" for s in specs):
-            # seed-grouped in-process path (plus pool for the rest)
+        if any(_runs_in_parent(s) for s in specs):
+            # in-process device path (plus pool for the rest)
             return self.run_batch(specs)
         pool = self._ensure_pool()
         while pool is not None:

@@ -37,6 +37,10 @@ How it stays bit-identical to the reference engine
   frozenset unions and ``ModelPlan.combo_retained`` calls the reference
   performs — CPython set iteration order and float accumulation order
   included — so the float sums are bit-equal, not just close.
+* **Arithmetic.**  Every float op goes through :mod:`repro.core.f64`:
+  JAX's float64 where it is IEEE binary64 (CPU, GPU), binary64 in
+  software on int64 bit patterns on the TPU, whose float64 is a pair of
+  float32s (:func:`f64.for_platform`).
 
 Speculation and its host-side validation
 ----------------------------------------
@@ -102,9 +106,8 @@ from repro.core.simulator import (
 )
 from repro.core.variants import ModelPlan
 
-# Pulls in jax and enables x64 process-wide (bit-parity requires f64).
-from repro.core import scheduler_jax
-from repro.core.scheduler_jax import jax, jnp
+from repro.core import f64, scheduler_jax
+from repro.core.scheduler_jax import jax, jnp, x64
 
 lax = jax.lax
 
@@ -152,7 +155,7 @@ class _Out(NamedTuple):
 
 
 def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
-    """Numpy-precompute the per-model tables; returns (tables, LP, NA)."""
+    """Precompute the per-model tables (numpy); returns (tables, LP, NA)."""
     from repro.core.accuracy import combo_retained_fraction
 
     M = len(plans)
@@ -179,12 +182,8 @@ def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
         for l, v in p.variants.items():
             hasv[m, l] = True
             factor[m, l] = combo_retained_fraction((v.loss,))
-    t = _Tables(
-        lat=jnp.asarray(lat), latv=jnp.asarray(latv), vdlr=jnp.asarray(vdlr),
-        rm=jnp.asarray(rm), minl=jnp.asarray(minl),
-        nl=jnp.asarray(nl), factor=jnp.asarray(factor),
-        hasv=jnp.asarray(hasv), theta=jnp.asarray(theta),
-    )
+    t = _Tables(lat=lat, latv=latv, vdlr=vdlr, rm=rm, minl=minl, nl=nl,
+                factor=factor, hasv=hasv, theta=theta)
     return t, LP, NA
 
 
@@ -192,6 +191,7 @@ def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
     jax.jit,
     static_argnames=(
         "kind", "mode", "use_budgets", "use_variants", "na", "lp", "faulted",
+        "soft",
     ),
 )
 def _run_trials(
@@ -201,13 +201,13 @@ def _run_trials(
     # fault lane (dummy minimal arrays when ``faulted=False``): the
     # pre-bound capability timeline — per-lane event stream plus the
     # time-indexed epoch planes (scheduler_jax.pack_fault_epochs)
-    fe_t, fe_acc, fe_code, fe_val, n_f,  # [B,NF+1],[B,NF],[B,NF],[B,NF],[B]
+    fe_t, fe_acc, fe_code, fe_val, fe_ratio, n_f,  # [B,NF+1],[B,NF]x4,[B]
     mult_ep,  # [B, NF+1, NA]
     vdlr_ep,  # [B, NF+1, M, LP+1]
     rm_ep,    # [B, NF+1, M, LP+2]
     minl_ep,  # [B, NF+1, M, LP]
     *, kind: str, mode: str, use_budgets: bool, use_variants: bool,
-    na: int, lp: int, faulted: bool = False,
+    na: int, lp: int, faulted: bool = False, soft: bool = False,
 ) -> _Out:
     """The whole-trial device program: vmap(lane while_loop) over seeds.
 
@@ -225,7 +225,15 @@ def _run_trials(
     deadline, so the window that avoids reuse-overflow is ~NR anyway,
     and the explicit two-phase rid tie-breaks it forces cost more than
     the width they save.)
+
+    Every float64 operation goes through ``F`` (:mod:`repro.core.f64`):
+    ``jnp`` float64 where the backend is IEEE, and with ``soft=True``
+    binary64 in software on the int64 bit patterns every float array
+    then holds (the TPU's float64 is not IEEE).
     """
+    F = f64.SOFT if soft else f64.NATIVE
+    INF, NINF, ZERO = F.const(_INF), F.const(-_INF), F.const(0.0)
+    EPS15 = F.const(1e-15)
     NA, LP = na, lp
     NR = arr_m.shape[-1]
     NF = fe_acc.shape[-1]
@@ -253,7 +261,7 @@ def _run_trials(
         ev_pend: object; evict_cnt: object; remap_cnt: object
 
     def one_lane(at, am, d_abs, d_eps12, ne,
-                 fe_t, fe_acc, fe_code, fe_val, nf,
+                 fe_t, fe_acc, fe_code, fe_val, fe_ratio, nf,
                  MULT_EP, VDLR_EP, RM_EP, MINL_EP):
         # State updates are ONE-HOT PREDICATED SELECTS, not scatters: a
         # single-row write becomes ``where(arange == idx, val, arr)`` with
@@ -283,18 +291,18 @@ def _run_trials(
                 # LayerVariantFeasible at push time (static while ready):
                 # empty-combo / singleton cases are exact; see the module
                 # docstring for the >= 3-variant ulp hazard.
-                vok = T.hasv[m, l] & (st.ret[r] * T.factor[m, l] >= T.theta[m])
-                latv_row = jnp.where(vok, T.latv[m, l], _INF)
+                vok = T.hasv[m, l] & F.ge(F.mul(st.ret[r], T.factor[m, l]), T.theta[m])
+                latv_row = jnp.where(vok, T.latv[m, l], INF)
             else:
-                latv_row = jnp.full((NA,), _INF)
+                latv_row = F.full((NA,), _INF)
             has_next = (l + 1) < T.nl[m]
             if use_budgets:
-                vdl = a + T.vdlr[m, l]
-                vdln = jnp.where(has_next, a + T.vdlr[m, l + 1], dr)
+                vdl = F.add(a, T.vdlr[m, l])
+                vdln = jnp.where(has_next, F.add(a, T.vdlr[m, l + 1]), dr)
             else:
-                vdl = dr - T.rm[m, l + 1]
-                vdln = jnp.where(has_next, dr - T.rm[m, l + 2], dr)
-            nm = jnp.where(has_next, T.minl[m, l + 1], 0.0)
+                vdl = F.sub(dr, T.rm[m, l + 1])
+                vdln = jnp.where(has_next, F.sub(dr, T.rm[m, l + 2]), dr)
+            nm = jnp.where(has_next, T.minl[m, l + 1], ZERO)
             rb = jnp.where(pred, r, NRi)
             hit = NRa == rb
             # the two [NR, NA] cache planes: one-hot select rewrites the
@@ -314,7 +322,7 @@ def _run_trials(
                 c_vdln=jnp.where(hit, vdln, st.c_vdln),
                 c_nm=jnp.where(hit, nm, st.c_nm),
                 c_rm=jnp.where(hit, T.rm[m, l], st.c_rm),
-                c_ek=jnp.where(hit, dr - T.rm[m, l + 1], st.c_ek),
+                c_ek=jnp.where(hit, F.sub(dr, T.rm[m, l + 1]), st.c_ek),
             )
 
         # -- scheduler kernels ----------------------------------------------
@@ -323,92 +331,104 @@ def _run_trials(
         # order, then stage-2 ascending k); the unrolled pick loops make
         # the emission buffer a compile-time structure instead of a device
         # array, so applying emissions needs no compaction scatters.
+        def col_adds(plane, tau):
+            """``[plane[:, k] + tau[k] for k]``.  Natively per column: a
+            static-k slice fuses into its elementwise consumers, so the
+            round never materializes an [NR, NA] f64 temporary.  In
+            software binary64 as ONE [NR, NA] add: each software add is a
+            large program, and NA copies of it multiply compile time."""
+            if soft:
+                full = F.add(plane, tau[None, :])
+                return [full[:, k] for k in range(NA)]
+            return [F.add(plane[:, k], tau[k]) for k in range(NA)]
+
         def kern_terastal(st: St, ready, idle0, now):
-            # Column-unrolled over the NA accelerators: a static-k slice
-            # fuses into its elementwise consumers, so the round never
-            # materializes an [NR, NA] f64 temporary (fo/fv/f0/ev live as
-            # per-column [NR] chains).  Same IEEE adds/compares — pairwise
-            # jnp.minimum and per-column adds are the exact ops the
-            # materialized form ran, in the same per-element order.
-            tau0 = jnp.maximum(st.busy, now)                 # [NA]
-            fo_c = [st.c_lat[:, k] + tau0[k] for k in range(NA)]
-            fv_c = [st.c_latv[:, k] + tau0[k] for k in range(NA)]
+            # Column-unrolled over the NA accelerators (``col_adds``):
+            # fo/fv/f0/ev are per-column [NR] chains.  Same IEEE adds/
+            # compares — pairwise minimums and per-column adds are the
+            # exact ops the materialized form ran, in the same order.
+            tau0 = F.maximum(st.busy, now)                   # [NA]
+            fo_c = col_adds(st.c_lat, tau0)
+            fv_c = col_adds(st.c_latv, tau0)
             fmin = fo_c[0]
             for k in range(1, NA):
-                fmin = jnp.minimum(fmin, fo_c[k])
-            keys = st.c_vdl - fmin        # stage-1 (slack, rid) sort key
-            d_eps = st.c_vdl + 1e-15
-            oko_c = [f <= d_eps for f in fo_c]
-            okv_c = [f <= d_eps for f in fv_c]   # +inf (no variant) fails
-            tau = tau0
+                fmin = F.minimum(fmin, fo_c[k])
+            keys = F.sub(st.c_vdl, fmin)  # stage-1 (slack, rid) sort key
+            d_eps = F.add(st.c_vdl, EPS15)
+            oko_c = [F.le(f, d_eps) for f in fo_c]
+            okv_c = [F.le(f, d_eps) for f in fv_c]  # +inf (no variant) fails
             idle = idle0
             alive = ready
             picks = []
             # stage 1: repeated (slack, rid)-argmin over feasible slots;
-            # argmin's first-occurrence rule == rid tie-break (slot == rid)
+            # argmin's first-occurrence rule == rid tie-break (slot == rid).
+            # Each accelerator takes at most one stage-1 pick, so stage 2's
+            # tau is tau0 plus one cost (c1) per picked accelerator.
+            c1 = F.full(NA, 0.0)
+            hit1 = jnp.zeros(NA, bool)
             for _ in range(NA):
                 feas_any = (oko_c[0] | okv_c[0]) & idle[0]
                 for k in range(1, NA):
                     feas_any = feas_any | ((oko_c[k] | okv_c[k]) & idle[k])
                 feas = alive & feas_any
-                mk = jnp.where(feas, keys, _INF)
-                i = jnp.argmin(mk).astype(I32)
-                valid = mk[i] < _INF
-                fo_i = st.c_lat[i] + tau0          # [NA], round-start tau
-                fv_i = st.c_latv[i] + tau0
-                vo = jnp.where(idle & (fo_i <= d_eps[i]), fo_i, _INF)
-                ko = jnp.argmin(vo).astype(I32)
-                any_o = vo[ko] < _INF     # original first (lines 4-10)
-                vv = jnp.where(idle & (fv_i <= d_eps[i]), fv_i, _INF)
-                kv = jnp.argmin(vv).astype(I32)
+                mk = jnp.where(feas, keys, INF)
+                i = F.argmin(mk).astype(I32)
+                valid = F.lt(mk[i], INF)
+                fo_i = jnp.stack([f[i] for f in fo_c])  # [NA], round-start tau
+                fv_i = jnp.stack([f[i] for f in fv_c])
+                vo = jnp.where(idle & F.le(fo_i, d_eps[i]), fo_i, INF)
+                ko = F.argmin(vo).astype(I32)
+                any_o = F.lt(vo[ko], INF)  # original first (lines 4-10)
+                vv = jnp.where(idle & F.le(fv_i, d_eps[i]), fv_i, INF)
+                kv = F.argmin(vv).astype(I32)
                 use_var = ~any_o
                 k_sel = jnp.where(any_o, ko, kv)
                 c = jnp.where(use_var, st.c_latv[i, k_sel], st.c_lat[i, k_sel])
                 picks.append((valid, i, k_sel, use_var, c))
                 hitk = (NAa == k_sel) & valid
-                tau = jnp.where(hitk, tau + c, tau)
+                c1 = jnp.where(hitk, c, c1)
+                hit1 = hit1 | hitk
                 idle = idle & ~hitk
                 alive = alive & ~((NRa == i) & valid)
-            # stage 2: backfill remaining idle accelerators, ascending k
+            tau = jnp.where(hit1, F.add(tau0, c1), tau0)
+            # stage 2: backfill remaining idle accelerators, ascending k.
+            # Original and variant rows are stacked ([2, NR]: 0 original,
+            # 1 variant) so each chain is one float op, not two.
             for k in range(NA):
-                f0 = st.c_lat[:, 0] + tau[0]       # s* at CURRENT tau
+                fo_k = col_adds(st.c_lat, tau)  # s* at CURRENT tau
+                f0 = fo_k[0]
                 for kk in range(1, NA):
-                    f0 = jnp.minimum(f0, st.c_lat[:, kk] + tau[kk])
-                s_star = st.c_vdl - f0
-                tk = tau[k]
-                fino = st.c_lat[:, k] + tk
-                t = ((st.c_vdln - fino) - st.c_nm) - s_star  # Eq. 8-9
-                if mode == "ef":
-                    okm = (fino <= f0 + 1e-15) & alive
-                else:
-                    okm = alive
-                do = jnp.where(okm, t, -_INF)
+                    f0 = F.minimum(f0, fo_k[kk])
+                s_star = F.sub(st.c_vdl, f0)
                 cv = st.c_latv[:, k]
-                finv = cv + tk
-                t2 = ((st.c_vdln - finv) - st.c_nm) - s_star
                 if mode == "ef":
-                    ev = st.c_latv[:, 0] + tau[0]
+                    fv_k = col_adds(st.c_latv, tau)
+                    ev = fv_k[0]
                     for kk in range(1, NA):
-                        ev = jnp.minimum(ev, st.c_latv[:, kk] + tau[kk])
-                    ok2 = (finv <= ev + 1e-15) & jnp.isfinite(cv)
+                        ev = F.minimum(ev, fv_k[kk])
+                    fin = jnp.stack([fo_k[k], fv_k[k]])
+                    # earliest-finish guards
+                    ok = F.le(fin, F.add(jnp.stack([f0, ev]), EPS15))
+                    ok = ok & jnp.stack([alive, F.isfinite(cv)])
                 else:
-                    ok2 = jnp.isfinite(cv)
-                ok2 = ok2 & alive
-                dv = jnp.where(ok2, t2, -_INF)
-                mo = jnp.max(do)
-                mv = jnp.max(dv)
-                orig_wins = mo >= mv     # (delta, -use_var) strictly-greater
+                    fin = jnp.stack([fo_k[k], F.add(cv, tau[k])])
+                    ok = jnp.stack([jnp.ones_like(alive), F.isfinite(cv)])
+                t = F.sub(F.sub(F.sub(st.c_vdln, fin), st.c_nm), s_star)  # Eq. 8-9
+                dd = jnp.where(ok & alive, t, NINF)
+                do, dv = dd[0], dd[1]
+                mo, mv = F.max(dd, axis=1)
+                orig_wins = F.ge(mo, mv)  # (delta, -use_var) strictly-greater
                 best = jnp.where(orig_wins, mo, mv)
-                valid = idle[k] & (best > -_INF)
+                valid = idle[k] & F.gt(best, NINF)
                 if mode == "positive":
-                    valid = valid & (best > 0.0)
+                    valid = valid & F.gt(best, ZERO)
                 d_sel = jnp.where(orig_wins, do, dv)
-                tb = jnp.where(d_sel == best, keys, _INF)
-                i = jnp.argmin(tb).astype(I32)  # earliest in stage-1 order
+                tb = jnp.where(F.eq(d_sel, best), keys, INF)
+                i = F.argmin(tb).astype(I32)  # earliest in stage-1 order
                 use_var = ~orig_wins
                 c = jnp.where(use_var, st.c_latv[i, k], st.c_lat[i, k])
                 picks.append((valid, i, jnp.asarray(k, I32), use_var, c))
-                tau = jnp.where((NAa == k) & valid, tau + c, tau)
+                tau = jnp.where((NAa == k) & valid, F.add(tau, c), tau)
                 alive = alive & ~((NRa == i) & valid)
             return picks
 
@@ -418,22 +438,22 @@ def _run_trials(
             elif kind == "edf":
                 key = st.c_ek                       # (edf deadline, rid)
             else:  # dream
-                key = (d_abs - now) - st.c_rm       # (slack, rid)
-            tau0 = jnp.maximum(st.busy, now)        # round-start, not updated
+                key = F.sub(F.sub(d_abs, now), st.c_rm)  # (slack, rid)
+            tau0 = F.maximum(st.busy, now)          # round-start, not updated
             idle = idle0
             alive = ready
             fK = jnp.asarray(False)
             picks = []
             for _ in range(NA):
-                mk = jnp.where(alive, key, _INF)
-                i = jnp.argmin(mk).astype(I32)
-                ok_i = mk[i] < _INF
+                mk = jnp.where(alive, key, INF)
+                i = F.argmin(mk).astype(I32)
+                ok_i = F.lt(mk[i], INF)
                 if kind == "dream":
-                    vals = jnp.where(idle, tau0 + st.c_lat[i], _INF)
+                    vals = jnp.where(idle, F.add(tau0, st.c_lat[i]), INF)
                 else:   # fcfs/edf: lowest latency, first-min ascending k
-                    vals = jnp.where(idle, st.c_lat[i], _INF)
-                k = jnp.argmin(vals).astype(I32)
-                valid = ok_i & (vals[k] < _INF)
+                    vals = jnp.where(idle, st.c_lat[i], INF)
+                k = F.argmin(vals).astype(I32)
+                valid = ok_i & F.lt(vals[k], INF)
                 c = st.c_lat[i, k]
                 picks.append((valid, i, k, fK, c))
                 idle = idle & ~((NAa == k) & valid)
@@ -446,7 +466,7 @@ def _run_trials(
         def cond(st: St):
             active = (st.ai < ne) | jnp.any(st.run_req >= 0)
             if faulted:
-                active = active | (st.fi < nf) | jnp.any(st.gh_t < _INF)
+                active = active | (st.fi < nf) | jnp.any(F.lt(st.gh_t, INF))
             return active & (st.it < max_it)
 
         def body(st: St):
@@ -458,22 +478,22 @@ def _run_trials(
             # counters, then dynamic finish counters), and ghost-vs-finish
             # ties break on the stored finish counters.
             arr_next = at[st.ai]
-            ft_min = jnp.min(st.fin_t)
+            ft_min = F.min(st.fin_t)
             k_f = jnp.argmin(
-                jnp.where(st.fin_t == ft_min, st.fin_cnt, IMAXi)
+                jnp.where(F.eq(st.fin_t, ft_min), st.fin_cnt, IMAXi)
             ).astype(I32)
             if faulted:
                 f_next = fe_t[st.fi]
-                gh_min = jnp.min(st.gh_t)
-                oth = jnp.minimum(ft_min, gh_min)
-                is_arr = arr_next <= jnp.minimum(f_next, oth)
-                is_fault = (~is_arr) & (f_next <= oth)
+                gh_min = F.min(st.gh_t)
+                oth = F.minimum(ft_min, gh_min)
+                is_arr = F.le(arr_next, F.minimum(f_next, oth))
+                is_fault = (~is_arr) & F.le(f_next, oth)
                 g_i = jnp.argmin(
-                    jnp.where(st.gh_t == gh_min, st.gh_cnt, IMAXi)
+                    jnp.where(F.eq(st.gh_t, gh_min), st.gh_cnt, IMAXi)
                 ).astype(I32)
                 is_ghost = (~is_arr) & (~is_fault) & (
-                    (gh_min < ft_min)
-                    | ((gh_min == ft_min) & (st.gh_cnt[g_i] < st.fin_cnt[k_f]))
+                    F.lt(gh_min, ft_min)
+                    | (F.eq(gh_min, ft_min) & (st.gh_cnt[g_i] < st.fin_cnt[k_f]))
                 )
                 is_fin = (~is_arr) & (~is_fault) & (~is_ghost)
                 now = jnp.where(
@@ -485,11 +505,11 @@ def _run_trials(
                 # still falls through to the round logic below
                 st = st._replace(
                     gh_t=jnp.where(
-                        NFa == jnp.where(is_ghost, g_i, NFi), _INF, st.gh_t
+                        NFa == jnp.where(is_ghost, g_i, NFi), INF, st.gh_t
                     )
                 )
             else:
-                is_arr = arr_next <= ft_min
+                is_arr = F.le(arr_next, ft_min)
                 is_fin = ~is_arr
                 now = jnp.where(is_arr, arr_next, ft_min)
 
@@ -507,11 +527,11 @@ def _run_trials(
             hit_d = NRa == jnp.where(done, r, NRi)
             st = st._replace(
                 ai=st.ai + is_arr.astype(I32),
-                fin_t=jnp.where(hit_f, _INF, st.fin_t),
+                fin_t=jnp.where(hit_f, INF, st.fin_t),
                 run_req=jnp.where(hit_f, -1, st.run_req),
                 layer=jnp.where(hit_r, l_new, st.layer),
                 state=jnp.where(hit_r, jnp.where(done, 3, 1), st.state),
-                missed=jnp.where(hit_d, now > d_eps12[r], st.missed),
+                missed=jnp.where(hit_d, F.gt(now, d_eps12[r]), st.missed),
                 done_seq=jnp.where(hit_d, st.done_ctr, st.done_seq),
                 done_ctr=st.done_ctr + done.astype(I32),
             )
@@ -523,6 +543,7 @@ def _run_trials(
                 fk = fe_acc[fi_c]
                 code = fe_code[fi_c]
                 val = fe_val[fi_c]
+                ratio = fe_ratio[fi_c]  # val / old, divided on the host
                 is_down = is_fault & (code == 0)
                 is_up = is_fault & (code == 1)
                 is_scale = is_fault & (code == 2)
@@ -546,21 +567,21 @@ def _run_trials(
                 )
                 # evict_busy_adjust replicated op-for-op in jnp
                 t0 = st.disp_t0[fk]
-                new_w = now - t0
-                new_h = jnp.minimum(new_w, jnp.maximum(0.0, duration - t0))
-                dw = new_w - st.disp_w[fk]
-                dh = new_h - st.disp_h[fk]
+                new_w = F.sub(now, t0)
+                new_h = F.minimum(new_w, F.maximum(ZERO, F.sub(duration, t0)))
+                dw = F.sub(new_w, st.disp_w[fk])
+                dh = F.sub(new_h, st.disp_h[fk])
                 hit_e = NAa == jnp.where(ev, fk, NAi)
                 # scale with an in-flight layer: re-time the finish by
                 # new_scale / old_scale (retime_busy_adjust in jnp)
                 old = st.fscale[fk]
-                changed = is_scale & has_run & (val != old)
+                changed = is_scale & has_run & F.ne(val, old)
                 fin_old = st.busy[fk]
-                fin_new = now + (fin_old - now) * (val / old)
-                nw2 = fin_new - t0
-                nh2 = jnp.minimum(nw2, jnp.maximum(0.0, duration - t0))
-                dw2 = nw2 - st.disp_w[fk]
-                dh2 = nh2 - st.disp_h[fk]
+                fin_new = F.add(now, F.mul(F.sub(fin_old, now), ratio))
+                nw2 = F.sub(fin_new, t0)
+                nh2 = F.minimum(nw2, F.maximum(ZERO, F.sub(duration, t0)))
+                dw2 = F.sub(nw2, st.disp_w[fk])
+                dh2 = F.sub(nh2, st.disp_h[fk])
                 hit_s = NAa == jnp.where(changed, fk, NAi)
                 # both eviction and re-time orphan the old finish event:
                 # push it onto the ghost list (the reference leaves it in
@@ -574,20 +595,20 @@ def _run_trials(
                     gh_cnt=jnp.where(gh_hit, st.fin_cnt[fk], st.gh_cnt),
                     gh_n=st.gh_n + ghost.astype(I32),
                     busy=jnp.where(
-                        hit_dn, _INF,
+                        hit_dn, INF,
                         jnp.where(hit_up, now,
                                   jnp.where(hit_s, fin_new, st.busy)),
                     ),
                     busy_t=jnp.where(
-                        hit_e, st.busy_t + dw,
-                        jnp.where(hit_s, st.busy_t + dw2, st.busy_t),
+                        hit_e, F.add(st.busy_t, dw),
+                        jnp.where(hit_s, F.add(st.busy_t, dw2), st.busy_t),
                     ),
                     busy_h=jnp.where(
-                        hit_e, st.busy_h + dh,
-                        jnp.where(hit_s, st.busy_h + dh2, st.busy_h),
+                        hit_e, F.add(st.busy_h, dh),
+                        jnp.where(hit_s, F.add(st.busy_h, dh2), st.busy_h),
                     ),
                     fin_t=jnp.where(
-                        hit_dn, _INF, jnp.where(hit_s, fin_new, st.fin_t)
+                        hit_dn, INF, jnp.where(hit_s, fin_new, st.fin_t)
                     ),
                     fin_cnt=jnp.where(hit_s, st.cnt, st.fin_cnt),
                     run_req=jnp.where(hit_dn, -1, st.run_req),
@@ -610,12 +631,12 @@ def _run_trials(
             # against the just-popped now; empty heap -> +inf -> round runs).
             # A suppressed round folds into the masks below (ready empty ->
             # the kernel emits nothing) instead of a whole-carry select.
-            t_next = jnp.minimum(at[st.ai], jnp.min(st.fin_t))
+            t_next = F.minimum(at[st.ai], F.min(st.fin_t))
             if faulted:
-                t_next = jnp.minimum(
-                    t_next, jnp.minimum(fe_t[st.fi], jnp.min(st.gh_t))
+                t_next = F.minimum(
+                    t_next, F.minimum(fe_t[st.fi], F.min(st.gh_t))
                 )
-            do_round = ~(jnp.abs(t_next - now) < 1e-15)
+            do_round = ~F.lt(F.abs(F.sub(t_next, now)), EPS15)
 
             st = st._replace(rounds=st.rounds + do_round.astype(I32))
             if faulted:
@@ -636,73 +657,71 @@ def _run_trials(
                 LP1i = jnp.asarray(LP + 1, I32)
                 has_nx = (l_all + 1) < T.nl[m_all]
                 if use_budgets:
-                    vdl_v = at[:NR] + vdlr_f[m_all, jnp.minimum(l_all, LPi)]
+                    vdl_v = F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all, LPi)])
                     vdln_v = jnp.where(
                         has_nx,
-                        at[:NR] + vdlr_f[m_all, jnp.minimum(l_all + 1, LPi)],
+                        F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all + 1, LPi)]),
                         d_abs,
                     )
                 else:
-                    vdl_v = d_abs - rm_f[m_all, jnp.minimum(l_all + 1, LP1i)]
+                    vdl_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
                     vdln_v = jnp.where(
                         has_nx,
-                        d_abs - rm_f[m_all, jnp.minimum(l_all + 2, LP1i)],
+                        F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 2, LP1i)]),
                         d_abs,
                     )
                 nm_v = jnp.where(
                     has_nx,
                     minl_f[m_all, jnp.minimum(l_all + 1, LPi - 1)],
-                    0.0,
+                    ZERO,
                 )
                 rm_v = rm_f[m_all, jnp.minimum(l_all, LP1i)]
-                ek_v = d_abs - rm_f[m_all, jnp.minimum(l_all + 1, LP1i)]
+                ek_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
                 stk = st._replace(
-                    c_lat=st.c_lat * mult[None, :],
-                    c_latv=st.c_latv * mult[None, :],
+                    c_lat=F.mul(st.c_lat, mult[None, :]),
+                    c_latv=F.mul(st.c_latv, mult[None, :]),
                     c_vdl=vdl_v, c_vdln=vdln_v, c_nm=nm_v,
                     c_rm=rm_v, c_ek=ek_v,
                 )
             else:
                 stk = st
             ready0 = (st.state == 1) & do_round
-            dropm = ready0 & ((now + stk.c_rm) > d_eps12)  # early-drop
+            dropm = ready0 & F.gt(F.add(now, stk.c_rm), d_eps12)  # early-drop
             st = st._replace(
                 state=jnp.where(dropm, 4, st.state),
                 missed=st.missed | dropm,
             )
             ready = ready0 & ~dropm
-            idle = st.busy <= now + 1e-15
+            idle = F.le(st.busy, F.add(now, EPS15))
             picks = kern(stk, ready, idle, now)
 
             # apply emissions: chained one-hot selects per pick.  Finish
             # counters are cnt + (# valid picks before this one) — the
-            # compacted emission index, tracked as traced scalars.
+            # compacted emission index, tracked as traced scalars.  An
+            # accelerator takes at most one pick per round, so the float
+            # updates gather each one's cost ``c_acc`` and run one [NA]
+            # add each: the same single add per accelerator as per pick.
             state_n, run_req = st.state, st.run_req
             fin_t, fin_cnt = st.fin_t, st.fin_cnt
             busy, busy_t, busy_h = st.busy, st.busy_t, st.busy_h
             disp_t0, disp_w, disp_h = st.disp_t0, st.disp_w, st.disp_h
             run_uv, run_prev = st.run_uv, st.run_prev_ret
-            rem = duration - now
-            rem = jnp.where(rem > 0.0, rem, 0.0)
+            rem = F.sub(duration, now)
+            rem = jnp.where(F.gt(rem, ZERO), rem, ZERO)
             n_e = jnp.asarray(0, I32)
+            c_acc = F.full(NA, 0.0)
+            hit = jnp.zeros(NA, bool)
             rs, uvs, vas, vls = [], [], [], []
             for valid, i, k, uv, c in picks:
-                fin = now + c
-                hc = jnp.where(c <= rem, c, rem)
                 hit_a = (NAa == k) & valid
+                hit = hit | hit_a
+                c_acc = jnp.where(hit_a, c, c_acc)
                 state_n = jnp.where((NRa == i) & valid, 2, state_n)
                 run_req = jnp.where(hit_a, i, run_req)
-                fin_t = jnp.where(hit_a, fin, fin_t)
                 fin_cnt = jnp.where(hit_a, st.cnt + n_e, fin_cnt)
-                busy = jnp.where(hit_a, fin, busy)
-                busy_t = jnp.where(hit_a, busy_t + c, busy_t)
-                busy_h = jnp.where(hit_a, busy_h + hc, busy_h)
                 if faulted:
                     # dispatch bookkeeping eviction/re-timing must undo;
                     # run_prev snapshots the pre-apply retained product
-                    disp_t0 = jnp.where(hit_a, now, disp_t0)
-                    disp_w = jnp.where(hit_a, c, disp_w)
-                    disp_h = jnp.where(hit_a, hc, disp_h)
                     run_uv = jnp.where(hit_a, uv, run_uv)
                     run_prev = jnp.where(hit_a, st.ret[i], run_prev)
                 n_e = n_e + valid.astype(I32)
@@ -710,6 +729,16 @@ def _run_trials(
                 uvs.append(uv)
                 vas.append(valid & uv)
                 vls.append(valid)
+            fin = F.add(now, c_acc)
+            hc = jnp.where(F.le(c_acc, rem), c_acc, rem)
+            fin_t = jnp.where(hit, fin, fin_t)
+            busy = jnp.where(hit, fin, busy)
+            busy_t = jnp.where(hit, F.add(busy_t, c_acc), busy_t)
+            busy_h = jnp.where(hit, F.add(busy_h, hc), busy_h)
+            if faulted:
+                disp_t0 = jnp.where(hit, now, disp_t0)
+                disp_w = jnp.where(hit, c_acc, disp_w)
+                disp_h = jnp.where(hit, hc, disp_h)
             # variant bookkeeping: a picked row is unique per round, so the
             # pre-round app_cnt/layer reads are the scatter-time values; the
             # [NR, LP] sequence table keeps a true (vector) scatter
@@ -724,8 +753,8 @@ def _run_trials(
                 app_seq=st.app_seq.at[rv, l_vec].set(
                     st.app_cnt[r_vec], mode="drop"),
                 app_cnt=st.app_cnt.at[rv].add(1, mode="drop"),
-                ret=st.ret.at[rv].multiply(
-                    T.factor[am[r_vec], l_vec], mode="drop"),
+                ret=st.ret.at[rv].set(
+                    F.mul(st.ret[r_vec], T.factor[am[r_vec], l_vec]), mode="drop"),
                 cnt=st.cnt + n_e,
             )
             if faulted:
@@ -746,31 +775,35 @@ def _run_trials(
             return st
 
         z = jnp.zeros
+
+        def fz(shape):
+            return F.full(shape, 0.0)
+
         st0 = St(
             ai=jnp.asarray(0, I32), it=jnp.asarray(0, I32),
             cnt=jnp.asarray(0, I32), rounds=jnp.asarray(0, I32),
             done_ctr=jnp.asarray(0, I32),
             state=z(NR, I32), layer=z(NR, I32),
-            c_lat=jnp.full((NR, NA), _INF), c_latv=jnp.full((NR, NA), _INF),
-            c_vdl=z(NR), c_vdln=z(NR), c_nm=z(NR),
-            c_rm=jnp.full(NR, _INF), c_ek=z(NR),
-            ret=jnp.ones(NR), app_seq=jnp.full((NR, LP), -1, I32),
+            c_lat=F.full((NR, NA), _INF), c_latv=F.full((NR, NA), _INF),
+            c_vdl=fz(NR), c_vdln=fz(NR), c_nm=fz(NR),
+            c_rm=F.full(NR, _INF), c_ek=fz(NR),
+            ret=F.full(NR, 1.0), app_seq=jnp.full((NR, LP), -1, I32),
             app_cnt=z(NR, I32),
             missed=z(NR, bool), done_seq=jnp.full(NR, -1, I32),
-            busy=z(NA), busy_t=z(NA), busy_h=z(NA),
-            fin_t=jnp.full(NA, _INF), fin_cnt=z(NA, I32),
+            busy=fz(NA), busy_t=fz(NA), busy_h=fz(NA),
+            fin_t=F.full(NA, _INF), fin_cnt=z(NA, I32),
             run_req=jnp.full(NA, -1, I32),
-            fi=jnp.asarray(0, I32), fscale=jnp.ones(NA),
-            gh_t=jnp.full(NF, _INF), gh_cnt=z(NF, I32),
+            fi=jnp.asarray(0, I32), fscale=F.full(NA, 1.0),
+            gh_t=F.full(NF, _INF), gh_cnt=z(NF, I32),
             gh_n=jnp.asarray(0, I32),
-            disp_t0=z(NA), disp_w=z(NA), disp_h=z(NA),
-            run_uv=z(NA, bool), run_prev_ret=jnp.ones(NA),
+            disp_t0=fz(NA), disp_w=fz(NA), disp_h=fz(NA),
+            run_uv=z(NA, bool), run_prev_ret=F.full(NA, 1.0),
             ev_pend=z(NR, bool), evict_cnt=z(NR, I32), remap_cnt=z(NR, I32),
         )
         st = lax.while_loop(cond, body, st0)
         act = (st.ai < ne) | jnp.any(st.run_req >= 0)
         if faulted:
-            act = act | (st.fi < nf) | jnp.any(st.gh_t < _INF)
+            act = act | (st.fi < nf) | jnp.any(F.lt(st.gh_t, INF))
         return _Out(
             state=st.state, missed=st.missed, app_seq=st.app_seq,
             app_cnt=st.app_cnt, done_seq=st.done_seq,
@@ -781,7 +814,7 @@ def _run_trials(
 
     return jax.vmap(one_lane)(
         arr_t, arr_m, dl, dl12, n_ev,
-        fe_t, fe_acc, fe_code, fe_val, n_f,
+        fe_t, fe_acc, fe_code, fe_val, fe_ratio, n_f,
         mult_ep, vdlr_ep, rm_ep, minl_ep,
     )
 
@@ -857,7 +890,16 @@ def _validate(
             )
 
 
-def simulate_batch(
+class _Staged(NamedTuple):
+    """One cell's seed batch, staged for :func:`_run_trials`."""
+
+    args: tuple      # positional arguments (host arrays and scalars)
+    static: dict     # static keyword arguments (the scheduler config)
+    events: list     # per-seed ``(times, models)`` release streams
+    n_spans: list    # per-seed faulted-window counts
+
+
+def stage_batch(
     plans: Sequence[ModelPlan],
     tasks: Sequence[TaskSpec],
     duration: float,
@@ -867,17 +909,14 @@ def simulate_batch(
     budget_policy=None,
     admission=None,
     faults=None,
-) -> List[SimResult]:
-    """Run B = ``len(seeds)`` trials of one cell as ONE device program.
+) -> _Staged:
+    """Validate a cell and stage its B seeds' inputs on the host.
 
-    Same contract as ``simulate()`` for every supported axis — each
-    returned :class:`SimResult` is fingerprint-identical to
-    ``simulate(..., seed=s, engine="soa")`` (pinned by
-    tests/test_engine_batch.py).  Unsupported axes raise
-    :class:`BatchUnsupportedError` (see :func:`_validate`); an
-    undrained lane (the speculation bound failed — an engine bug, not a
-    workload property) raises ``RuntimeError``.
-    """
+    ``_run_trials(*staged.args, **staged.static)`` then runs the batch;
+    the arrays are numpy and become 64-bit device arrays when the jit
+    is called under :func:`scheduler_jax.x64`.  The binary64 follows the
+    platform (:func:`f64.for_platform`); under the software one, float
+    arrays are staged as their int64 bit patterns."""
     from repro.core.admission import make_admission_policy
     from repro.core.budget_online import make_budget_policy
     from repro.core.faults import make_fault_model
@@ -902,6 +941,8 @@ def simulate_batch(
                 DreamScheduler: "dream"}[kind]
         cfg = dict(kind=name, mode="", use_budgets=False, use_variants=False)
 
+    F = f64.for_platform()
+    soft = F is f64.SOFT
     tables, LP, NA = _build_tables(plans)
     deadline_by_model = np.array([p.deadline for p in plans])
     events = batch_release_events(tasks, duration, seeds, processes)
@@ -932,29 +973,68 @@ def simulate_batch(
             "fe_acc": np.zeros((b_pad, 1), np.int32),
             "fe_code": np.zeros((b_pad, 1), np.int32),
             "fe_val": np.ones((b_pad, 1)),
+            "fe_ratio": np.ones((b_pad, 1)),
             "n_f": np.zeros(b_pad, np.int32),
             "mult_ep": np.ones((b_pad, 1, NA)),
             "vdlr_ep": np.zeros((b_pad, 1, 1, 1)),
             "rm_ep": np.zeros((b_pad, 1, 1, 1)),
             "minl_ep": np.zeros((b_pad, 1, 1, 1)),
         }
-
-    out: _Out = _run_trials(
+    fl = F.to_device
+    tables = tables._replace(**{
+        k: fl(v) for k, v in tables._asdict().items() if v.dtype == np.float64})
+    args = (
         tables,
-        jnp.asarray(buf["arr_t"]), jnp.asarray(buf["arr_m"]),
-        jnp.asarray(buf["dl"]), jnp.asarray(buf["dl12"]),
-        jnp.asarray(buf["n_ev"]),
-        duration, np.int32(max_it),
-        jnp.asarray(fbuf["fe_t"]), jnp.asarray(fbuf["fe_acc"]),
-        jnp.asarray(fbuf["fe_code"]), jnp.asarray(fbuf["fe_val"]),
-        jnp.asarray(fbuf["n_f"]),
-        jnp.asarray(fbuf["mult_ep"]), jnp.asarray(fbuf["vdlr_ep"]),
-        jnp.asarray(fbuf["rm_ep"]), jnp.asarray(fbuf["minl_ep"]),
-        na=NA, lp=LP, faulted=faulted, **cfg,
+        fl(buf["arr_t"]), buf["arr_m"], fl(buf["dl"]), fl(buf["dl12"]),
+        buf["n_ev"],
+        fl(np.float64(duration)), np.int32(max_it),
+        fl(fbuf["fe_t"]), fbuf["fe_acc"], fbuf["fe_code"], fl(fbuf["fe_val"]),
+        fl(fbuf["fe_ratio"]), fbuf["n_f"],
+        fl(fbuf["mult_ep"]), fl(fbuf["vdlr_ep"]), fl(fbuf["rm_ep"]),
+        fl(fbuf["minl_ep"]),
     )
-    out = jax.tree_util.tree_map(np.asarray, out)  # ONE host sync
+    return _Staged(args, dict(na=NA, lp=LP, faulted=faulted, soft=soft, **cfg),
+                   events, n_spans)
 
-    drained = out.drained[: len(seeds)]
+
+@x64  # bit-parity requires f64 tables, buffers and traces
+def simulate_batch(
+    plans: Sequence[ModelPlan],
+    tasks: Sequence[TaskSpec],
+    duration: float,
+    scheduler: Scheduler,
+    seeds: Sequence[int],
+    processes: Optional[Sequence[Optional[ArrivalProcess]]] = None,
+    budget_policy=None,
+    admission=None,
+    faults=None,
+) -> List[SimResult]:
+    """Run B = ``len(seeds)`` trials of one cell as ONE device program.
+
+    Same contract as ``simulate()`` for every supported axis — each
+    returned :class:`SimResult` is fingerprint-identical to
+    ``simulate(..., seed=s, engine="soa")`` (pinned by
+    tests/test_engine_batch.py).  Unsupported axes raise
+    :class:`BatchUnsupportedError` (see :func:`_validate`); an
+    undrained lane (the speculation bound failed — an engine bug, not a
+    workload property) raises ``RuntimeError``.
+    """
+    staged = stage_batch(plans, tasks, duration, scheduler, seeds,
+                         processes, budget_policy, admission, faults)
+    out: _Out = _run_trials(*staged.args, **staged.static)
+    return assemble_batch(out, staged, plans, tasks, duration, scheduler)
+
+
+def assemble_batch(out, staged: _Staged, plans, tasks, duration, scheduler):
+    """Host assembly: one device->host copy of ``out``, then a
+    :class:`SimResult` per seed."""
+    events, n_spans = staged.events, staged.n_spans
+    out = jax.tree_util.tree_map(np.asarray, out)  # ONE host sync
+    F = f64.SOFT if staged.static["soft"] else f64.NATIVE
+    out = out._replace(busy_t=F.from_device(out.busy_t),
+                       busy_h=F.from_device(out.busy_h))
+
+    drained = out.drained[: len(events)]
     if not drained.all():
         raise RuntimeError(
             "engine='batch' lane(s) %s did not drain their event horizon "

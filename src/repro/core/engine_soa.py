@@ -216,13 +216,10 @@ _SJ = None  # lazily imported repro.core.scheduler_jax (pulls in jax)
 
 
 def _jax_mod():
-    """Lazy scheduler_jax import.  NOTE: importing it enables jax x64
-    process-wide (bit-parity with the f64 Python kernels requires it),
-    so the first jitted round in a process changes the default dtype of
-    any *later-created* default-dtype jax arrays.  In-repo jax code is
-    dtype-explicit (pinned by running the suite under JAX_ENABLE_X64=1);
-    embedders mixing this engine with dtype-implicit jax code should
-    import scheduler_jax up front rather than mid-run."""
+    """Lazy scheduler_jax import: a process whose rounds stay on the
+    Python kernels never imports jax.  The jitted round scopes its f64
+    arithmetic with ``scheduler_jax.x64``; the process default stays
+    32-bit."""
     global _SJ
     if _SJ is None:
         from repro.core import scheduler_jax

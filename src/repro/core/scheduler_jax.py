@@ -30,30 +30,52 @@ Tie-breaking matches the Python reference bit-for-bit (stable argsort on
 best-case slack == sorted(..., key=(slack, rid)); first-minimum argmin ==
 min(key=...); first-maximum argmax == strict-improvement replacement),
 property-tested in tests/test_scheduler_jax.py.  The round runs in
-float64 (x64 enabled at import): every add/sub/compare is then the same
-IEEE op the Python kernels execute, so the jitted round is bit-identical
-on arbitrary latency tables, not just dyadic ones — a requirement for
-the engine dispatch path (``REPRO_ROUND_KERNEL=jax``), whose SimResults
-are pinned against the reference engine.
+binary64: every add/sub/compare is then the same IEEE op the Python
+kernels execute, so the jitted round is bit-identical on arbitrary
+latency tables, not just dyadic ones — a requirement for the engine
+dispatch path (``REPRO_ROUND_KERNEL=jax``), whose SimResults are pinned
+against the reference engine.  Its float operations go through
+:mod:`repro.core.f64`: ``jnp`` float64 where the backend is IEEE, the
+software binary64 on the TPU (whose float64 is not), with the packers
+staging float arrays as their int64 bit patterns there.
+
+64-bit types are scoped, never switched on for the process: every entry
+point here that stages arrays or calls a jitted program runs under
+:func:`x64` (``jax.enable_x64``), so models and kernels that share the
+process keep JAX's default 32-bit types.
 """
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, wraps
 from typing import NamedTuple, Tuple
 
 import jax
-
-# The jitted round must reproduce the Python schedulers' float64
-# arithmetic exactly; without x64, inputs silently downcast to f32 and
-# bit-parity only holds on dyadic grids.  Enabled before any tracing.
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import f64
+
 EPS = 1e-15
 NEG = -1e30
+
+
+def x64(fn):
+    """Run ``fn`` with 64-bit types enabled.
+
+    The simulator's device programs must reproduce the Python engines'
+    float64 arithmetic exactly; without x64, ``jnp.asarray`` silently
+    downcasts f64 inputs to f32 and jit traces in f32.  Wrap every host
+    function that stages simulator arrays or calls a simulator jit, so
+    the arrays it creates and the traces it triggers are f64."""
+
+    @wraps(fn)
+    def run(*args, **kwargs):
+        with jax.enable_x64(True):
+            return fn(*args, **kwargs)
+
+    return run
+
 
 #: stage-2 guard variants of TerastalScheduler.backfill_mode (static
 #: compile-time argument of :func:`terastal_round`).
@@ -77,20 +99,31 @@ class RoundOutputs(NamedTuple):
     assign_seq: jax.Array  # [NJ] int32 emission order; NJ + NA = unassigned
 
 
-def _best_case_slack(inp: RoundInputs, tau: jax.Array) -> jax.Array:
-    finish = tau[None, :] + inp.lat  # [NJ, NA]
-    return inp.vdl - finish.min(axis=1)
+def _best_case_slack(F, inp: RoundInputs, tau: jax.Array) -> jax.Array:
+    finish = F.add(tau[None, :], inp.lat)  # [NJ, NA]
+    return F.sub(inp.vdl, F.min(finish, axis=1))
 
 
-@partial(jax.jit, static_argnames=("mode",))
+@x64
 def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
+    """One jitted Terastal round over :func:`pack_view`/:func:`pack_arrays`
+    inputs (compiles once per NJ bucket and mode: ``round_jit``), in the
+    platform's binary64 (:func:`f64.for_platform`), as the packers
+    staged them."""
+    return round_jit(inp, mode=mode, soft=f64.for_platform() is f64.SOFT)
+
+
+@partial(jax.jit, static_argnames=("mode", "soft"))
+def round_jit(inp: RoundInputs, mode: str = "ef", soft: bool = False) -> RoundOutputs:
     if mode not in BACKFILL_MODES:
         raise ValueError(f"unknown backfill mode {mode!r} (have {BACKFILL_MODES})")
+    F = f64.SOFT if soft else f64.NATIVE
     NJ, NA = inp.lat.shape
-    inf = jnp.inf
+    inf, ninf = F.const(jnp.inf), F.const(-jnp.inf)
+    eps = F.const(EPS)
 
-    s_star0 = jnp.where(inp.ready_mask, _best_case_slack(inp, inp.tau), inf)
-    order = jnp.argsort(s_star0, stable=True)  # ties -> lower slot index
+    s_star0 = jnp.where(inp.ready_mask, _best_case_slack(F, inp, inp.tau), inf)
+    order = F.argsort(s_star0)  # stable: ties -> lower slot index
 
     # ---------------- stage 1 ----------------
     def stage1_body(i, state):
@@ -100,10 +133,10 @@ def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
         d_v = inp.vdl[j]
 
         def try_impl(lat_row):
-            finish = tau + lat_row
-            cand = idle & (finish <= d_v + EPS) & jnp.isfinite(lat_row)
+            finish = F.add(tau, lat_row)
+            cand = idle & F.le(finish, F.add(d_v, eps)) & F.isfinite(lat_row)
             masked = jnp.where(cand, finish, inf)
-            k = jnp.argmin(masked)
+            k = F.argmin(masked)
             return cand.any(), k, lat_row[k]
 
         ok1, k1, c1 = try_impl(inp.lat[j])
@@ -114,7 +147,7 @@ def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
         c = jnp.where(use1, c1, c2)
         assigned = use1 | use2
         idle = jnp.where(assigned, idle.at[k].set(False), idle)
-        tau = jnp.where(assigned, tau.at[k].add(c), tau)
+        tau = jnp.where(assigned, tau.at[k].set(F.add(tau[k], c)), tau)
         acc = jnp.where(assigned, acc.at[j].set(k.astype(jnp.int32)), acc)
         var = jnp.where(assigned, var.at[j].set(use2), var)
         seq = jnp.where(assigned, seq.at[j].set(i.astype(jnp.int32)), seq)
@@ -135,18 +168,18 @@ def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
     def stage2_body(k, state):
         idle, tau, acc, var, seq, remaining = state
         k_idle = idle[k]
-        s_star = _best_case_slack(inp, tau)  # [NJ] current tau
+        s_star = _best_case_slack(F, inp, tau)  # [NJ] current tau
 
         def score(lat_tab):
             c = lat_tab[:, k]
-            finish = tau[k] + c
-            allowed = remaining & jnp.isfinite(c)
+            finish = F.add(tau[k], c)
+            allowed = remaining & F.isfinite(c)
             if mode == "ef":
                 # earliest-finish optimality guard across ALL accelerators
-                ef_all = (tau[None, :] + lat_tab).min(axis=1)
-                allowed = allowed & (finish <= ef_all + EPS)
-            s_f = inp.vdl_next - finish - inp.next_min
-            return jnp.where(allowed, s_f - s_star, -inf)
+                ef_all = F.min(F.add(tau[None, :], lat_tab), axis=1)
+                allowed = allowed & F.le(finish, F.add(ef_all, eps))
+            s_f = F.sub(F.sub(inp.vdl_next, finish), inp.next_min)
+            return jnp.where(allowed, F.sub(s_f, s_star), ninf)
 
         d_orig = score(inp.lat)  # [NJ] (slot order)
         d_var = score(inp.lat_var)
@@ -156,20 +189,18 @@ def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
         # maximum so exact ties resolve identically.
         d_orig_p, d_var_p = d_orig[order], d_var[order]
         flat = jnp.stack([d_orig_p, d_var_p], axis=1).reshape(-1)  # [NJ*2]
-        rank = jnp.stack(
-            [jnp.zeros_like(d_orig_p), -jnp.ones_like(d_var_p)], axis=1
-        ).reshape(-1)
-        best = jnp.argmax(flat)  # first max in sorted order
-        is_max = flat == flat[best]
-        best = jnp.argmax(jnp.where(is_max, rank, -inf))
+        rank = jnp.tile(jnp.array([0, -1], jnp.int32), NJ)  # original, variant
+        best = F.argmax(flat)  # first max in sorted order
+        is_max = F.eq(flat, flat[best])
+        best = jnp.argmax(jnp.where(is_max, rank, -2))
         j = order[best // 2]
         use_var = (best % 2).astype(bool)
-        have = k_idle & jnp.isfinite(flat[best]) & (flat[best] > -inf)
+        have = k_idle & F.isfinite(flat[best]) & F.gt(flat[best], ninf)
         if mode == "positive":
-            have = have & (flat[best] > 0.0)
+            have = have & F.gt(flat[best], F.const(0.0))
         c = jnp.where(use_var, inp.lat_var[j, k], inp.lat[j, k])
         idle = jnp.where(have, idle.at[k].set(False), idle)
-        tau = jnp.where(have, tau.at[k].add(c), tau)
+        tau = jnp.where(have, tau.at[k].set(F.add(tau[k], c)), tau)
         acc = jnp.where(have, acc.at[j].set(jnp.int32(k)), acc)
         var = jnp.where(have, var.at[j].set(use_var), var)
         seq = jnp.where(have, seq.at[j].set(jnp.int32(NJ + k)), seq)
@@ -219,6 +250,7 @@ def _buffers(nj_pad: int, na: int):
     return buf
 
 
+@x64
 def pack_view(view, scheduler) -> Tuple[RoundInputs, list]:
     """Build RoundInputs from a SchedView + TerastalScheduler (host side).
     Returns (inputs, slot->request list).  ``vdl``/``vdl_next`` come from
@@ -267,19 +299,26 @@ def pack_view(view, scheduler) -> Tuple[RoundInputs, list]:
             lat_var[i] = np.inf
     tau = np.array([view.tau(k) for k in range(NA)])
     idle = np.array([view.acc_busy_until[k] <= view.now + 1e-15 for k in range(NA)])
-    inp = RoundInputs(
+    return _stage(ready, vdl, vdl_next, next_min, lat, lat_var, tau, idle), reqs
+
+
+def _stage(ready, vdl, vdl_next, next_min, lat, lat_var, tau, idle) -> RoundInputs:
+    """Host arrays -> device RoundInputs, float arrays in the platform's
+    binary64 (their bit patterns under the software one)."""
+    fl = f64.for_platform().to_device
+    return RoundInputs(
         ready_mask=jnp.asarray(ready),
-        vdl=jnp.asarray(vdl),
-        vdl_next=jnp.asarray(vdl_next),
-        next_min=jnp.asarray(next_min),
-        lat=jnp.asarray(lat),
-        lat_var=jnp.asarray(lat_var),
-        tau=jnp.asarray(tau),
+        vdl=jnp.asarray(fl(vdl)),
+        vdl_next=jnp.asarray(fl(vdl_next)),
+        next_min=jnp.asarray(fl(next_min)),
+        lat=jnp.asarray(fl(lat)),
+        lat_var=jnp.asarray(fl(lat_var)),
+        tau=jnp.asarray(fl(tau)),
         idle_mask=jnp.asarray(idle),
     )
-    return inp, reqs
 
 
+@x64
 def pack_arrays(
     vdl: np.ndarray,
     vdl_next: np.ndarray,
@@ -312,16 +351,8 @@ def pack_arrays(
         dst = buf[name]
         dst[:NJ] = src
         dst[NJ:] = pad
-    return RoundInputs(
-        ready_mask=jnp.asarray(ready),
-        vdl=jnp.asarray(buf["vdl"]),
-        vdl_next=jnp.asarray(buf["vdl_next"]),
-        next_min=jnp.asarray(buf["next_min"]),
-        lat=jnp.asarray(buf["lat"]),
-        lat_var=jnp.asarray(buf["lat_var"]),
-        tau=jnp.asarray(tau),
-        idle_mask=jnp.asarray(idle),
-    )
+    return _stage(ready, buf["vdl"], buf["vdl_next"], buf["next_min"],
+                  buf["lat"], buf["lat_var"], np.asarray(tau, np.float64), idle)
 
 
 # ------------------------------------------- batched trial staging ----
@@ -420,7 +451,9 @@ def pack_fault_epochs(fault_model, plans, duration, seeds, b_pad: int, lp: int):
     A lane's capability state is piecewise-constant between its fault
     events, so the whole timeline is NF events plus NF+1 *epochs*; this
     stages, per lane, the event stream (``fe_t``/``fe_acc``/``fe_code``/
-    ``fe_val``/``n_f``) and, per epoch, every capability-derived table
+    ``fe_val``/``n_f``, and ``fe_ratio``: a scale event's new factor over
+    the accelerator's previous one, divided here so that the device
+    program needs no division) and, per epoch, every capability-derived table
     the round kernels read — the ``[NA]`` latency multiplier
     (``mult_ep``), the virtual-deadline chains (``vdlr_ep``; the
     re-tightened chains under ``retighten=true`` via
@@ -456,6 +489,7 @@ def pack_fault_epochs(fault_model, plans, duration, seeds, b_pad: int, lp: int):
         "fe_acc": np.zeros((b_pad, nf_pad), np.int32),
         "fe_code": np.zeros((b_pad, nf_pad), np.int32),
         "fe_val": np.ones((b_pad, nf_pad)),
+        "fe_ratio": np.ones((b_pad, nf_pad)),
         "n_f": np.zeros(b_pad, np.int32),
         "mult_ep": np.ones((b_pad, nf_pad + 1, NA)),
         "vdlr_ep": np.zeros((b_pad, nf_pad + 1, M, lp + 1)),
@@ -500,6 +534,7 @@ def pack_fault_epochs(fault_model, plans, duration, seeds, b_pad: int, lp: int):
             elif ev.code == "up":
                 avail[ev.acc] = True
             else:
+                fbuf["fe_ratio"][b, e_i] = ev.value / fscale[ev.acc]
                 fscale[ev.acc] = ev.value
             mult = fault_multipliers(fscale, avail)
             eff = effective_plans(plans, mult)

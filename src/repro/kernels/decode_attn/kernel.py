@@ -5,8 +5,13 @@ once per step.  Grid: (batch, kv_heads, L/chunk) with the cache-length
 axis sequential; online-softmax running stats (m, l) and the weighted
 accumulator [G, Dh] live in VMEM scratch, so the output is written once
 at the final chunk.  The query tile [G, Dh] (G = H/Hkv grouped heads)
-rides along every chunk step — G x chunk MXU matmuls keep the VPU/MXU
-busy while the next KV chunk streams.
+rides along every chunk step.
+
+The kernel streams a head-major cache [B, Hkv, L, Dh]: each block is a
+[chunk, Dh] slab whose last two dims satisfy the TPU tiling rule (chunk
+a multiple of 8, Dh the whole dim).  The models keep a position-major
+cache [B, L, Hkv, Dh], whose per-head block [chunk, 1, Dh] the compiler
+refuses, so the wrapper transposes — one extra pass over the cache.
 """
 
 from __future__ import annotations
@@ -18,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params_cls
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _decode_attn_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref, *, n_chunks: int, scale: float):
@@ -31,12 +36,15 @@ def _decode_attn_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_r
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)  # [G, Dh]
-    k = k_ref[0].astype(jnp.float32)[:, 0]  # [Lc, Dh]
-    v = v_ref[0].astype(jnp.float32)[:, 0]  # [Lc, Dh]
+    k = k_ref[0, 0].astype(jnp.float32)  # [Lc, Dh]
+    v = v_ref[0, 0].astype(jnp.float32)  # [Lc, Dh]
     Lc = k.shape[0]
-    valid_len = len_ref[0]
+    valid_len = len_ref[pl.program_id(0)]  # whole [B] vector sits in SMEM
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, Lc]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HIGHEST,
+    ) * scale  # [G, Lc]
     pos = c * Lc + jax.lax.broadcasted_iota(jnp.int32, (1, Lc), 1)
     s = jnp.where(pos < valid_len, s, -1e30)
 
@@ -45,7 +53,9 @@ def _decode_attn_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_r
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32, precision=_HIGHEST
+    )
     m_ref[...] = m_new
 
     @pl.when(c == n_chunks - 1)
@@ -69,15 +79,17 @@ def decode_attn_pallas(
     nc = L // Lc
     scale = 1.0 / (Dh**0.5)
     qg = q.reshape(B, Hkv, G, Dh)
-    vlen = valid_len.astype(jnp.int32).reshape(B, 1)
+    kh = cache_k.transpose(0, 2, 1, 3)  # [B, Hkv, L, Dh]
+    vh = cache_v.transpose(0, 2, 1, 3)
+    vlen = valid_len.astype(jnp.int32)
     out = pl.pallas_call(
         functools.partial(_decode_attn_kernel, n_chunks=nc, scale=scale),
         grid=(B, Hkv, nc),
         in_specs=[
             pl.BlockSpec((1, 1, G, Dh), lambda b, h, c: (b, h, 0, 0)),
-            pl.BlockSpec((1, Lc, 1, Dh), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Lc, 1, Dh), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (b, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, Lc, Dh), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Lc, Dh), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dh), lambda b, h, c: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
@@ -86,9 +98,9 @@ def decode_attn_pallas(
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dh), jnp.float32),
         ],
-        compiler_params=compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qg, cache_k, cache_v, vlen)
+    )(qg, kh, vh, vlen)
     return out.reshape(B, H, Dh)

@@ -1,71 +1,76 @@
 """Pallas TPU kernel: fused D2S -> 1x1 conv -> S2D (the Terastal variant).
 
-Grid: (B, H/th, W/tw).  Each program reads one x tile
-[1, th, tw, C] from VMEM, performs the depth-to-space rearrangement as a
-register-level reshape/transpose (never touching HBM), runs the MXU
-matmul against the resident variant weights [C/g^2, K/g^2], folds space
-back into depth, and writes the [1, th, tw, K'] output tile.
+For a pointwise conv the whole D2S -> conv -> S2D sandwich is a
+block-diagonal matmul: output channels ``(a, b, k)`` of pixel ``(h, w)``
+are ``sum_c x[h, w, (a, b, c)] * w[c, k]`` — the two rearrangements move
+each pixel's ``g^2`` channel groups to sub-pixels and back, and cancel.
+On the contiguous NHWC buffer that is a free reshape: ``x`` viewed as
+``[B*H*W*g^2, C/g^2]`` rows times the variant weights ``[C/g^2, K/g^2]``
+gives the output viewed as ``[B*H*W*g^2, K/g^2]``.  So the kernel is a
+row-tiled MXU matmul with the weights resident in VMEM, and nothing is
+rearranged on chip (an in-kernel reshape/transpose of the tile is a
+shape cast the TPU compiler refuses at VGG widths).
 
-BlockSpec sizing: th*tw*g^2 rows of C/g^2 contraction — tiles are chosen
-so rows are a multiple of 8 (VPU sublane) and the contraction/output dims
-align to 128 (MXU lane) where the layer allows; the wrapper in ops.py
-picks tile sizes against a 16 MiB VMEM budget.
+BlockSpec sizing: row tiles are multiples of 8 (f32 sublanes), picked
+against a VMEM budget, and the contraction/output dims are whole.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _s2d_conv_kernel(x_ref, w_ref, o_ref, *, gamma: int):
-    # x_ref: [1, th, tw, C]; w_ref: [C/g^2, Kv]; o_ref: [1, th, tw, Kv*g^2]
-    g = gamma
-    g2 = g * g
-    th, tw, C = x_ref.shape[1], x_ref.shape[2], x_ref.shape[3]
-    Cv = C // g2
-    x = x_ref[0]  # [th, tw, C]
-    # ---- D2S within the tile: (th, tw, C) -> (th*g * tw*g, C/g^2) ------
-    x = x.reshape(th, tw, g, g, Cv)
-    x = x.transpose(0, 2, 1, 3, 4)  # th, g, tw, g, Cv
-    x = x.reshape(th * g * tw * g, Cv)
-    # ---- MXU matmul ------------------------------------------------------
-    y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
-    y = y.astype(o_ref.dtype)  # [th*g*tw*g, Kv]
-    # ---- S2D back: fold the g x g spatial expansion into channels --------
-    Kv = y.shape[-1]
-    y = y.reshape(th, g, tw, g, Kv)
-    y = y.transpose(0, 2, 1, 3, 4)  # th, tw, g, g, Kv
-    o_ref[0] = y.reshape(th, tw, Kv * g2)
+def _s2d_conv_kernel(x_ref, w_ref, o_ref):
+    # x_ref: [rows, C/g^2]; w_ref: [C/g^2, K/g^2]; o_ref: [rows, K/g^2]
+    # f32 operands contract at full f32 precision; bf16 ones are exact
+    f32 = x_ref.dtype == jnp.float32
+    o_ref[...] = jnp.dot(
+        x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+    ).astype(o_ref.dtype)
+
+
+#: VMEM the row tiles may take, leaving headroom of the 16 MiB per core.
+VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def pick_rows(rows: int, Cv: int, Kv: int, bytes_per_elem: int) -> int:
+    """Largest row tile (a multiple of 8 dividing ``rows``) whose double-
+    buffered x and out tiles plus the resident weights fit the budget."""
+    for t in (2048, 1024, 512, 256, 128, 64, 32, 16, 8):
+        if rows % t:
+            continue
+        vmem = (2 * t * (Cv + Kv) + Cv * Kv) * bytes_per_elem
+        if vmem <= VMEM_BUDGET:
+            return t
+    return rows
 
 
 def s2d_conv_pallas(
     x: jax.Array,  # [B, H, W, C]
     w: jax.Array,  # [C/g^2, K/g^2]
     gamma: int,
-    tile_h: int = 8,
-    tile_w: int = 8,
+    block_rows: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, H, W, C = x.shape
     g2 = gamma * gamma
     Cv, Kv = w.shape
     assert Cv * g2 == C, (C, Cv, gamma)
-    K = Kv * g2
-    th, tw = min(tile_h, H), min(tile_w, W)
-    assert H % th == 0 and W % tw == 0, (H, W, th, tw)
-    grid = (B, H // th, W // tw)
-    return pl.pallas_call(
-        functools.partial(_s2d_conv_kernel, gamma=gamma),
-        grid=grid,
+    rows = B * H * W * g2
+    tm = block_rows or pick_rows(rows, Cv, Kv, x.dtype.itemsize)
+    assert rows % tm == 0, (rows, tm)
+    y = pl.pallas_call(
+        _s2d_conv_kernel,
+        grid=(rows // tm,),
         in_specs=[
-            pl.BlockSpec((1, th, tw, C), lambda b, i, j: (b, i, j, 0)),
-            pl.BlockSpec((Cv, Kv), lambda b, i, j: (0, 0)),
+            pl.BlockSpec((tm, Cv), lambda i: (i, 0)),
+            pl.BlockSpec((Cv, Kv), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, th, tw, K), lambda b, i, j: (b, i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, W, K), x.dtype),
+        out_specs=pl.BlockSpec((tm, Kv), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, Kv), x.dtype),
         interpret=interpret,
-    )(x, w)
+    )(x.reshape(rows, Cv), w)
+    return y.reshape(B, H, W, Kv * g2)

@@ -1,9 +1,8 @@
 """jit'd public wrapper for the fused S2D-variant conv.
 
-``s2d_variant_conv`` handles: tile-size selection against the VMEM
-budget, the general R x S case via im2col (the kernel itself fuses the
-pointwise core — R x S > 1 layers become a patch-matmul with the same
-D2S/S2D sandwich), and CPU fallback through interpret mode.
+``s2d_variant_conv`` runs the kernel compiled on a TPU and interpreted
+only on the CPU backend.  ``s2d_variant_conv_rs`` covers the general
+R x S case in jnp.
 """
 
 from __future__ import annotations
@@ -14,36 +13,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.s2d_conv.kernel import s2d_conv_pallas
-from repro.kernels.s2d_conv.ref import s2d_conv_ref
-
-VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom of 16 MiB/core
 
 
-def _pick_tiles(H: int, W: int, C: int, K: int, bytes_per_elem: int) -> int:
-    for t in (16, 8, 4, 2, 1):
-        if H % t or W % t:
-            continue
-        # x tile + out tile + weights resident
-        vmem = t * t * (C + K) * bytes_per_elem
-        if vmem <= VMEM_BUDGET:
-            return t
-    return 1
-
-
-@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
-def s2d_variant_conv(x: jax.Array, w: jax.Array, gamma: int, interpret: bool = True) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("gamma",))
+def s2d_variant_conv(x: jax.Array, w: jax.Array, gamma: int) -> jax.Array:
     """Fused variant pointwise conv. x: [B,H,W,C], w: [C/g^2, K/g^2]."""
-    B, H, W, C = x.shape
-    Cv, Kv = w.shape
-    K = Kv * gamma * gamma
-    t = _pick_tiles(H, W, C, K, x.dtype.itemsize)
-    return s2d_conv_pallas(x, w, gamma, tile_h=t, tile_w=t, interpret=interpret)
+    return s2d_conv_pallas(x, w, gamma, interpret=jax.default_backend() == "cpu")
 
 
-def s2d_variant_conv_rs(
-    x: jax.Array, w_full: jax.Array, gamma: int, interpret: bool = True
-) -> jax.Array:
-    """R x S > 1 variant conv via im2col + the fused pointwise kernel.
+def s2d_variant_conv_rs(x: jax.Array, w_full: jax.Array, gamma: int) -> jax.Array:
+    """R x S > 1 variant conv: im2col at the D2S resolution, then a jnp
+    patch matmul and S2D.
 
     w_full: [R, S, C/g^2, K/g^2] variant filter (operates in d2s space);
     x is patched at the d2s resolution, matching the paper's Fig. 1

@@ -2,8 +2,8 @@
 
 ``ssd_scan(..., backend="pallas")`` matches ``repro.models.mamba2
 .ssd_chunked`` numerically (tests sweep shapes/dtypes against
-``ssd_naive``); the mamba2/zamba2 models call through here so the kernel
-can be toggled per deployment (interpret=True on CPU, compiled on TPU).
+``ssd_naive``).  The kernel runs compiled on a TPU and interpreted only
+on the CPU backend.
 """
 
 from __future__ import annotations
@@ -16,10 +16,11 @@ from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.models.mamba2 import ssd_chunked, ssd_naive
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "backend", "interpret"))
-def ssd_scan(x, log_a, B, C, dt, chunk: int = 256, backend: str = "jnp", interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk", "backend"))
+def ssd_scan(x, log_a, B, C, dt, chunk: int = 256, backend: str = "jnp"):
     if backend == "pallas":
-        return ssd_scan_pallas(x, log_a, B, C, dt, chunk=chunk, interpret=interpret)
+        return ssd_scan_pallas(x, log_a, B, C, dt, chunk=chunk,
+                               interpret=jax.default_backend() == "cpu")
     if backend == "jnp":
         return ssd_chunked(x, log_a, B, C, dt, chunk)
     if backend == "naive":

@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the REAL step function (full train_step with
@@ -20,6 +16,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -194,6 +191,9 @@ def run_cell(
 
 
 def main() -> None:
+    # 512 host devices stand in for the production mesh; JAX reads the
+    # flag when it first initializes a backend, which is after this line
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS))
     ap.add_argument("--shape", choices=list(SHAPES))

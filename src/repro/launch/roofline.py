@@ -25,16 +25,8 @@ from repro.launch.analytics import HBM_BW, ICI_BW, PEAK_FLOPS, roofline, total_p
 
 
 def cost_analysis_dict(compiled) -> Dict:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns one dict; newer versions return a list with one
-    entry per compiled module (the main module first).  Always hand back
-    a plain dict so callers can ``.get("flops")`` either way.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict (``.get("flops")``)."""
+    return dict(compiled.cost_analysis())
 
 
 def _fmt_s(x: float) -> str:

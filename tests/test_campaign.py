@@ -2,6 +2,10 @@
 parallel == serial, bootstrap aggregation math, and the regression pin
 that the periodic process reproduces the seed simulator exactly."""
 
+import concurrent.futures
+import multiprocessing
+import os
+
 import numpy as np
 import pytest
 
@@ -262,8 +266,9 @@ def test_warm_plan_cache_initializer(monkeypatch):
     """The pool initializer primes the per-process offline-plan cache for
     every campaign cell, so spawn workers skip the Algorithm-1 rebuild on
     their first trial (fork workers inherit it; the initializer is then a
-    cache hit).  Campaign.run must hand the initializer + its cell keys
-    to the executor it constructs."""
+    cache hit), and pins the worker's JAX to the CPU so no worker opens
+    the chip.  Campaign.run must hand the initializer + its cell keys to
+    the executor it constructs."""
     from repro.core import campaign as campaign_mod
     from repro.core.campaign import _PLAN_CACHE, _warm_plan_cache
 
@@ -301,12 +306,42 @@ def test_warm_plan_cache_initializer(monkeypatch):
     monkeypatch.setattr(
         campaign_mod.concurrent.futures, "ProcessPoolExecutor", FakeExecutor
     )
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     camp = Campaign(scenarios=("ar_social",), platforms=("4k_1ws2os",),
                     schedulers=("fcfs",), seeds=(0, 1), duration=0.3)
     res = camp.run(parallel=True, max_workers=2)
     assert len(res.trials) == 2
-    assert captured["initializer"] is campaign_mod._warm_plan_cache
+    assert captured["initializer"] is campaign_mod._init_worker
     assert key in captured["initargs"][0]  # the campaign's cells were handed over
+    assert os.environ["JAX_PLATFORMS"] == "cpu"  # worker startup pinned the CPU
+
+
+def _worker_jax_platforms():
+    import jax
+
+    return jax.config.jax_platforms
+
+
+def test_pool_workers_pin_jax_to_cpu(monkeypatch):
+    """A spawn worker started through the executor's initializer has its
+    JAX pinned to the CPU even when the parent's environment names no
+    platform, so it can never open the chip the parent holds; specs that
+    run a device program never reach the pool."""
+    from repro.core.campaign import TrialExecutor, TrialSpec, _init_worker
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ctx = multiprocessing.get_context("spawn")
+    with concurrent.futures.ProcessPoolExecutor(
+        max_workers=1, mp_context=ctx, initializer=_init_worker, initargs=([],)
+    ) as pool:
+        assert pool.submit(_worker_jax_platforms).result(timeout=300) == "cpu"
+
+    ex = TrialExecutor(parallel=True, max_workers=2)
+    for kw in (dict(engine="batch"), dict(round_kernel="jax")):
+        spec = TrialSpec("ar_social", "4k_1ws2os", "terastal", duration=0.1, **kw)
+        assert type(ex.submit(spec)).__name__ == "_ImmediateFuture"
+    assert ex._pool is None  # no worker was started for them
+    ex.close()
 
 
 def test_campaign_engine_axis_threads_through():

@@ -73,6 +73,27 @@ def test_batch_matches_soa_on_differential_grid(sched_spec, arrival):
         assert res.fingerprint() == want, (sched_spec, arrival, s)
 
 
+@pytest.mark.parametrize("sched_spec,faults", [
+    ("terastal", None),
+    ("edf", "throttle(acc=0,start=0.02,duration=0.05,factor=4.0,retighten=true)"),
+])
+def test_soft_binary64_lanes_match_soa(sched_spec, faults, monkeypatch):
+    """The software binary64 the engine runs on a TPU (whose float64 is
+    not IEEE) keeps every lane fingerprint-identical to SoA — checked
+    here on the CPU, where both arithmetics are available."""
+    from repro.core import f64
+
+    plans, tasks = _plans_tasks()
+    procs = _procs(tasks, "poisson")
+    monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
+    batch = simulate_batch(plans, tasks, DUR, make_scheduler(sched_spec), SEEDS,
+                           processes=procs, faults=faults)
+    monkeypatch.undo()
+    ref = _soa_fingerprints(plans, tasks, sched_spec, procs, SEEDS, faults=faults)
+    for s, res, want in zip(SEEDS, batch, ref):
+        assert res.fingerprint() == want, (sched_spec, faults, s)
+
+
 def test_batch_matches_soa_with_inert_budget_axes():
     """The inert budget axes — explicit static policy, admission="none"
     — are supported and stay fingerprint-exact; they must not be

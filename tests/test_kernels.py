@@ -38,7 +38,7 @@ def test_s2d_conv_matches_ref(B, H, W, C, K, g, dtype):
     x = jax.random.normal(KEY, (B, H, W, C), dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (C // g**2, K // g**2), dtype)
     ref = s2d_conv_ref(x, w, g).astype(jnp.float32)
-    got = s2d_conv_pallas(x, w, g, tile_h=4, tile_w=4, interpret=True).astype(jnp.float32)
+    got = s2d_conv_pallas(x, w, g, block_rows=32, interpret=True).astype(jnp.float32)
     tol = 1e-5 if dtype == jnp.float32 else 0.15
     np.testing.assert_allclose(got, ref, atol=tol, rtol=tol)
 
@@ -48,7 +48,7 @@ def test_s2d_conv_tile_invariance():
     x = jax.random.normal(KEY, (1, 16, 16, 64))
     w = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
     outs = [
-        s2d_conv_pallas(x, w, 2, tile_h=t, tile_w=t, interpret=True) for t in (2, 4, 8, 16)
+        s2d_conv_pallas(x, w, 2, block_rows=t, interpret=True) for t in (8, 64, 256, 1024)
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], atol=1e-5)
@@ -87,7 +87,7 @@ def test_ssd_scan_matches_naive(Bt, L, H, P, N, Q):
     C = jax.random.normal(ks[3], (Bt, L, N))
     dt = jax.nn.softplus(jax.random.normal(ks[4], (Bt, L, H)))
     ref = ssd_naive(x, la, B, C, dt)
-    got = ssd_scan(x, la, B, C, dt, chunk=Q, backend="pallas", interpret=True)
+    got = ssd_scan(x, la, B, C, dt, chunk=Q, backend="pallas")
     rel = float(jnp.abs(ref - got).max() / (jnp.abs(ref).max() + 1e-9))
     assert rel < 1e-5
 
@@ -101,7 +101,7 @@ def test_ssd_scan_dtypes(dtype):
     C = jax.random.normal(ks[3], (1, 64, 8), dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[4], (1, 64, 2))).astype(dtype)
     ref = ssd_naive(x, la, B, C, dt).astype(jnp.float32)
-    got = ssd_scan(x, la, B, C, dt, chunk=16, backend="pallas", interpret=True).astype(jnp.float32)
+    got = ssd_scan(x, la, B, C, dt, chunk=16, backend="pallas").astype(jnp.float32)
     tol = 1e-4 if dtype == jnp.float32 else 0.15
     rel = float(jnp.abs(ref - got).max() / (jnp.abs(ref).max() + 1e-9))
     assert rel < tol
@@ -116,7 +116,7 @@ def test_ssd_chunked_equals_pallas_paths():
     C = jax.random.normal(ks[3], (2, 64, 16))
     dt = jax.nn.softplus(jax.random.normal(ks[4], (2, 64, 4)))
     a = ssd_chunked(x, la, B, C, dt, 16)
-    b = ssd_scan(x, la, B, C, dt, chunk=16, backend="pallas", interpret=True)
+    b = ssd_scan(x, la, B, C, dt, chunk=16, backend="pallas")
     np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
@@ -135,7 +135,7 @@ def test_decode_attn_matches_ref(B, L, H, Hkv, Dh, pos, chunk):
     k = jax.random.normal(ks[1], (B, L, Hkv, Dh))
     v = jax.random.normal(ks[2], (B, L, Hkv, Dh))
     ref = decode_attention(q, k, v, jnp.int32(pos))
-    got = gqa_decode_attention(q, k, v, jnp.int32(pos), backend="pallas", chunk=chunk, interpret=True)
+    got = gqa_decode_attention(q, k, v, jnp.int32(pos), backend="pallas", chunk=chunk)
     np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-4)
 
 
@@ -147,8 +147,8 @@ def test_decode_attn_respects_valid_length():
     k = jax.random.normal(ks[1], (B, L, Hkv, Dh))
     v = jax.random.normal(ks[2], (B, L, Hkv, Dh))
     pos = jnp.int32(20)
-    out1 = gqa_decode_attention(q, k, v, pos, backend="pallas", chunk=16, interpret=True)
+    out1 = gqa_decode_attention(q, k, v, pos, backend="pallas", chunk=16)
     k2 = k.at[:, 30:].set(999.0)
     v2 = v.at[:, 30:].set(-999.0)
-    out2 = gqa_decode_attention(q, k2, v2, pos, backend="pallas", chunk=16, interpret=True)
+    out2 = gqa_decode_attention(q, k2, v2, pos, backend="pallas", chunk=16)
     np.testing.assert_allclose(out1, out2, atol=1e-6)

@@ -77,13 +77,19 @@ def test_vec_kernel_parity_at_bucket_boundaries(mode):
     assert checked >= len(BOUNDARY_NJ)
 
 
+@pytest.mark.parametrize("soft", [False, True], ids=["native", "soft"])
 @pytest.mark.parametrize("mode", ["ef", "paper"])
-def test_jax_round_parity_at_bucket_boundaries(mode):
+def test_jax_round_parity_at_bucket_boundaries(mode, soft, monkeypatch):
     """The jitted round (through the engine's staging path) matches the
     scalar kernel on the same captured states — including the emission
     order reconstructed from assign_seq, which fixes finish-event
     tie-breaking downstream.  f64 end to end: the latency tables here
-    are arbitrary floats, not the dyadic grid of the property test."""
+    are arbitrary floats, not the dyadic grid of the property test.
+    ``soft`` runs the round in the software binary64 the TPU uses."""
+    from repro.core import f64
+
+    if soft:
+        monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
     targets = (15, 16, 17) if mode == "paper" else BOUNDARY_NJ
     states = _capture(mode, targets, per_target=2)
     for nj, instances in states.items():

@@ -82,8 +82,9 @@ def test_jax_round_matches_python(inst):
 def test_pack_view_shape_buckets_bound_recompiles():
     """pack_view pads NJ to power-of-two buckets with persistent host
     buffers, so terastal_round compiles at most once per (bucket, NA)
-    per process — asserted via the jit compilation-cache counter."""
-    from repro.core.scheduler_jax import BUCKET_MIN, bucket_nj
+    per process — asserted via the jit compilation-cache counter, which
+    is cleared first so buckets compiled by earlier tests do not count."""
+    from repro.core.scheduler_jax import BUCKET_MIN, bucket_nj, round_jit
 
     assert bucket_nj(1) == BUCKET_MIN and bucket_nj(BUCKET_MIN) == BUCKET_MIN
     assert bucket_nj(BUCKET_MIN + 1) == 2 * BUCKET_MIN
@@ -109,17 +110,19 @@ def test_pack_view_shape_buckets_bound_recompiles():
         assert len(slots) == nj
         return out
 
+    round_jit.clear_cache()
     round_for(2)  # warm the BUCKET_MIN bucket for this NA
-    base = terastal_round._cache_size()
+    base = round_jit._cache_size()
+    assert base == 1
     for nj in (1, 2, 3, 4):  # same bucket: zero new compilations
         round_for(nj)
-    assert terastal_round._cache_size() == base
+    assert round_jit._cache_size() == base
     round_for(5)  # next bucket: exactly one new compilation ...
-    grown = terastal_round._cache_size()
+    grown = round_jit._cache_size()
     assert grown == base + 1
     for nj in (6, 7, 8):  # ... reused across the whole bucket
         round_for(nj)
-    assert terastal_round._cache_size() == grown
+    assert round_jit._cache_size() == grown
 
 
 def test_jax_round_with_variants():
