@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device-operation intervals / window), from the profiler
+trace of the batches traced after the window."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 1.0 - t["busy_s"] / t["window_s"]
