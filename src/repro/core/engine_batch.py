@@ -106,7 +106,7 @@ from repro.core.simulator import (
 )
 from repro.core.variants import ModelPlan
 
-from repro.core import f64, scheduler_jax
+from repro.core import f64, obs, scheduler_jax
 from repro.core.scheduler_jax import jax, jnp, x64
 
 lax = jax.lax
@@ -152,6 +152,10 @@ class _Out(NamedTuple):
     drained: "jnp.ndarray"   # [B] bool — horizon fully consumed
     evict_cnt: "jnp.ndarray"  # [B, NR] i32 in-flight evictions (faults)
     remap_cnt: "jnp.ndarray"  # [B, NR] i32 post-eviction re-dispatches
+    iters: "jnp.ndarray"     # [B] i32 loop iterations (events popped); the
+    #                          max over lanes is the vmapped loop's trip count
+    live_peak: "jnp.ndarray"  # [B] i32 most requests live at once (ready
+    #                          or running)
 
 
 def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
@@ -230,6 +234,13 @@ def _run_trials(
     ``jnp`` float64 where the backend is IEEE, and with ``soft=True``
     binary64 in software on the int64 bit patterns every float array
     then holds (the TPU's float64 is not IEEE).
+
+    Each part of the loop body runs under a ``jax.named_scope`` (one of
+    ``obs.SCOPES``; ``round/stage1`` and ``round/stage2`` inside the
+    Terastal kernel), so every device operation names its stage in its
+    op name; scopes are metadata and change no number.  Each lane also
+    returns its iteration count (``iters``) and the most requests live
+    at once (``live_peak``, one [NR] reduction per iteration).
     """
     F = f64.SOFT if soft else f64.NATIVE
     INF, NINF, ZERO = F.const(_INF), F.const(-_INF), F.const(0.0)
@@ -259,6 +270,7 @@ def _run_trials(
         disp_t0: object; disp_w: object; disp_h: object
         run_uv: object; run_prev_ret: object
         ev_pend: object; evict_cnt: object; remap_cnt: object
+        live_pk: object  # most requests live at once (ready or running)
 
     def one_lane(at, am, d_abs, d_eps12, ne,
                  fe_t, fe_acc, fe_code, fe_val, fe_ratio, nf,
@@ -283,6 +295,7 @@ def _run_trials(
         NFa = jnp.arange(NF, dtype=I32)
 
         # -- per-event row bind: request r becomes ready at layer l ---------
+        @obs.scope("bind")
         def bind(st: St, pred, r, l, m):
             a = at[r]
             dr = d_abs[r]
@@ -347,89 +360,91 @@ def _run_trials(
             # fo/fv/f0/ev are per-column [NR] chains.  Same IEEE adds/
             # compares — pairwise minimums and per-column adds are the
             # exact ops the materialized form ran, in the same order.
-            tau0 = F.maximum(st.busy, now)                   # [NA]
-            fo_c = col_adds(st.c_lat, tau0)
-            fv_c = col_adds(st.c_latv, tau0)
-            fmin = fo_c[0]
-            for k in range(1, NA):
-                fmin = F.minimum(fmin, fo_c[k])
-            keys = F.sub(st.c_vdl, fmin)  # stage-1 (slack, rid) sort key
-            d_eps = F.add(st.c_vdl, EPS15)
-            oko_c = [F.le(f, d_eps) for f in fo_c]
-            okv_c = [F.le(f, d_eps) for f in fv_c]  # +inf (no variant) fails
-            idle = idle0
-            alive = ready
-            picks = []
-            # stage 1: repeated (slack, rid)-argmin over feasible slots;
-            # argmin's first-occurrence rule == rid tie-break (slot == rid).
-            # Each accelerator takes at most one stage-1 pick, so stage 2's
-            # tau is tau0 plus one cost (c1) per picked accelerator.
-            c1 = F.full(NA, 0.0)
-            hit1 = jnp.zeros(NA, bool)
-            for _ in range(NA):
-                feas_any = (oko_c[0] | okv_c[0]) & idle[0]
+            with jax.named_scope("stage1"):
+                tau0 = F.maximum(st.busy, now)                   # [NA]
+                fo_c = col_adds(st.c_lat, tau0)
+                fv_c = col_adds(st.c_latv, tau0)
+                fmin = fo_c[0]
                 for k in range(1, NA):
-                    feas_any = feas_any | ((oko_c[k] | okv_c[k]) & idle[k])
-                feas = alive & feas_any
-                mk = jnp.where(feas, keys, INF)
-                i = F.argmin(mk).astype(I32)
-                valid = F.lt(mk[i], INF)
-                fo_i = jnp.stack([f[i] for f in fo_c])  # [NA], round-start tau
-                fv_i = jnp.stack([f[i] for f in fv_c])
-                vo = jnp.where(idle & F.le(fo_i, d_eps[i]), fo_i, INF)
-                ko = F.argmin(vo).astype(I32)
-                any_o = F.lt(vo[ko], INF)  # original first (lines 4-10)
-                vv = jnp.where(idle & F.le(fv_i, d_eps[i]), fv_i, INF)
-                kv = F.argmin(vv).astype(I32)
-                use_var = ~any_o
-                k_sel = jnp.where(any_o, ko, kv)
-                c = jnp.where(use_var, st.c_latv[i, k_sel], st.c_lat[i, k_sel])
-                picks.append((valid, i, k_sel, use_var, c))
-                hitk = (NAa == k_sel) & valid
-                c1 = jnp.where(hitk, c, c1)
-                hit1 = hit1 | hitk
-                idle = idle & ~hitk
-                alive = alive & ~((NRa == i) & valid)
-            tau = jnp.where(hit1, F.add(tau0, c1), tau0)
-            # stage 2: backfill remaining idle accelerators, ascending k.
-            # Original and variant rows are stacked ([2, NR]: 0 original,
-            # 1 variant) so each chain is one float op, not two.
-            for k in range(NA):
-                fo_k = col_adds(st.c_lat, tau)  # s* at CURRENT tau
-                f0 = fo_k[0]
-                for kk in range(1, NA):
-                    f0 = F.minimum(f0, fo_k[kk])
-                s_star = F.sub(st.c_vdl, f0)
-                cv = st.c_latv[:, k]
-                if mode == "ef":
-                    fv_k = col_adds(st.c_latv, tau)
-                    ev = fv_k[0]
+                    fmin = F.minimum(fmin, fo_c[k])
+                keys = F.sub(st.c_vdl, fmin)  # stage-1 (slack, rid) sort key
+                d_eps = F.add(st.c_vdl, EPS15)
+                oko_c = [F.le(f, d_eps) for f in fo_c]
+                okv_c = [F.le(f, d_eps) for f in fv_c]  # +inf (no variant) fails
+                idle = idle0
+                alive = ready
+                picks = []
+                # stage 1: repeated (slack, rid)-argmin over feasible slots;
+                # argmin's first-occurrence rule == rid tie-break (slot == rid).
+                # Each accelerator takes at most one stage-1 pick, so stage 2's
+                # tau is tau0 plus one cost (c1) per picked accelerator.
+                c1 = F.full(NA, 0.0)
+                hit1 = jnp.zeros(NA, bool)
+                for _ in range(NA):
+                    feas_any = (oko_c[0] | okv_c[0]) & idle[0]
+                    for k in range(1, NA):
+                        feas_any = feas_any | ((oko_c[k] | okv_c[k]) & idle[k])
+                    feas = alive & feas_any
+                    mk = jnp.where(feas, keys, INF)
+                    i = F.argmin(mk).astype(I32)
+                    valid = F.lt(mk[i], INF)
+                    fo_i = jnp.stack([f[i] for f in fo_c])  # [NA], round-start tau
+                    fv_i = jnp.stack([f[i] for f in fv_c])
+                    vo = jnp.where(idle & F.le(fo_i, d_eps[i]), fo_i, INF)
+                    ko = F.argmin(vo).astype(I32)
+                    any_o = F.lt(vo[ko], INF)  # original first (lines 4-10)
+                    vv = jnp.where(idle & F.le(fv_i, d_eps[i]), fv_i, INF)
+                    kv = F.argmin(vv).astype(I32)
+                    use_var = ~any_o
+                    k_sel = jnp.where(any_o, ko, kv)
+                    c = jnp.where(use_var, st.c_latv[i, k_sel], st.c_lat[i, k_sel])
+                    picks.append((valid, i, k_sel, use_var, c))
+                    hitk = (NAa == k_sel) & valid
+                    c1 = jnp.where(hitk, c, c1)
+                    hit1 = hit1 | hitk
+                    idle = idle & ~hitk
+                    alive = alive & ~((NRa == i) & valid)
+                tau = jnp.where(hit1, F.add(tau0, c1), tau0)
+            with jax.named_scope("stage2"):
+                # stage 2: backfill remaining idle accelerators, ascending k.
+                # Original and variant rows are stacked ([2, NR]: 0 original,
+                # 1 variant) so each chain is one float op, not two.
+                for k in range(NA):
+                    fo_k = col_adds(st.c_lat, tau)  # s* at CURRENT tau
+                    f0 = fo_k[0]
                     for kk in range(1, NA):
-                        ev = F.minimum(ev, fv_k[kk])
-                    fin = jnp.stack([fo_k[k], fv_k[k]])
-                    # earliest-finish guards
-                    ok = F.le(fin, F.add(jnp.stack([f0, ev]), EPS15))
-                    ok = ok & jnp.stack([alive, F.isfinite(cv)])
-                else:
-                    fin = jnp.stack([fo_k[k], F.add(cv, tau[k])])
-                    ok = jnp.stack([jnp.ones_like(alive), F.isfinite(cv)])
-                t = F.sub(F.sub(F.sub(st.c_vdln, fin), st.c_nm), s_star)  # Eq. 8-9
-                dd = jnp.where(ok & alive, t, NINF)
-                do, dv = dd[0], dd[1]
-                mo, mv = F.max(dd, axis=1)
-                orig_wins = F.ge(mo, mv)  # (delta, -use_var) strictly-greater
-                best = jnp.where(orig_wins, mo, mv)
-                valid = idle[k] & F.gt(best, NINF)
-                if mode == "positive":
-                    valid = valid & F.gt(best, ZERO)
-                d_sel = jnp.where(orig_wins, do, dv)
-                tb = jnp.where(F.eq(d_sel, best), keys, INF)
-                i = F.argmin(tb).astype(I32)  # earliest in stage-1 order
-                use_var = ~orig_wins
-                c = jnp.where(use_var, st.c_latv[i, k], st.c_lat[i, k])
-                picks.append((valid, i, jnp.asarray(k, I32), use_var, c))
-                tau = jnp.where((NAa == k) & valid, F.add(tau, c), tau)
-                alive = alive & ~((NRa == i) & valid)
+                        f0 = F.minimum(f0, fo_k[kk])
+                    s_star = F.sub(st.c_vdl, f0)
+                    cv = st.c_latv[:, k]
+                    if mode == "ef":
+                        fv_k = col_adds(st.c_latv, tau)
+                        ev = fv_k[0]
+                        for kk in range(1, NA):
+                            ev = F.minimum(ev, fv_k[kk])
+                        fin = jnp.stack([fo_k[k], fv_k[k]])
+                        # earliest-finish guards
+                        ok = F.le(fin, F.add(jnp.stack([f0, ev]), EPS15))
+                        ok = ok & jnp.stack([alive, F.isfinite(cv)])
+                    else:
+                        fin = jnp.stack([fo_k[k], F.add(cv, tau[k])])
+                        ok = jnp.stack([jnp.ones_like(alive), F.isfinite(cv)])
+                    t = F.sub(F.sub(F.sub(st.c_vdln, fin), st.c_nm), s_star)  # Eq. 8-9
+                    dd = jnp.where(ok & alive, t, NINF)
+                    do, dv = dd[0], dd[1]
+                    mo, mv = F.max(dd, axis=1)
+                    orig_wins = F.ge(mo, mv)  # (delta, -use_var) strictly-greater
+                    best = jnp.where(orig_wins, mo, mv)
+                    valid = idle[k] & F.gt(best, NINF)
+                    if mode == "positive":
+                        valid = valid & F.gt(best, ZERO)
+                    d_sel = jnp.where(orig_wins, do, dv)
+                    tb = jnp.where(F.eq(d_sel, best), keys, INF)
+                    i = F.argmin(tb).astype(I32)  # earliest in stage-1 order
+                    use_var = ~orig_wins
+                    c = jnp.where(use_var, st.c_latv[i, k], st.c_lat[i, k])
+                    picks.append((valid, i, jnp.asarray(k, I32), use_var, c))
+                    tau = jnp.where((NAa == k) & valid, F.add(tau, c), tau)
+                    alive = alive & ~((NRa == i) & valid)
             return picks
 
         def kern_greedy(st: St, ready, idle0, now):
@@ -470,230 +485,241 @@ def _run_trials(
             return active & (st.it < max_it)
 
         def body(st: St):
-            st = st._replace(it=st.it + 1)
-            # pop: lexicographic (time, counter) min; arrivals beat
-            # same-time finishes (their heap counters are always smaller).
-            # With faults: arrival < fault < finish/ghost at equal times
-            # (the reference allocates arrival counters first, then fault
-            # counters, then dynamic finish counters), and ghost-vs-finish
-            # ties break on the stored finish counters.
-            arr_next = at[st.ai]
-            ft_min = F.min(st.fin_t)
-            k_f = jnp.argmin(
-                jnp.where(F.eq(st.fin_t, ft_min), st.fin_cnt, IMAXi)
-            ).astype(I32)
-            if faulted:
-                f_next = fe_t[st.fi]
-                gh_min = F.min(st.gh_t)
-                oth = F.minimum(ft_min, gh_min)
-                is_arr = F.le(arr_next, F.minimum(f_next, oth))
-                is_fault = (~is_arr) & F.le(f_next, oth)
-                g_i = jnp.argmin(
-                    jnp.where(F.eq(st.gh_t, gh_min), st.gh_cnt, IMAXi)
+            with obs.scope("pop"):
+                st = st._replace(it=st.it + 1)
+                # pop: lexicographic (time, counter) min; arrivals beat
+                # same-time finishes (their heap counters are always smaller).
+                # With faults: arrival < fault < finish/ghost at equal times
+                # (the reference allocates arrival counters first, then fault
+                # counters, then dynamic finish counters), and ghost-vs-finish
+                # ties break on the stored finish counters.
+                arr_next = at[st.ai]
+                ft_min = F.min(st.fin_t)
+                k_f = jnp.argmin(
+                    jnp.where(F.eq(st.fin_t, ft_min), st.fin_cnt, IMAXi)
                 ).astype(I32)
-                is_ghost = (~is_arr) & (~is_fault) & (
-                    F.lt(gh_min, ft_min)
-                    | (F.eq(gh_min, ft_min) & (st.gh_cnt[g_i] < st.fin_cnt[k_f]))
-                )
-                is_fin = (~is_arr) & (~is_fault) & (~is_ghost)
-                now = jnp.where(
-                    is_arr, arr_next,
-                    jnp.where(is_fault, f_next,
-                              jnp.where(is_ghost, gh_min, ft_min)),
-                )
-                # ghost pop: a stale finish is a no-op state-wise; its pop
-                # still falls through to the round logic below
-                st = st._replace(
-                    gh_t=jnp.where(
-                        NFa == jnp.where(is_ghost, g_i, NFi), INF, st.gh_t
+                if faulted:
+                    f_next = fe_t[st.fi]
+                    gh_min = F.min(st.gh_t)
+                    oth = F.minimum(ft_min, gh_min)
+                    is_arr = F.le(arr_next, F.minimum(f_next, oth))
+                    is_fault = (~is_arr) & F.le(f_next, oth)
+                    g_i = jnp.argmin(
+                        jnp.where(F.eq(st.gh_t, gh_min), st.gh_cnt, IMAXi)
+                    ).astype(I32)
+                    is_ghost = (~is_arr) & (~is_fault) & (
+                        F.lt(gh_min, ft_min)
+                        | (F.eq(gh_min, ft_min) & (st.gh_cnt[g_i] < st.fin_cnt[k_f]))
                     )
+                    is_fin = (~is_arr) & (~is_fault) & (~is_ghost)
+                    now = jnp.where(
+                        is_arr, arr_next,
+                        jnp.where(is_fault, f_next,
+                                  jnp.where(is_ghost, gh_min, ft_min)),
+                    )
+                    # ghost pop: a stale finish is a no-op state-wise; its pop
+                    # still falls through to the round logic below
+                    st = st._replace(
+                        gh_t=jnp.where(
+                            NFa == jnp.where(is_ghost, g_i, NFi), INF, st.gh_t
+                        )
+                    )
+                else:
+                    is_arr = F.le(arr_next, ft_min)
+                    is_fin = ~is_arr
+                    now = jnp.where(is_arr, arr_next, ft_min)
+
+                # finish candidate (garbage when not is_fin; writes are masked)
+                pop_rf = is_arr | is_fin
+                r_f = st.run_req[k_f]
+                r = jnp.where(is_arr, st.ai, r_f)  # slot == rid == stream index
+                m = am[r]
+                l_new = jnp.where(is_arr, 0, st.layer[r] + 1)
+                done = is_fin & (l_new >= T.nl[m])
+
+                hit_f = NAa == jnp.where(is_fin, k_f, NAi)
+                r_m = jnp.where(pop_rf, r, NRi)
+                hit_r = NRa == r_m
+                hit_d = NRa == jnp.where(done, r, NRi)
+                st = st._replace(
+                    ai=st.ai + is_arr.astype(I32),
+                    fin_t=jnp.where(hit_f, INF, st.fin_t),
+                    run_req=jnp.where(hit_f, -1, st.run_req),
+                    layer=jnp.where(hit_r, l_new, st.layer),
+                    state=jnp.where(hit_r, jnp.where(done, 3, 1), st.state),
+                    missed=jnp.where(hit_d, F.gt(now, d_eps12[r]), st.missed),
+                    done_seq=jnp.where(hit_d, st.done_ctr, st.done_seq),
+                    done_ctr=st.done_ctr + done.astype(I32),
                 )
-            else:
-                is_arr = F.le(arr_next, ft_min)
-                is_fin = ~is_arr
-                now = jnp.where(is_arr, arr_next, ft_min)
-
-            # finish candidate (garbage when not is_fin; writes are masked)
-            pop_rf = is_arr | is_fin
-            r_f = st.run_req[k_f]
-            r = jnp.where(is_arr, st.ai, r_f)  # slot == rid == stream index
-            m = am[r]
-            l_new = jnp.where(is_arr, 0, st.layer[r] + 1)
-            done = is_fin & (l_new >= T.nl[m])
-
-            hit_f = NAa == jnp.where(is_fin, k_f, NAi)
-            r_m = jnp.where(pop_rf, r, NRi)
-            hit_r = NRa == r_m
-            hit_d = NRa == jnp.where(done, r, NRi)
-            st = st._replace(
-                ai=st.ai + is_arr.astype(I32),
-                fin_t=jnp.where(hit_f, INF, st.fin_t),
-                run_req=jnp.where(hit_f, -1, st.run_req),
-                layer=jnp.where(hit_r, l_new, st.layer),
-                state=jnp.where(hit_r, jnp.where(done, 3, 1), st.state),
-                missed=jnp.where(hit_d, F.gt(now, d_eps12[r]), st.missed),
-                done_seq=jnp.where(hit_d, st.done_ctr, st.done_seq),
-                done_ctr=st.done_ctr + done.astype(I32),
-            )
             st = bind(st, pop_rf & ~done, r, l_new, m)
+            # after the pop, before any drop or completion of this
+            # event's round: the peak slot demand
+            with obs.scope("counters"):
+                live = jnp.sum((st.state == 1) | (st.state == 2), dtype=I32)
+                st = st._replace(live_pk=jnp.maximum(st.live_pk, live))
 
             if faulted:
-                # ---- capability event (masked is_fault) -------------------
-                fi_c = jnp.minimum(st.fi, NFi - 1)
-                fk = fe_acc[fi_c]
-                code = fe_code[fi_c]
-                val = fe_val[fi_c]
-                ratio = fe_ratio[fi_c]  # val / old, divided on the host
-                is_down = is_fault & (code == 0)
-                is_up = is_fault & (code == 1)
-                is_scale = is_fault & (code == 2)
-                r_e = st.run_req[fk]
-                has_run = r_e >= 0
-                # down with an in-flight layer: undo the dispatch (variant
-                # bookkeeping, un-run busy time) and re-enter the ready set
-                ev = is_down & has_run
-                r_ec = jnp.where(ev, r_e, NRi)
-                l_e = st.layer[jnp.where(ev, r_e, 0)]
-                m_e = am[jnp.where(ev, r_e, 0)]
-                undo = ev & st.run_uv[fk]
-                r_u = jnp.where(undo, r_e, NRi)
-                st = st._replace(
-                    # exact ret restore: the evicted variant is the
-                    # request's most recent apply, so the pre-dispatch
-                    # product saved at dispatch time is the undone value
-                    ret=jnp.where(NRa == r_u, st.run_prev_ret[fk], st.ret),
-                    app_seq=st.app_seq.at[r_u, l_e].set(-1, mode="drop"),
-                    app_cnt=st.app_cnt.at[r_u].add(-1, mode="drop"),
-                )
-                # evict_busy_adjust replicated op-for-op in jnp
-                t0 = st.disp_t0[fk]
-                new_w = F.sub(now, t0)
-                new_h = F.minimum(new_w, F.maximum(ZERO, F.sub(duration, t0)))
-                dw = F.sub(new_w, st.disp_w[fk])
-                dh = F.sub(new_h, st.disp_h[fk])
-                hit_e = NAa == jnp.where(ev, fk, NAi)
-                # scale with an in-flight layer: re-time the finish by
-                # new_scale / old_scale (retime_busy_adjust in jnp)
-                old = st.fscale[fk]
-                changed = is_scale & has_run & F.ne(val, old)
-                fin_old = st.busy[fk]
-                fin_new = F.add(now, F.mul(F.sub(fin_old, now), ratio))
-                nw2 = F.sub(fin_new, t0)
-                nh2 = F.minimum(nw2, F.maximum(ZERO, F.sub(duration, t0)))
-                dw2 = F.sub(nw2, st.disp_w[fk])
-                dh2 = F.sub(nh2, st.disp_h[fk])
-                hit_s = NAa == jnp.where(changed, fk, NAi)
-                # both eviction and re-time orphan the old finish event:
-                # push it onto the ghost list (the reference leaves it in
-                # the heap as a stale pop)
-                ghost = ev | changed
-                gh_hit = NFa == jnp.where(ghost, st.gh_n, NFi)
-                hit_dn = NAa == jnp.where(is_down, fk, NAi)
-                hit_up = NAa == jnp.where(is_up, fk, NAi)
-                st = st._replace(
-                    gh_t=jnp.where(gh_hit, st.fin_t[fk], st.gh_t),
-                    gh_cnt=jnp.where(gh_hit, st.fin_cnt[fk], st.gh_cnt),
-                    gh_n=st.gh_n + ghost.astype(I32),
-                    busy=jnp.where(
-                        hit_dn, INF,
-                        jnp.where(hit_up, now,
-                                  jnp.where(hit_s, fin_new, st.busy)),
-                    ),
-                    busy_t=jnp.where(
-                        hit_e, F.add(st.busy_t, dw),
-                        jnp.where(hit_s, F.add(st.busy_t, dw2), st.busy_t),
-                    ),
-                    busy_h=jnp.where(
-                        hit_e, F.add(st.busy_h, dh),
-                        jnp.where(hit_s, F.add(st.busy_h, dh2), st.busy_h),
-                    ),
-                    fin_t=jnp.where(
-                        hit_dn, INF, jnp.where(hit_s, fin_new, st.fin_t)
-                    ),
-                    fin_cnt=jnp.where(hit_s, st.cnt, st.fin_cnt),
-                    run_req=jnp.where(hit_dn, -1, st.run_req),
-                    cnt=st.cnt + changed.astype(I32),
-                    fscale=jnp.where(
-                        NAa == jnp.where(is_scale, fk, NAi), val, st.fscale
-                    ),
-                    state=jnp.where(NRa == r_ec, 1, st.state),
-                    ev_pend=jnp.where(NRa == r_ec, True, st.ev_pend),
-                    evict_cnt=st.evict_cnt + (NRa == r_ec).astype(I32),
-                    disp_w=jnp.where(hit_s, nw2, st.disp_w),
-                    disp_h=jnp.where(hit_s, nh2, st.disp_h),
-                    fi=st.fi + is_fault.astype(I32),
-                )
-                # re-bind the evicted row at its current layer with the
-                # post-undo ret (variant feasibility may have changed)
-                st = bind(st, ev, jnp.where(ev, r_e, NRi), l_e, m_e)
+                with obs.scope("fault"):
+                    # ---- capability event (masked is_fault) -------------------
+                    fi_c = jnp.minimum(st.fi, NFi - 1)
+                    fk = fe_acc[fi_c]
+                    code = fe_code[fi_c]
+                    val = fe_val[fi_c]
+                    ratio = fe_ratio[fi_c]  # val / old, divided on the host
+                    is_down = is_fault & (code == 0)
+                    is_up = is_fault & (code == 1)
+                    is_scale = is_fault & (code == 2)
+                    r_e = st.run_req[fk]
+                    has_run = r_e >= 0
+                    # down with an in-flight layer: undo the dispatch (variant
+                    # bookkeeping, un-run busy time) and re-enter the ready set
+                    ev = is_down & has_run
+                    r_ec = jnp.where(ev, r_e, NRi)
+                    l_e = st.layer[jnp.where(ev, r_e, 0)]
+                    m_e = am[jnp.where(ev, r_e, 0)]
+                    undo = ev & st.run_uv[fk]
+                    r_u = jnp.where(undo, r_e, NRi)
+                    st = st._replace(
+                        # exact ret restore: the evicted variant is the
+                        # request's most recent apply, so the pre-dispatch
+                        # product saved at dispatch time is the undone value
+                        ret=jnp.where(NRa == r_u, st.run_prev_ret[fk], st.ret),
+                        app_seq=st.app_seq.at[r_u, l_e].set(-1, mode="drop"),
+                        app_cnt=st.app_cnt.at[r_u].add(-1, mode="drop"),
+                    )
+                    # evict_busy_adjust replicated op-for-op in jnp
+                    t0 = st.disp_t0[fk]
+                    new_w = F.sub(now, t0)
+                    new_h = F.minimum(new_w, F.maximum(ZERO, F.sub(duration, t0)))
+                    dw = F.sub(new_w, st.disp_w[fk])
+                    dh = F.sub(new_h, st.disp_h[fk])
+                    hit_e = NAa == jnp.where(ev, fk, NAi)
+                    # scale with an in-flight layer: re-time the finish by
+                    # new_scale / old_scale (retime_busy_adjust in jnp)
+                    old = st.fscale[fk]
+                    changed = is_scale & has_run & F.ne(val, old)
+                    fin_old = st.busy[fk]
+                    fin_new = F.add(now, F.mul(F.sub(fin_old, now), ratio))
+                    nw2 = F.sub(fin_new, t0)
+                    nh2 = F.minimum(nw2, F.maximum(ZERO, F.sub(duration, t0)))
+                    dw2 = F.sub(nw2, st.disp_w[fk])
+                    dh2 = F.sub(nh2, st.disp_h[fk])
+                    hit_s = NAa == jnp.where(changed, fk, NAi)
+                    # both eviction and re-time orphan the old finish event:
+                    # push it onto the ghost list (the reference leaves it in
+                    # the heap as a stale pop)
+                    ghost = ev | changed
+                    gh_hit = NFa == jnp.where(ghost, st.gh_n, NFi)
+                    hit_dn = NAa == jnp.where(is_down, fk, NAi)
+                    hit_up = NAa == jnp.where(is_up, fk, NAi)
+                    st = st._replace(
+                        gh_t=jnp.where(gh_hit, st.fin_t[fk], st.gh_t),
+                        gh_cnt=jnp.where(gh_hit, st.fin_cnt[fk], st.gh_cnt),
+                        gh_n=st.gh_n + ghost.astype(I32),
+                        busy=jnp.where(
+                            hit_dn, INF,
+                            jnp.where(hit_up, now,
+                                      jnp.where(hit_s, fin_new, st.busy)),
+                        ),
+                        busy_t=jnp.where(
+                            hit_e, F.add(st.busy_t, dw),
+                            jnp.where(hit_s, F.add(st.busy_t, dw2), st.busy_t),
+                        ),
+                        busy_h=jnp.where(
+                            hit_e, F.add(st.busy_h, dh),
+                            jnp.where(hit_s, F.add(st.busy_h, dh2), st.busy_h),
+                        ),
+                        fin_t=jnp.where(
+                            hit_dn, INF, jnp.where(hit_s, fin_new, st.fin_t)
+                        ),
+                        fin_cnt=jnp.where(hit_s, st.cnt, st.fin_cnt),
+                        run_req=jnp.where(hit_dn, -1, st.run_req),
+                        cnt=st.cnt + changed.astype(I32),
+                        fscale=jnp.where(
+                            NAa == jnp.where(is_scale, fk, NAi), val, st.fscale
+                        ),
+                        state=jnp.where(NRa == r_ec, 1, st.state),
+                        ev_pend=jnp.where(NRa == r_ec, True, st.ev_pend),
+                        evict_cnt=st.evict_cnt + (NRa == r_ec).astype(I32),
+                        disp_w=jnp.where(hit_s, nw2, st.disp_w),
+                        disp_h=jnp.where(hit_s, nh2, st.disp_h),
+                        fi=st.fi + is_fault.astype(I32),
+                    )
+                    # re-bind the evicted row at its current layer with the
+                    # post-undo ret (variant feasibility may have changed)
+                    st = bind(st, ev, jnp.where(ev, r_e, NRi), l_e, m_e)
 
             # batch simultaneous events before scheduling (ref: abs < 1e-15
             # against the just-popped now; empty heap -> +inf -> round runs).
             # A suppressed round folds into the masks below (ready empty ->
             # the kernel emits nothing) instead of a whole-carry select.
-            t_next = F.minimum(at[st.ai], F.min(st.fin_t))
-            if faulted:
-                t_next = F.minimum(
-                    t_next, F.minimum(fe_t[st.fi], F.min(st.gh_t))
-                )
-            do_round = ~F.lt(F.abs(F.sub(t_next, now)), EPS15)
+            with obs.scope("pop"):
+                t_next = F.minimum(at[st.ai], F.min(st.fin_t))
+                if faulted:
+                    t_next = F.minimum(
+                        t_next, F.minimum(fe_t[st.fi], F.min(st.gh_t))
+                    )
+                do_round = ~F.lt(F.abs(F.sub(t_next, now)), EPS15)
 
-            st = st._replace(rounds=st.rounds + do_round.astype(I32))
+                st = st._replace(rounds=st.rounds + do_round.astype(I32))
             if faulted:
-                # the round sees the CURRENT capability epoch: nominal
-                # cache planes times the epoch multiplier (elementwise —
-                # bit-equal to the effective tables the scalar engines
-                # swap in), and the capability-derived scalar vectors
-                # regathered from the epoch planes (vdl chains re-bound
-                # to arrival + chain under retighten, effective
-                # remaining-min for early-drop/EDF/DREAM keys)
-                mult = MULT_EP[st.fi]
-                vdlr_f = VDLR_EP[st.fi]
-                rm_f = RM_EP[st.fi]
-                minl_f = MINL_EP[st.fi]
-                l_all = st.layer
-                m_all = am
-                LPi = jnp.asarray(LP, I32)
-                LP1i = jnp.asarray(LP + 1, I32)
-                has_nx = (l_all + 1) < T.nl[m_all]
-                if use_budgets:
-                    vdl_v = F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all, LPi)])
-                    vdln_v = jnp.where(
+                with obs.scope("epoch"):
+                    # the round sees the CURRENT capability epoch: nominal
+                    # cache planes times the epoch multiplier (elementwise —
+                    # bit-equal to the effective tables the scalar engines
+                    # swap in), and the capability-derived scalar vectors
+                    # regathered from the epoch planes (vdl chains re-bound
+                    # to arrival + chain under retighten, effective
+                    # remaining-min for early-drop/EDF/DREAM keys)
+                    mult = MULT_EP[st.fi]
+                    vdlr_f = VDLR_EP[st.fi]
+                    rm_f = RM_EP[st.fi]
+                    minl_f = MINL_EP[st.fi]
+                    l_all = st.layer
+                    m_all = am
+                    LPi = jnp.asarray(LP, I32)
+                    LP1i = jnp.asarray(LP + 1, I32)
+                    has_nx = (l_all + 1) < T.nl[m_all]
+                    if use_budgets:
+                        vdl_v = F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all, LPi)])
+                        vdln_v = jnp.where(
+                            has_nx,
+                            F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all + 1, LPi)]),
+                            d_abs,
+                        )
+                    else:
+                        vdl_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
+                        vdln_v = jnp.where(
+                            has_nx,
+                            F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 2, LP1i)]),
+                            d_abs,
+                        )
+                    nm_v = jnp.where(
                         has_nx,
-                        F.add(at[:NR], vdlr_f[m_all, jnp.minimum(l_all + 1, LPi)]),
-                        d_abs,
+                        minl_f[m_all, jnp.minimum(l_all + 1, LPi - 1)],
+                        ZERO,
                     )
-                else:
-                    vdl_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
-                    vdln_v = jnp.where(
-                        has_nx,
-                        F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 2, LP1i)]),
-                        d_abs,
+                    rm_v = rm_f[m_all, jnp.minimum(l_all, LP1i)]
+                    ek_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
+                    stk = st._replace(
+                        c_lat=F.mul(st.c_lat, mult[None, :]),
+                        c_latv=F.mul(st.c_latv, mult[None, :]),
+                        c_vdl=vdl_v, c_vdln=vdln_v, c_nm=nm_v,
+                        c_rm=rm_v, c_ek=ek_v,
                     )
-                nm_v = jnp.where(
-                    has_nx,
-                    minl_f[m_all, jnp.minimum(l_all + 1, LPi - 1)],
-                    ZERO,
-                )
-                rm_v = rm_f[m_all, jnp.minimum(l_all, LP1i)]
-                ek_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
-                stk = st._replace(
-                    c_lat=F.mul(st.c_lat, mult[None, :]),
-                    c_latv=F.mul(st.c_latv, mult[None, :]),
-                    c_vdl=vdl_v, c_vdln=vdln_v, c_nm=nm_v,
-                    c_rm=rm_v, c_ek=ek_v,
-                )
             else:
                 stk = st
-            ready0 = (st.state == 1) & do_round
-            dropm = ready0 & F.gt(F.add(now, stk.c_rm), d_eps12)  # early-drop
-            st = st._replace(
-                state=jnp.where(dropm, 4, st.state),
-                missed=st.missed | dropm,
-            )
-            ready = ready0 & ~dropm
-            idle = F.le(st.busy, F.add(now, EPS15))
-            picks = kern(stk, ready, idle, now)
+            with obs.scope("drop"):
+                ready0 = (st.state == 1) & do_round
+                dropm = ready0 & F.gt(F.add(now, stk.c_rm), d_eps12)  # early-drop
+                st = st._replace(
+                    state=jnp.where(dropm, 4, st.state),
+                    missed=st.missed | dropm,
+                )
+                ready = ready0 & ~dropm
+            with obs.scope("round"):
+                idle = F.le(st.busy, F.add(now, EPS15))
+                picks = kern(stk, ready, idle, now)
 
             # apply emissions: chained one-hot selects per pick.  Finish
             # counters are cnt + (# valid picks before this one) — the
@@ -701,77 +727,78 @@ def _run_trials(
             # accelerator takes at most one pick per round, so the float
             # updates gather each one's cost ``c_acc`` and run one [NA]
             # add each: the same single add per accelerator as per pick.
-            state_n, run_req = st.state, st.run_req
-            fin_t, fin_cnt = st.fin_t, st.fin_cnt
-            busy, busy_t, busy_h = st.busy, st.busy_t, st.busy_h
-            disp_t0, disp_w, disp_h = st.disp_t0, st.disp_w, st.disp_h
-            run_uv, run_prev = st.run_uv, st.run_prev_ret
-            rem = F.sub(duration, now)
-            rem = jnp.where(F.gt(rem, ZERO), rem, ZERO)
-            n_e = jnp.asarray(0, I32)
-            c_acc = F.full(NA, 0.0)
-            hit = jnp.zeros(NA, bool)
-            rs, uvs, vas, vls = [], [], [], []
-            for valid, i, k, uv, c in picks:
-                hit_a = (NAa == k) & valid
-                hit = hit | hit_a
-                c_acc = jnp.where(hit_a, c, c_acc)
-                state_n = jnp.where((NRa == i) & valid, 2, state_n)
-                run_req = jnp.where(hit_a, i, run_req)
-                fin_cnt = jnp.where(hit_a, st.cnt + n_e, fin_cnt)
+            with obs.scope("apply"):
+                state_n, run_req = st.state, st.run_req
+                fin_t, fin_cnt = st.fin_t, st.fin_cnt
+                busy, busy_t, busy_h = st.busy, st.busy_t, st.busy_h
+                disp_t0, disp_w, disp_h = st.disp_t0, st.disp_w, st.disp_h
+                run_uv, run_prev = st.run_uv, st.run_prev_ret
+                rem = F.sub(duration, now)
+                rem = jnp.where(F.gt(rem, ZERO), rem, ZERO)
+                n_e = jnp.asarray(0, I32)
+                c_acc = F.full(NA, 0.0)
+                hit = jnp.zeros(NA, bool)
+                rs, uvs, vas, vls = [], [], [], []
+                for valid, i, k, uv, c in picks:
+                    hit_a = (NAa == k) & valid
+                    hit = hit | hit_a
+                    c_acc = jnp.where(hit_a, c, c_acc)
+                    state_n = jnp.where((NRa == i) & valid, 2, state_n)
+                    run_req = jnp.where(hit_a, i, run_req)
+                    fin_cnt = jnp.where(hit_a, st.cnt + n_e, fin_cnt)
+                    if faulted:
+                        # dispatch bookkeeping eviction/re-timing must undo;
+                        # run_prev snapshots the pre-apply retained product
+                        run_uv = jnp.where(hit_a, uv, run_uv)
+                        run_prev = jnp.where(hit_a, st.ret[i], run_prev)
+                    n_e = n_e + valid.astype(I32)
+                    rs.append(i)
+                    uvs.append(uv)
+                    vas.append(valid & uv)
+                    vls.append(valid)
+                fin = F.add(now, c_acc)
+                hc = jnp.where(F.le(c_acc, rem), c_acc, rem)
+                fin_t = jnp.where(hit, fin, fin_t)
+                busy = jnp.where(hit, fin, busy)
+                busy_t = jnp.where(hit, F.add(busy_t, c_acc), busy_t)
+                busy_h = jnp.where(hit, F.add(busy_h, hc), busy_h)
                 if faulted:
-                    # dispatch bookkeeping eviction/re-timing must undo;
-                    # run_prev snapshots the pre-apply retained product
-                    run_uv = jnp.where(hit_a, uv, run_uv)
-                    run_prev = jnp.where(hit_a, st.ret[i], run_prev)
-                n_e = n_e + valid.astype(I32)
-                rs.append(i)
-                uvs.append(uv)
-                vas.append(valid & uv)
-                vls.append(valid)
-            fin = F.add(now, c_acc)
-            hc = jnp.where(F.le(c_acc, rem), c_acc, rem)
-            fin_t = jnp.where(hit, fin, fin_t)
-            busy = jnp.where(hit, fin, busy)
-            busy_t = jnp.where(hit, F.add(busy_t, c_acc), busy_t)
-            busy_h = jnp.where(hit, F.add(busy_h, hc), busy_h)
-            if faulted:
-                disp_t0 = jnp.where(hit, now, disp_t0)
-                disp_w = jnp.where(hit, c_acc, disp_w)
-                disp_h = jnp.where(hit, hc, disp_h)
-            # variant bookkeeping: a picked row is unique per round, so the
-            # pre-round app_cnt/layer reads are the scatter-time values; the
-            # [NR, LP] sequence table keeps a true (vector) scatter
-            r_vec = jnp.stack(rs)
-            va = jnp.stack(vas)
-            rv = jnp.where(va, r_vec, NRi)
-            l_vec = st.layer[r_vec]
-            st = st._replace(
-                state=state_n, run_req=run_req,
-                fin_t=fin_t, fin_cnt=fin_cnt,
-                busy=busy, busy_t=busy_t, busy_h=busy_h,
-                app_seq=st.app_seq.at[rv, l_vec].set(
-                    st.app_cnt[r_vec], mode="drop"),
-                app_cnt=st.app_cnt.at[rv].add(1, mode="drop"),
-                ret=st.ret.at[rv].set(
-                    F.mul(st.ret[r_vec], T.factor[am[r_vec], l_vec]), mode="drop"),
-                cnt=st.cnt + n_e,
-            )
-            if faulted:
-                # a dispatched evicted-pending request is remapped (SoA:
-                # evicted_pending cleared + remapped += 1 at dispatch)
-                valid_vec = jnp.stack(vls)
-                was_pend = st.ev_pend[r_vec] & valid_vec
+                    disp_t0 = jnp.where(hit, now, disp_t0)
+                    disp_w = jnp.where(hit, c_acc, disp_w)
+                    disp_h = jnp.where(hit, hc, disp_h)
+                # variant bookkeeping: a picked row is unique per round, so the
+                # pre-round app_cnt/layer reads are the scatter-time values; the
+                # [NR, LP] sequence table keeps a true (vector) scatter
+                r_vec = jnp.stack(rs)
+                va = jnp.stack(vas)
+                rv = jnp.where(va, r_vec, NRi)
+                l_vec = st.layer[r_vec]
                 st = st._replace(
-                    disp_t0=disp_t0, disp_w=disp_w, disp_h=disp_h,
-                    run_uv=run_uv, run_prev_ret=run_prev,
-                    remap_cnt=st.remap_cnt.at[
-                        jnp.where(was_pend, r_vec, NRi)
-                    ].add(1, mode="drop"),
-                    ev_pend=st.ev_pend.at[
-                        jnp.where(valid_vec, r_vec, NRi)
-                    ].set(False, mode="drop"),
+                    state=state_n, run_req=run_req,
+                    fin_t=fin_t, fin_cnt=fin_cnt,
+                    busy=busy, busy_t=busy_t, busy_h=busy_h,
+                    app_seq=st.app_seq.at[rv, l_vec].set(
+                        st.app_cnt[r_vec], mode="drop"),
+                    app_cnt=st.app_cnt.at[rv].add(1, mode="drop"),
+                    ret=st.ret.at[rv].set(
+                        F.mul(st.ret[r_vec], T.factor[am[r_vec], l_vec]), mode="drop"),
+                    cnt=st.cnt + n_e,
                 )
+                if faulted:
+                    # a dispatched evicted-pending request is remapped (SoA:
+                    # evicted_pending cleared + remapped += 1 at dispatch)
+                    valid_vec = jnp.stack(vls)
+                    was_pend = st.ev_pend[r_vec] & valid_vec
+                    st = st._replace(
+                        disp_t0=disp_t0, disp_w=disp_w, disp_h=disp_h,
+                        run_uv=run_uv, run_prev_ret=run_prev,
+                        remap_cnt=st.remap_cnt.at[
+                            jnp.where(was_pend, r_vec, NRi)
+                        ].add(1, mode="drop"),
+                        ev_pend=st.ev_pend.at[
+                            jnp.where(valid_vec, r_vec, NRi)
+                        ].set(False, mode="drop"),
+                    )
             return st
 
         z = jnp.zeros
@@ -799,6 +826,7 @@ def _run_trials(
             disp_t0=fz(NA), disp_w=fz(NA), disp_h=fz(NA),
             run_uv=z(NA, bool), run_prev_ret=F.full(NA, 1.0),
             ev_pend=z(NR, bool), evict_cnt=z(NR, I32), remap_cnt=z(NR, I32),
+            live_pk=jnp.asarray(0, I32),
         )
         st = lax.while_loop(cond, body, st0)
         act = (st.ai < ne) | jnp.any(st.run_req >= 0)
@@ -810,6 +838,8 @@ def _run_trials(
             busy_t=st.busy_t, busy_h=st.busy_h, rounds=st.rounds,
             drained=~act,
             evict_cnt=st.evict_cnt, remap_cnt=st.remap_cnt,
+            iters=st.it,
+            live_peak=st.live_pk,
         )
 
     return jax.vmap(one_lane)(
@@ -897,6 +927,7 @@ class _Staged(NamedTuple):
     static: dict     # static keyword arguments (the scheduler config)
     events: list     # per-seed ``(times, models)`` release streams
     n_spans: list    # per-seed faulted-window counts
+    batch: Optional[int] = None  # recorder batch id (:mod:`repro.core.obs`)
 
 
 def stage_batch(
@@ -922,6 +953,7 @@ def stage_batch(
     from repro.core.faults import make_fault_model
     from repro.core.workload import batch_release_events
 
+    bid = obs.open_batch()
     policy = make_budget_policy(budget_policy)
     policy.reset()
     adm = make_admission_policy(admission)
@@ -945,8 +977,10 @@ def stage_batch(
     soft = F is f64.SOFT
     tables, LP, NA = _build_tables(plans)
     deadline_by_model = np.array([p.deadline for p in plans])
-    events = batch_release_events(tasks, duration, seeds, processes)
-    buf, b_pad, nr_pad = scheduler_jax.pack_trials(events, deadline_by_model)
+    with obs.span("stage.releases", bid):
+        events = batch_release_events(tasks, duration, seeds, processes)
+    with obs.span("stage.pack", bid):
+        buf, b_pad, nr_pad = scheduler_jax.pack_trials(events, deadline_by_model)
 
     # exact event-count bound: each loop iteration pops exactly one event,
     # and the horizon holds n_ev arrivals plus at most one finish per
@@ -958,9 +992,10 @@ def stage_batch(
 
     faulted = fault_model is not None and fault_model.active
     if faulted:
-        fbuf, nf_pad, n_spans = scheduler_jax.pack_fault_epochs(
-            fault_model, plans, duration, seeds, b_pad, LP
-        )
+        with obs.span("stage.faults", bid):
+            fbuf, nf_pad, n_spans = scheduler_jax.pack_fault_epochs(
+                fault_model, plans, duration, seeds, b_pad, LP
+            )
         # each fault event adds at most three pops: itself, the ghost of
         # an orphaned finish, and the re-dispatched layer's new finish
         max_it += 3 * int(fbuf["n_f"].max())
@@ -981,20 +1016,25 @@ def stage_batch(
             "minl_ep": np.zeros((b_pad, 1, 1, 1)),
         }
     fl = F.to_device
-    tables = tables._replace(**{
-        k: fl(v) for k, v in tables._asdict().items() if v.dtype == np.float64})
-    args = (
-        tables,
-        fl(buf["arr_t"]), buf["arr_m"], fl(buf["dl"]), fl(buf["dl12"]),
-        buf["n_ev"],
-        fl(np.float64(duration)), np.int32(max_it),
-        fl(fbuf["fe_t"]), fbuf["fe_acc"], fbuf["fe_code"], fl(fbuf["fe_val"]),
-        fl(fbuf["fe_ratio"]), fbuf["n_f"],
-        fl(fbuf["mult_ep"]), fl(fbuf["vdlr_ep"]), fl(fbuf["rm_ep"]),
-        fl(fbuf["minl_ep"]),
-    )
+    with obs.span("stage.to_device", bid):
+        tables = tables._replace(**{
+            k: fl(v) for k, v in tables._asdict().items() if v.dtype == np.float64})
+        args = (
+            tables,
+            fl(buf["arr_t"]), buf["arr_m"], fl(buf["dl"]), fl(buf["dl12"]),
+            buf["n_ev"],
+            fl(np.float64(duration)), np.int32(max_it),
+            fl(fbuf["fe_t"]), fbuf["fe_acc"], fbuf["fe_code"], fl(fbuf["fe_val"]),
+            fl(fbuf["fe_ratio"]), fbuf["n_f"],
+            fl(fbuf["mult_ep"]), fl(fbuf["vdlr_ep"]), fl(fbuf["rm_ep"]),
+            fl(fbuf["minl_ep"]),
+        )
+    obs.count("lanes", len(seeds), bid)
+    obs.count("nr_pad", nr_pad, bid)
+    obs.count("max_it", max_it, bid)
+    obs.count("releases", int(buf["n_ev"].sum()), bid)
     return _Staged(args, dict(na=NA, lp=LP, faulted=faulted, soft=soft, **cfg),
-                   events, n_spans)
+                   events, n_spans, bid)
 
 
 @x64  # bit-parity requires f64 tables, buffers and traces
@@ -1018,70 +1058,96 @@ def simulate_batch(
     :class:`BatchUnsupportedError` (see :func:`_validate`); an
     undrained lane (the speculation bound failed — an engine bug, not a
     workload property) raises ``RuntimeError``.
+
+    The three stages run under the spans ``engine.stage``,
+    ``engine.loop`` and ``engine.assemble`` of ``engine.batch``; while a
+    recorder of :mod:`repro.core.obs` is active they and the batch's
+    counters are kept.
     """
-    staged = stage_batch(plans, tasks, duration, scheduler, seeds,
-                         processes, budget_policy, admission, faults)
-    out: _Out = _run_trials(*staged.args, **staged.static)
-    return assemble_batch(out, staged, plans, tasks, duration, scheduler)
+    with obs.span("batch"):
+        with obs.span("stage"):
+            staged = stage_batch(plans, tasks, duration, scheduler, seeds,
+                                 processes, budget_policy, admission, faults)
+        with obs.span("loop", staged.batch):
+            before = _run_trials._cache_size()
+            out: _Out = jax.block_until_ready(_run_trials(*staged.args, **staged.static))
+            obs.count("compiles", _run_trials._cache_size() - before, staged.batch)
+        with obs.span("assemble", staged.batch):
+            return assemble_batch(out, staged, plans, tasks, duration, scheduler)
 
 
 def assemble_batch(out, staged: _Staged, plans, tasks, duration, scheduler):
     """Host assembly: one device->host copy of ``out``, then a
     :class:`SimResult` per seed."""
-    events, n_spans = staged.events, staged.n_spans
-    out = jax.tree_util.tree_map(np.asarray, out)  # ONE host sync
-    F = f64.SOFT if staged.static["soft"] else f64.NATIVE
-    out = out._replace(busy_t=F.from_device(out.busy_t),
-                       busy_h=F.from_device(out.busy_h))
+    events, n_spans, bid = staged.events, staged.n_spans, staged.batch
+    with obs.span("assemble.copy", bid):
+        out = jax.tree_util.tree_map(np.asarray, out)  # ONE host sync
+        F = f64.SOFT if staged.static["soft"] else f64.NATIVE
+        out = out._replace(busy_t=F.from_device(out.busy_t),
+                           busy_h=F.from_device(out.busy_h))
+    nb = len(events)
+    iters = out.iters[:nb]
+    obs.count("iters_max", int(iters.max(initial=0)), bid)
+    obs.count("iters_sum", int(iters.sum()), bid)
+    obs.count("rounds_sum", int(out.rounds[:nb].sum()), bid)
+    obs.count("live_peak", int(out.live_peak[:nb].max(initial=0)), bid)
 
-    drained = out.drained[: len(events)]
+    drained = out.drained[:nb]
     if not drained.all():
         raise RuntimeError(
             "engine='batch' lane(s) %s did not drain their event horizon "
             "within the exact bound — engine bug" % np.flatnonzero(~drained)
         )
 
-    results: List[SimResult] = []
-    for b, (times, models) in enumerate(events):
-        n = len(times)
-        state = out.state[b, :n]
-        missed_f = out.missed[b, :n]
-        app_cnt = out.app_cnt[b, :n]
-        evict_c = out.evict_cnt[b, :n]
-        remap_c = out.remap_cnt[b, :n]
-        stats: Dict[int, ModelStats] = {t.model_idx: ModelStats() for t in tasks}
-        for m in stats:
-            mm = models[:n] == m
-            st = stats[m]
-            st.released = int(mm.sum())
-            st.completed = int((mm & (state == 3)).sum())
-            st.dropped = int((mm & (state == 4)).sum())
-            st.missed = int((mm & missed_f).sum())
-            # every released request ends completed, dropped, or in flight
-            st.in_flight = st.released - st.completed - st.dropped
-            st.variants_applied = int(app_cnt[mm].sum())
-            st.evicted = int(evict_c[mm].sum())
-            st.remapped = int(remap_c[mm].sum())
-        # retained_sum: host replay in completion order, through the same
-        # frozenset unions + combo_retained calls the reference performs
-        done = np.flatnonzero(state == 3)
-        for r in done[np.argsort(out.done_seq[b, done])]:
-            m = int(models[r])
-            applied = frozenset()
-            seq = out.app_seq[b, r]
-            order = np.flatnonzero(seq >= 0)
-            for l in order[np.argsort(seq[order])]:
-                applied = applied | {int(l)}
-            stats[m].retained_sum += plans[m].combo_retained(applied)
-        results.append(
-            SimResult(
-                duration=duration,
-                per_model=stats,
-                acc_busy_time=out.busy_t[b].copy(),
-                scheduler_name=scheduler.name,
-                acc_busy_in_horizon=out.busy_h[b].copy(),
-                rounds=int(out.rounds[b]),
-                faulted_spans=n_spans[b],
-            )
+    per_lane: List[Dict[int, ModelStats]] = []
+    with obs.span("assemble.counts", bid):
+        for b, (times, models) in enumerate(events):
+            n = len(times)
+            state = out.state[b, :n]
+            missed_f = out.missed[b, :n]
+            app_cnt = out.app_cnt[b, :n]
+            evict_c = out.evict_cnt[b, :n]
+            remap_c = out.remap_cnt[b, :n]
+            stats: Dict[int, ModelStats] = {t.model_idx: ModelStats() for t in tasks}
+            for m in stats:
+                mm = models[:n] == m
+                st = stats[m]
+                st.released = int(mm.sum())
+                st.completed = int((mm & (state == 3)).sum())
+                st.dropped = int((mm & (state == 4)).sum())
+                st.missed = int((mm & missed_f).sum())
+                # every released request ends completed, dropped, or in flight
+                st.in_flight = st.released - st.completed - st.dropped
+                st.variants_applied = int(app_cnt[mm].sum())
+                st.evicted = int(evict_c[mm].sum())
+                st.remapped = int(remap_c[mm].sum())
+            per_lane.append(stats)
+    # retained_sum: host replay in completion order, through the same
+    # frozenset unions + combo_retained calls the reference performs
+    replayed = 0
+    with obs.span("assemble.replay", bid):
+        for b, (times, models) in enumerate(events):
+            state = out.state[b, :len(times)]
+            done = np.flatnonzero(state == 3)
+            replayed += len(done)
+            for r in done[np.argsort(out.done_seq[b, done])]:
+                m = int(models[r])
+                applied = frozenset()
+                seq = out.app_seq[b, r]
+                order = np.flatnonzero(seq >= 0)
+                for l in order[np.argsort(seq[order])]:
+                    applied = applied | {int(l)}
+                per_lane[b][m].retained_sum += plans[m].combo_retained(applied)
+    obs.count("replayed", replayed, bid)
+    return [
+        SimResult(
+            duration=duration,
+            per_model=per_lane[b],
+            acc_busy_time=out.busy_t[b].copy(),
+            scheduler_name=scheduler.name,
+            acc_busy_in_horizon=out.busy_h[b].copy(),
+            rounds=int(out.rounds[b]),
+            faulted_spans=n_spans[b],
         )
-    return results
+        for b in range(nb)
+    ]
