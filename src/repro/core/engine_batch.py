@@ -53,7 +53,10 @@ policies, non-inert budget policies, custom schedulers) with the named
 :class:`BatchUnsupportedError`, and dynamically, by checking the
 returned ``drained`` flag (every lane consumed its horizon within the
 exact event-count bound).  Unsupported axes NEVER silently fall back —
-callers choose the scalar engines explicitly.
+callers choose the scalar engines explicitly.  The same holds for the
+round's slot window: the host proves its width from the staged releases
+(:func:`ready_span_bound`), and the device checks it every iteration
+(``win_miss``).
 
 Fault injection (``restart`` interrupted-work policy)
 -----------------------------------------------------
@@ -113,6 +116,19 @@ lax = jax.lax
 
 _INF = float("inf")
 
+#: The slots the round reads when the host proves the ready set fits in
+#: them (:func:`ready_span_bound`).  On a v5e the round's device time per
+#: loop iteration is flat from 96 to 128 padded slots (63.3 and 63.2 us)
+#: and 7.3x that at 192 (461 us; PERF.md section 5), so a window of 128
+#: costs what the narrowest shapes cost.
+ROUND_WINDOW = 128
+#: The window starts on a multiple of this many slots: it is one of a few
+#: static slices, chosen per lane by a select.  A per-lane dynamic slice
+#: is a gather under ``vmap``, which the v5e runs as a loop per call
+#: (PERF.md section 6).  A ready set ``span`` rids wide then fits
+#: when ``span <= ROUND_WINDOW - ROUND_ALIGN + 1``.
+ROUND_ALIGN = 32
+
 
 class BatchUnsupportedError(ValueError):
     """A simulation axis the batched engine does not cover.
@@ -156,6 +172,8 @@ class _Out(NamedTuple):
     #                          max over lanes is the vmapped loop's trip count
     live_peak: "jnp.ndarray"  # [B] i32 most requests live at once (ready
     #                          or running)
+    win_miss: "jnp.ndarray"  # [B] bool — a round found a ready request
+    #                          outside its slot window (an engine bug)
 
 
 def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
@@ -195,7 +213,7 @@ def _build_tables(plans: Sequence[ModelPlan]) -> Tuple[_Tables, int, int]:
     jax.jit,
     static_argnames=(
         "kind", "mode", "use_budgets", "use_variants", "na", "lp", "faulted",
-        "soft",
+        "soft", "win",
     ),
 )
 def _run_trials(
@@ -211,7 +229,7 @@ def _run_trials(
     rm_ep,    # [B, NF+1, M, LP+2]
     minl_ep,  # [B, NF+1, M, LP]
     *, kind: str, mode: str, use_budgets: bool, use_variants: bool,
-    na: int, lp: int, faulted: bool = False, soft: bool = False,
+    na: int, lp: int, win: int, faulted: bool = False, soft: bool = False,
 ) -> _Out:
     """The whole-trial device program: vmap(lane while_loop) over seeds.
 
@@ -222,13 +240,19 @@ def _run_trials(
     runs until the slowest lane finishes.
 
     Request slot == rid == arrival-stream index, so ``argmin``'s
-    first-occurrence rule IS the reference's rid tie-break.  (A ring-
-    window variant — per-round state in a ``rid % W`` ring so kernels
-    scan O(W) instead of O(NR) slots — was tried and reverted: the
-    saturation family keeps requests live for nearly their whole
-    deadline, so the window that avoids reuse-overflow is ~NR anyway,
-    and the explicit two-phase rid tie-breaks it forces cost more than
-    the width they save.)
+    first-occurrence rule IS the reference's rid tie-break.
+
+    ``win`` (static) is the width ``W`` of the slots the round reads:
+    all ``NR``, or ``W`` contiguous rows in rid order from ``base``, the
+    first ready rid rounded down to a multiple of :data:`ROUND_ALIGN`
+    (at most ``NR - W``), so the kernels and their tie-breaks are
+    unchanged and a pick maps back as ``base + i``.
+    ``stage_batch`` picks ``W < NR`` only where :func:`ready_span_bound`
+    proves every round's ready set fits, and each iteration checks it
+    (``win_miss``).  An earlier ring window (state in a ``rid % W``
+    ring) was reverted: judged on CPU times, where the round's cost
+    grows linearly with width, it needed two-phase rid tie-breaks and
+    overflowed on saturation traffic, whose live span really is wide.
 
     Every float64 operation goes through ``F`` (:mod:`repro.core.f64`):
     ``jnp`` float64 where the backend is IEEE, and with ``soft=True``
@@ -247,6 +271,7 @@ def _run_trials(
     EPS15 = F.const(1e-15)
     NA, LP = na, lp
     NR = arr_m.shape[-1]
+    W = win
     NF = fe_acc.shape[-1]
     I32 = jnp.int32
 
@@ -271,6 +296,7 @@ def _run_trials(
         run_uv: object; run_prev_ret: object
         ev_pend: object; evict_cnt: object; remap_cnt: object
         live_pk: object  # most requests live at once (ready or running)
+        win_miss: object  # a ready request lay outside the round's window
 
     def one_lane(at, am, d_abs, d_eps12, ne,
                  fe_t, fe_acc, fe_code, fe_val, fe_ratio, nf,
@@ -355,11 +381,15 @@ def _run_trials(
                 return [full[:, k] for k in range(NA)]
             return [F.add(plane[:, k], tau[k]) for k in range(NA)]
 
-        def kern_terastal(st: St, ready, idle0, now):
+        # Both kernels read the round's rows (all NR slots, or the W-slot
+        # window): ``st``'s per-request planes, ``ready``, and the arrival
+        # and deadline rows ``at_r``/``d_r`` the greedy keys use.
+        def kern_terastal(st: St, ready, idle0, now, at_r, d_r):
             # Column-unrolled over the NA accelerators (``col_adds``):
             # fo/fv/f0/ev are per-column [NR] chains.  Same IEEE adds/
             # compares — pairwise minimums and per-column adds are the
             # exact ops the materialized form ran, in the same order.
+            rows = jnp.arange(ready.shape[0], dtype=I32)
             with jax.named_scope("stage1"):
                 tau0 = F.maximum(st.busy, now)                   # [NA]
                 fo_c = col_adds(st.c_lat, tau0)
@@ -403,7 +433,7 @@ def _run_trials(
                     c1 = jnp.where(hitk, c, c1)
                     hit1 = hit1 | hitk
                     idle = idle & ~hitk
-                    alive = alive & ~((NRa == i) & valid)
+                    alive = alive & ~((rows == i) & valid)
                 tau = jnp.where(hit1, F.add(tau0, c1), tau0)
             with jax.named_scope("stage2"):
                 # stage 2: backfill remaining idle accelerators, ascending k.
@@ -444,16 +474,17 @@ def _run_trials(
                     c = jnp.where(use_var, st.c_latv[i, k], st.c_lat[i, k])
                     picks.append((valid, i, jnp.asarray(k, I32), use_var, c))
                     tau = jnp.where((NAa == k) & valid, F.add(tau, c), tau)
-                    alive = alive & ~((NRa == i) & valid)
+                    alive = alive & ~((rows == i) & valid)
             return picks
 
-        def kern_greedy(st: St, ready, idle0, now):
+        def kern_greedy(st: St, ready, idle0, now, at_r, d_r):
+            rows = jnp.arange(ready.shape[0], dtype=I32)
             if kind == "fcfs":
-                key = at[:NR]                       # (arrival, rid)
+                key = at_r                          # (arrival, rid)
             elif kind == "edf":
                 key = st.c_ek                       # (edf deadline, rid)
             else:  # dream
-                key = F.sub(F.sub(d_abs, now), st.c_rm)  # (slack, rid)
+                key = F.sub(F.sub(d_r, now), st.c_rm)  # (slack, rid)
             tau0 = F.maximum(st.busy, now)          # round-start, not updated
             idle = idle0
             alive = ready
@@ -472,7 +503,7 @@ def _run_trials(
                 c = st.c_lat[i, k]
                 picks.append((valid, i, k, fK, c))
                 idle = idle & ~((NAa == k) & valid)
-                alive = alive & ~((NRa == i) & valid)
+                alive = alive & ~((rows == i) & valid)
             return picks
 
         kern = kern_terastal if kind == "terastal" else kern_greedy
@@ -719,7 +750,28 @@ def _run_trials(
                 ready = ready0 & ~dropm
             with obs.scope("round"):
                 idle = F.le(st.busy, F.add(now, EPS15))
-                picks = kern(stk, ready, idle, now)
+                if W < NR:
+                    # every ready rid r has r < ai and dl12[r] >= now (the
+                    # drop above, c_rm >= 0), and the host proved that
+                    # range fits: read W rows from the aligned slot at or
+                    # below the first ready rid
+                    first = jnp.argmax(ready).astype(I32)
+                    base = jnp.minimum(first - first % ROUND_ALIGN, NR - W)
+
+                    def rows_w(x):
+                        out = x[NR - W:]
+                        for b0 in range(0, NR - W, ROUND_ALIGN):
+                            out = jnp.where(base == b0, x[b0:b0 + W], out)
+                        return out
+
+                    stw = stk._replace(**{f: rows_w(getattr(stk, f)) for f in (
+                        "c_lat", "c_latv", "c_vdl", "c_vdln", "c_nm", "c_rm", "c_ek")})
+                    picks = [(v, base + i, k, uv, c) for v, i, k, uv, c in kern(
+                        stw, rows_w(ready), idle, now, rows_w(at[:NR]), rows_w(d_abs))]
+                    outside = ready & ((NRa < base) | (NRa >= base + W))
+                    st = st._replace(win_miss=st.win_miss | jnp.any(outside))
+                else:
+                    picks = kern(stk, ready, idle, now, at[:NR], d_abs)
 
             # apply emissions: chained one-hot selects per pick.  Finish
             # counters are cnt + (# valid picks before this one) — the
@@ -826,7 +878,7 @@ def _run_trials(
             disp_t0=fz(NA), disp_w=fz(NA), disp_h=fz(NA),
             run_uv=z(NA, bool), run_prev_ret=F.full(NA, 1.0),
             ev_pend=z(NR, bool), evict_cnt=z(NR, I32), remap_cnt=z(NR, I32),
-            live_pk=jnp.asarray(0, I32),
+            live_pk=jnp.asarray(0, I32), win_miss=jnp.asarray(False),
         )
         st = lax.while_loop(cond, body, st0)
         act = (st.ai < ne) | jnp.any(st.run_req >= 0)
@@ -839,7 +891,7 @@ def _run_trials(
             drained=~act,
             evict_cnt=st.evict_cnt, remap_cnt=st.remap_cnt,
             iters=st.it,
-            live_peak=st.live_pk,
+            live_peak=st.live_pk, win_miss=st.win_miss,
         )
 
     return jax.vmap(one_lane)(
@@ -920,6 +972,26 @@ def _validate(
             )
 
 
+def ready_span_bound(arr_t, dl12, n_ev) -> int:
+    """The widest rid range any round of the batch can find ready.
+
+    A ready request at a round at time ``now`` has been released
+    (``r < ai``) and survived the early drop (``now + c_rm <= dl12[r]``
+    with ``c_rm >= 0``, so ``dl12[r] >= now``); rids are in time order.
+    The ready set therefore lies in ``[lo(now), ai)`` with ``lo(t) =
+    min{r : dl12[r] >= t}``.  ``ai`` changes only at arrivals and ``lo``
+    never decreases, so per lane the widest such range is
+    ``max_j (j + 1 - lo(t_j))`` over its arrivals ``j`` at ``t_j``; this
+    is the maximum over the lanes of ``(B, NR)`` staged rows, each lane's
+    first ``n_ev`` slots live."""
+    span = 0
+    for t, d, n in zip(arr_t, dl12, n_ev):
+        if n:
+            lo = np.searchsorted(np.maximum.accumulate(d[:n]), t[:n], "left")
+            span = max(span, int((np.arange(1, n + 1) - lo).max()))
+    return span
+
+
 class _Staged(NamedTuple):
     """One cell's seed batch, staged for :func:`_run_trials`."""
 
@@ -982,6 +1054,12 @@ def stage_batch(
     with obs.span("stage.pack", bid):
         buf, b_pad, nr_pad = scheduler_jax.pack_trials(events, deadline_by_model)
 
+    # the round reads a ROUND_WINDOW-slot window where every lane's ready
+    # set provably fits in one from its aligned start; otherwise all nr_pad
+    span = ready_span_bound(buf["arr_t"], buf["dl12"], buf["n_ev"])
+    fits = span <= ROUND_WINDOW - ROUND_ALIGN + 1
+    win = ROUND_WINDOW if fits and ROUND_WINDOW < nr_pad else nr_pad
+
     # exact event-count bound: each loop iteration pops exactly one event,
     # and the horizon holds n_ev arrivals plus at most one finish per
     # executed layer (sum of layer counts over released requests)
@@ -1033,7 +1111,9 @@ def stage_batch(
     obs.count("nr_pad", nr_pad, bid)
     obs.count("max_it", max_it, bid)
     obs.count("releases", int(buf["n_ev"].sum()), bid)
-    return _Staged(args, dict(na=NA, lp=LP, faulted=faulted, soft=soft, **cfg),
+    obs.count("span_bound", span, bid)
+    obs.count("round_slots", win, bid)
+    return _Staged(args, dict(na=NA, lp=LP, faulted=faulted, soft=soft, win=win, **cfg),
                    events, n_spans, bid)
 
 
@@ -1057,7 +1137,8 @@ def simulate_batch(
     tests/test_engine_batch.py).  Unsupported axes raise
     :class:`BatchUnsupportedError` (see :func:`_validate`); an
     undrained lane (the speculation bound failed — an engine bug, not a
-    workload property) raises ``RuntimeError``.
+    workload property) raises ``RuntimeError``, as does a lane whose
+    round found a ready request outside its proven slot window.
 
     The three stages run under the spans ``engine.stage``,
     ``engine.loop`` and ``engine.assemble`` of ``engine.batch``; while a
@@ -1097,6 +1178,12 @@ def assemble_batch(out, staged: _Staged, plans, tasks, duration, scheduler):
         raise RuntimeError(
             "engine='batch' lane(s) %s did not drain their event horizon "
             "within the exact bound — engine bug" % np.flatnonzero(~drained)
+        )
+    win_miss = out.win_miss[:nb]
+    if win_miss.any():
+        raise RuntimeError(
+            "engine='batch' lane(s) %s found a ready request outside the "
+            "round's proven slot window — engine bug" % np.flatnonzero(win_miss)
         )
 
     per_lane: List[Dict[int, ModelStats]] = []

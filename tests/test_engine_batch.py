@@ -19,6 +19,8 @@ Pins the three contracts the batched engine ships with:
 import dataclasses
 import math
 
+import jax
+import numpy as np
 import pytest
 
 from repro.core import make_scheduler, simulate
@@ -226,3 +228,139 @@ def test_executor_groups_batch_specs_preserving_order():
     for got in (results[0], results[2], results[4]):
         want = run_trial(dataclasses.replace(got.spec, engine="soa"))
         _assert_same_metrics(got, want)
+
+
+# ------------------------------------------------------ round slot window ----
+
+WIN_DUR = 1.5  # Table II rates: 135-139 releases pad to 192 slots
+WIN_SEEDS = [0, 1]
+
+
+def _poisson_multicam():
+    from repro.core.workload import SCENARIOS
+
+    plans, tasks = SCENARIOS["multicam_light"].plans(PLATFORMS[PLATFORM])
+    return plans, tasks, _procs(tasks, "poisson")
+
+
+def _brute_span(arr_t, dl12, n_ev):
+    """The widest ``[first ready-able rid, ai)`` range over every time a
+    round could run: each arrival time and a point between each pair."""
+    best = 0
+    for t, d, n in zip(arr_t, dl12, n_ev):
+        t, d = t[:n], d[:n]
+        nows = np.concatenate([t, (t[:-1] + t[1:]) / 2, t[-1:] + 1.0])
+        for now in nows:
+            ai = int((t <= now).sum())
+            live = [r for r in range(ai) if d[r] >= now]
+            if live:
+                best = max(best, ai - min(live))
+    return best
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_ready_span_bound_matches_brute_force(seed):
+    """The host bound equals a direct scan of every round time, on
+    arrival streams with tied times and deadlines that do not grow with
+    rid (models with different relative deadlines)."""
+    from repro.core.engine_batch import ready_span_bound
+
+    rng = np.random.default_rng(seed)
+    B, NR = 3, 64
+    arr_t = np.full((B, NR), np.inf)
+    dl12 = np.full((B, NR), np.inf)
+    n_ev = rng.integers(1, NR + 1, size=B)
+    n_ev[0] = NR
+    for b, n in enumerate(n_ev):
+        t = np.sort(np.round(rng.uniform(0.0, 1.0, n), 2))  # ties
+        rel = rng.choice([0.02, 0.05, 0.3], size=n)
+        arr_t[b, :n] = t
+        dl12[b, :n] = t + rel + 1e-12
+    got = ready_span_bound(arr_t, dl12, n_ev)
+    assert got == _brute_span(arr_t, dl12, n_ev)
+    assert 1 <= got <= NR
+
+
+def test_saturation_keeps_every_slot():
+    """Saturation traffic keeps requests ready for most of a 4-period
+    deadline: its live span is its release count, so the round keeps
+    every padded slot (``win == nr_pad``), past 128 slots too."""
+    from repro.core import obs
+    from repro.core.engine_batch import stage_batch
+
+    plans, tasks = _plans_tasks()
+    with jax.enable_x64(True), obs.record() as rec:
+        staged = stage_batch(plans, tasks, DUR, make_scheduler("terastal"), SEEDS)
+    assert staged.static["win"] == staged.args[2].shape[-1]
+    plans5, tasks5 = SATURATION_SCENARIOS["saturation_5x"].plans(PLATFORMS[PLATFORM])
+    with jax.enable_x64(True), obs.record() as rec:
+        staged = stage_batch(plans5, tasks5, 0.15, make_scheduler("terastal"), SEEDS)
+    c = rec.batches[0]["counters"]
+    assert c["nr_pad"] == 192 and c["span_bound"] > 128
+    assert staged.static["win"] == c["round_slots"] == 192
+
+
+@pytest.mark.parametrize("arith", ["native", "soft"])
+@pytest.mark.parametrize("sched_spec", [
+    "terastal", "terastal(backfill_mode=paper)", "edf",
+])
+def test_windowed_round_matches_soa(sched_spec, arith, monkeypatch):
+    """A Table II multicam Poisson cell padded to 192 slots, whose ready
+    set provably spans far fewer rids, runs its round over a 128-slot
+    window; every lane stays fingerprint-identical to SoA in both
+    arithmetics."""
+    from repro.core import f64, obs
+
+    plans, tasks, procs = _poisson_multicam()
+    if arith == "soft":
+        monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
+    with obs.record() as rec:
+        batch = simulate_batch(plans, tasks, WIN_DUR, make_scheduler(sched_spec),
+                               WIN_SEEDS, processes=procs)
+    monkeypatch.undo()
+    c = rec.batches[0]["counters"]
+    assert c["nr_pad"] == 192 and c["round_slots"] == 128 and c["span_bound"] <= 128
+    ref = [simulate(plans, tasks, WIN_DUR, make_scheduler(sched_spec), seed=s,
+                    processes=procs, engine="soa").fingerprint() for s in WIN_SEEDS]
+    for s, res, want in zip(WIN_SEEDS, batch, ref):
+        assert res.fingerprint() == want, (sched_spec, arith, s)
+
+
+@pytest.mark.parametrize("sched_spec,faults", [
+    ("fcfs", None),
+    ("dream", None),
+    ("terastal", "throttle(acc=0,start=0.3,duration=0.6,factor=4.0,retighten=true)"),
+    ("dream", "down(acc=1,start=0.2,duration=0.5)"),
+])
+def test_windowed_round_matches_soa_other_kinds_and_faults(sched_spec, faults):
+    """The window reads the greedy keys' arrival and deadline rows, and
+    under faults the epoch's re-bound rows; lanes stay identical to SoA."""
+    from repro.core import obs
+
+    plans, tasks, procs = _poisson_multicam()
+    with obs.record() as rec:
+        batch = simulate_batch(plans, tasks, WIN_DUR, make_scheduler(sched_spec),
+                               WIN_SEEDS, processes=procs, faults=faults)
+    assert rec.batches[0]["counters"]["round_slots"] == 128
+    ref = [simulate(plans, tasks, WIN_DUR, make_scheduler(sched_spec), seed=s,
+                    processes=procs, engine="soa", faults=faults).fingerprint()
+           for s in WIN_SEEDS]
+    for s, res, want in zip(WIN_SEEDS, batch, ref):
+        assert res.fingerprint() == want, (sched_spec, faults, s)
+
+
+def test_window_below_the_span_is_caught():
+    """A window narrower than the ready span (forced here; the staging
+    rule never picks one) trips the per-iteration guard, and assembly
+    refuses the batch as an engine bug."""
+    from repro.core.engine_batch import _run_trials, assemble_batch, stage_batch
+
+    plans, tasks = _plans_tasks()
+    sched = make_scheduler("terastal")
+    with jax.enable_x64(True):
+        staged = stage_batch(plans, tasks, DUR, sched, SEEDS)
+        staged = staged._replace(static={**staged.static, "win": 16})
+        out = jax.block_until_ready(_run_trials(*staged.args, **staged.static))
+    assert np.asarray(out.win_miss)[: len(SEEDS)].any()
+    with pytest.raises(RuntimeError, match="outside the round's proven slot window"):
+        assemble_batch(out, staged, plans, tasks, DUR, sched)

@@ -58,25 +58,31 @@ def _has_kernel(compiled) -> bool:
 def test_run_trials_compiles(one_chip, monkeypatch):
     """The device-resident trial engine, in the software binary64 it runs
     on a TPU, at the BENCH_batch headline cell (saturation_5x / 4k_1ws2os
-    / terastal / poisson, B=32, 0.1 s).  About a minute: the software
-    binary64 makes a large program."""
+    / terastal / poisson, B=32, 0.1 s), which reads every slot in its
+    round, and at Table II rates over 2 s (multicam_light, Poisson), 256
+    slots whose round reads a 128-slot window.  About a minute each: the
+    software binary64 makes a large program."""
     from repro.core import f64
     from repro.core.campaign import _plans_for
-    from repro.core.engine_batch import _run_trials, stage_batch
+    from repro.core.engine_batch import ROUND_WINDOW, _run_trials, stage_batch
     from repro.core.scheduler import make_scheduler
     from repro.core.simulator import make_arrival_process
 
-    plans, tasks = _plans_for("saturation_5x", "4k_1ws2os", 0.90, True)
     proc = make_arrival_process("poisson")
     # this process's backend is the CPU: stage as the TPU would
     monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
-    staged = stage_batch(plans, tasks, 0.1, make_scheduler("terastal"), list(range(32)),
-                         processes=[t.arrival or proc for t in tasks])
-    with jax.enable_x64(True):
-        args = _specs(staged.args, one_chip)
-        assert args[1].shape[0] == 32 and args[1].dtype == jnp.int64
-        compiled = _run_trials.lower(*args, **staged.static).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    for cell, horizon, nr, win in [("saturation_5x", 0.1, None, None),
+                                   ("multicam_light", 2.0, 256, ROUND_WINDOW)]:
+        plans, tasks = _plans_for(cell, "4k_1ws2os", 0.90, True)
+        staged = stage_batch(plans, tasks, horizon, make_scheduler("terastal"),
+                             list(range(32)), processes=[t.arrival or proc for t in tasks])
+        with jax.enable_x64(True):
+            args = _specs(staged.args, one_chip)
+            assert args[1].shape[0] == 32 and args[1].dtype == jnp.int64
+            if nr is not None:
+                assert args[2].shape[-1] == nr and staged.static["win"] == win
+            compiled = _run_trials.lower(*args, **staged.static).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_terastal_round_compiles(one_chip):
