@@ -38,15 +38,29 @@ def test_every_entry_names_existing_files():
         assert os.path.isfile(os.path.join(bench.HERE, "metrics", m["name"] + ".py"))
 
 
-def _catalog(name):
-    """The catalog scenario each traffic file was written from, and the
-    arrival given to ``Scenario.plans``."""
+def _fault_parts(spec):
+    """A fault spec as its parsed parts: ``[(kind, {key: value})]``, none
+    for ``"none"``."""
+    import reference
+
+    if spec in (None, "", "none"):
+        return []
+    return [reference.parse_spec(part) for part in spec.split("+")]
+
+
+def check_catalogued(traffic):
+    """The catalog scenario a traffic file declares it was written from
+    (``catalog``, with ``catalog_arrival`` given to ``Scenario.plans``),
+    and an ``AssertionError`` unless the traffic's fault spec is the
+    catalog's, part by part: a fault wave is run at its own times."""
     from repro.core.workload import get_scenario
 
-    return {
-        "sat5x_h0.1": (get_scenario("saturation_5x"), None),
-        "poisson_h2.0": (get_scenario("multicam_light"), "poisson"),
-    }[name]
+    for key in ("catalog", "catalog_arrival"):
+        assert key in traffic, f"traffic {traffic.get('name')!r} has no {key!r} key"
+    sc = get_scenario(traffic["catalog"])
+    got, want = _fault_parts(traffic.get("faults")), _fault_parts(sc.faults)
+    assert got == want, f"fault parts {got} against the catalog's {want}"
+    return sc, traffic["catalog_arrival"]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -55,7 +69,7 @@ def test_traffic_builds_the_catalogued_scenario(name):
 
     cell = bench.Cell(BENCH, name)
     prog = bench.Program(cell)
-    sc, arrival = _catalog(cell.traffic["name"])
+    sc, arrival = check_catalogued(cell.traffic)
     plans, tasks = sc.plans(PLATFORMS[cell.config["platform"]], theta=cell.config["theta"],
                             arrival=arrival)
     assert tasks == prog.tasks
@@ -66,7 +80,57 @@ def test_traffic_builds_the_catalogued_scenario(name):
         assert (p.lat_var == q.lat_var).all()
         assert {l: v.loss for l, v in p.variants.items()} == \
             {l: v.loss for l, v in q.variants.items()}
-    assert prog.faults == "none" and sc.faults in (None, "none")
+
+
+WAVE = "throttle(acc=0,start=0.2,duration=0.5,factor=3.0)+down(acc=1,start=0.7,duration=0.5)"
+
+
+@pytest.fixture
+def catalog(monkeypatch):
+    """Two catalog scenarios: ``wave`` with ``WAVE``, ``calm`` with no faults."""
+    from repro.core import workload
+
+    for name, faults in (("wave", WAVE), ("calm", None)):
+        monkeypatch.setitem(workload.FAULT_SCENARIOS, name,
+                            workload.Scenario(name, (), (), faults=faults))
+
+
+def _traffic(catalog, faults):
+    return {"name": "t", "catalog": catalog, "catalog_arrival": "poisson", "faults": faults}
+
+
+@pytest.mark.parametrize("name,faults", [
+    ("wave", WAVE),
+    ("wave", WAVE.replace("start=0.7,duration=0.5", "duration=0.5,start=0.7")),
+    ("calm", "none"),
+    ("calm", None),
+])
+def test_the_catalog_check_takes_the_catalogs_wave(catalog, name, faults):
+    sc, arrival = check_catalogued(_traffic(name, faults))
+    assert sc.name == name and arrival == "poisson"
+
+
+@pytest.mark.parametrize("name,faults", [
+    ("wave", WAVE.replace("start=0.2,duration=0.5", "start=0.1,duration=0.25")),  # retimed
+    ("wave", WAVE.replace("factor=3.0", "factor=2.0")),  # another throttle factor
+    ("wave", WAVE.replace("acc=0", "acc=2")),  # another accelerator
+    ("wave", WAVE.split("+")[0]),  # a part missing
+    ("wave", WAVE.replace("down(", "throttle(")),  # another kind of fault
+    ("wave", WAVE.replace("factor=3.0", "factor=3.0,retighten=true")),  # a key more
+    ("wave", "none"),
+    ("calm", "down(acc=0,start=0.1,duration=0.2)"),  # faults where the catalog has none
+])
+def test_the_catalog_check_refuses_another_wave(catalog, name, faults):
+    with pytest.raises(AssertionError):
+        check_catalogued(_traffic(name, faults))
+
+
+@pytest.mark.parametrize("key", ["catalog", "catalog_arrival"])
+def test_a_traffic_that_declares_no_catalog_fails_by_name(catalog, key):
+    traffic = _traffic("calm", "none")
+    del traffic[key]
+    with pytest.raises(AssertionError, match=repr(key)):
+        check_catalogued(traffic)
 
 
 @pytest.mark.parametrize("name", CELLS)
