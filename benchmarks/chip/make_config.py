@@ -7,7 +7,9 @@ latency tables it is run with.
 
 The tables are the MAESTRO-style profile of each layer on each
 accelerator (``repro.costmodel``), and of every feasible layer variant
-(gamma in {2, 3}, d2s and s2d) with its accuracy loss.  They are the
+(gamma in {2, 3}, d2s and s2d) with its accuracy loss, and, for a
+model whose layers form a graph and not a chain, ``preds``: each node's
+predecessors (node ``i`` is layer ``i``).  They are the
 deployment's data, as MAESTRO's tables are the paper's: the benchmark's
 reference derives budgets, the variant choice and every scheduling
 decision from them, and the harness checks that the program's offline
@@ -54,8 +56,11 @@ def tables(platform_name: str, models):
                 variants[str(l)] = cands
                 loss[str(l)] = {g: layer_variant_loss(model.name, spec.name, model.redundancy, int(g))
                                 for g in cands}
-        out.append({"model": model.name, "resolution": res, "n_layers": len(model.layers),
-                    "lat": lat.tolist(), "variants": variants, "loss": loss})
+        entry = {"model": model.name, "resolution": res, "n_layers": len(model.layers),
+                 "lat": lat.tolist(), "variants": variants, "loss": loss}
+        if model.dag is not None and not model.dag.is_linear:
+            entry["preds"] = [list(ps) for ps in model.dag.preds]
+        out.append(entry)
     accs = [{"name": a.name, "dataflow": a.dataflow.value, "pes": a.pes}
             for a in platform.accelerators]
     return accs, out

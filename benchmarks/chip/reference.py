@@ -13,7 +13,20 @@ Poisson and MMPP releases (with thinning), static budgets, the FCFS,
 EDF, DREAM and Terastal schedulers (all three backfill guards and both
 ablations), and capability faults (down, throttle, permanent,
 intermittent) under the ``restart`` policy, with or without budget
-re-tightening.  Linear layer chains only.
+re-tightening, and layer DAGs.
+
+A model entry may carry ``preds``, one predecessor list per node (node
+``i`` is layer ``i``).  Without it, or where it is the linear chain, the
+chain path runs.  A DAG plan distributes the deadline over the critical
+path (Algorithm 1 at the current levels: each node's earliest completion
+``ecl``; feasible when the sink's ``ecl`` fits; otherwise the largest
+gap among the tightenable nodes on a critical path is tightened).  A
+request then has one ready entry per unblocked node, all sharing one
+run record: pending predecessor counts, the applied variants and a
+dropped flag.  The sink's finish completes it, the first hopeless entry
+drops it once, and its siblings are swept uncounted.  Faults with a DAG
+plan raise :class:`DagFaultsUnsupported`: no cell needs them, and
+re-tightening over a graph is semantics of its own.
 
 ``dtype`` sets the float type of every time and latency.  ``np.float64``
 is the configuration's precision; ``np.float32`` is the lower-precision
@@ -34,6 +47,10 @@ LEVEL_ATOL = 1e-12
 GAMMAS = (2, 3)
 INTERACTION = 1.1
 FAULT_SALT = 0x5EED_FA17
+
+
+class DagFaultsUnsupported(ValueError):
+    """Faults in a trial with a DAG plan: not covered by the reference."""
 
 
 # ------------------------------------------------------------ specs ----
@@ -201,6 +218,74 @@ def tighten(levels, deadline, dt):
         rho[int(np.argmax(gaps))] += 1
 
 
+class Graph:
+    """A model's layer DAG from its ``preds``: successors (ascending),
+    a topological order, the sources (ascending) and the one sink."""
+
+    def __init__(self, preds: Sequence[Sequence[int]]):
+        n = len(preds)
+        self.preds = [list(ps) for ps in preds]
+        self.succs: List[List[int]] = [[] for _ in range(n)]
+        for l, ps in enumerate(self.preds):
+            if len(set(ps)) != len(ps) or any(not 0 <= p < n or p == l for p in ps):
+                raise ValueError(f"node {l}: malformed predecessors {ps}")
+            for p in ps:
+                self.succs[p].append(l)
+        pending = [len(ps) for ps in self.preds]
+        self.sources = [l for l in range(n) if not pending[l]]
+        self.topo, todo = [], list(self.sources)
+        while todo:
+            l = todo.pop()
+            self.topo.append(l)
+            for s in self.succs[l]:
+                pending[s] -= 1
+                if not pending[s]:
+                    todo.append(s)
+        sinks = [l for l in range(n) if not self.succs[l]]
+        if len(self.topo) != n or len(sinks) != 1:
+            raise ValueError("a layer graph is acyclic with one sink")
+        self.sink = sinks[0]
+
+    @staticmethod
+    def of(model: dict, L: int) -> Optional["Graph"]:
+        """The model's graph, or ``None`` for a chain."""
+        preds = model.get("preds")
+        if preds is not None and len(preds) != L:
+            raise ValueError(f"{len(preds)} predecessor lists for {L} layers")
+        if preds is None or preds == [[]] + [[l] for l in range(L - 1)]:
+            return None
+        return Graph(preds)
+
+
+def tighten_dag(levels, deadline, graph: Graph, dt):
+    """Algorithm 1 over the critical path: ``(feasible, budgets, vdl_rel,
+    rho)``."""
+    levels = [np.asarray(lv, dtype=dt) for lv in levels]
+    L = len(levels)
+    R = np.array([len(lv) for lv in levels])
+    rho = np.zeros(L, dtype=np.int64)
+    zero = dt(0.0)
+    while True:
+        c_ref = np.array([levels[l][rho[l]] for l in range(L)], dtype=dt)
+        ecl = np.zeros(L, dtype=dt)
+        for l in graph.topo:
+            ecl[l] = max((ecl[p] for p in graph.preds[l]), default=zero) + c_ref[l]
+        cp = ecl[graph.sink]
+        if cp <= deadline:
+            scale = deadline / cp
+            return True, c_ref * scale, ecl * scale, rho
+        tail = np.zeros(L, dtype=dt)  # the longest path strictly below each node
+        for l in reversed(graph.topo):
+            tail[l] = max((tail[s] + c_ref[s] for s in graph.succs[l]), default=zero)
+        open_ = (ecl + tail >= cp - LEVEL_ATOL) & (rho < R - 1)
+        if not open_.any():
+            return False, np.zeros(L, dtype=dt), np.zeros(L, dtype=dt), rho
+        gaps = np.full(L, -np.inf, dtype=dt)
+        for l in np.flatnonzero(open_):
+            gaps[l] = levels[l][rho[l]] - levels[l][rho[l] + 1]
+        rho[int(np.argmax(gaps))] += 1
+
+
 class Plan:
     """One model's offline plan, derived from the configuration's tables."""
 
@@ -210,14 +295,21 @@ class Plan:
         self.L, self.na = self.lat.shape
         self.deadline = dt(deadline)
         self.theta = theta
+        self.graph = Graph.of(model, self.L)
         lv = [levels_of(self.lat[l]) for l in range(self.L)]
-        self.feasible, budgets, rho = tighten(lv, self.deadline, dt)
-        self.vdl_rel = np.cumsum(budgets)
+        if self.graph is None:
+            self.feasible, budgets, self.rho = tighten(lv, self.deadline, dt)
+            self.vdl_rel = np.cumsum(budgets)
+            self.succs = [[l + 1] for l in range(self.L - 1)] + [[]]
+        else:
+            self.feasible, budgets, self.vdl_rel, self.rho = tighten_dag(
+                lv, self.deadline, self.graph, dt)
+            self.succs = self.graph.succs
         self.loss: Dict[int, float] = {}
         self.lat_var = np.full_like(self.lat, np.inf)
         if variants_on and self.feasible:
             for l in range(self.L):
-                got = self._design(model, l, lv[l], int(rho[l]), dataflows)
+                got = self._design(model, l, lv[l], int(self.rho[l]), dataflows)
                 if got is not None:
                     self.lat_var[l], self.loss[l] = got
         self._derive()
@@ -246,10 +338,24 @@ class Plan:
         return None
 
     def _derive(self):
+        """The minimum-latency tables: ``crit_from[l]``, the least work
+        from node ``l`` on, inclusive, and ``crit_after[l]``, the least
+        work strictly after it (on a chain, slices of the remaining sum
+        ``rm``)."""
         self.min_lat = self.lat.min(axis=1)
         rm = np.zeros(self.L + 1, dtype=self.dt)
         rm[:-1] = np.cumsum(self.min_lat[::-1])[::-1]
         self.rm = rm
+        if self.graph is None:
+            self.crit_from, self.crit_after = rm[:-1], rm[1:]
+            return
+        zero = self.dt(0.0)
+        cf = np.zeros(self.L, dtype=self.dt)
+        for l in reversed(self.graph.topo):
+            cf[l] = self.min_lat[l] + max((cf[s] for s in self.succs[l]), default=zero)
+        self.crit_from = cf
+        self.crit_after = np.array([max((cf[s] for s in self.succs[l]), default=zero)
+                                    for l in range(self.L)], dtype=self.dt)
 
     def scaled(self, mult):
         """The plan under a capability multiplier per accelerator."""
@@ -284,15 +390,35 @@ def plans_for(config: dict, traffic: dict, dt=np.float64) -> List[Plan]:
 # ----------------------------------------------------------- trial ----
 
 
-class Req:
-    __slots__ = ("rid", "m", "arrival", "deadline", "layer", "applied", "evicted", "vdl_abs")
+class Run:
+    """What the node entries of one DAG request share."""
+    __slots__ = ("pending", "applied", "dropped")
 
-    def __init__(self, rid, m, arrival, deadline):
+    def __init__(self, graph: Graph):
+        self.pending = [len(ps) for ps in graph.preds]
+        self.applied = frozenset()
+        self.dropped = False
+
+
+class Req:
+    """A ready or running request; on a DAG plan, one node entry of it
+    (``layer`` is the node), with its shared ``run``."""
+    __slots__ = ("rid", "m", "arrival", "deadline", "layer", "applied", "evicted", "vdl_abs",
+                 "run")
+
+    def __init__(self, rid, m, arrival, deadline, layer=0, run=None):
         self.rid, self.m, self.arrival, self.deadline = rid, m, arrival, deadline
-        self.layer = 0
+        self.layer = layer
         self.applied = frozenset()
         self.evicted = False
         self.vdl_abs = None
+        self.run = run
+
+
+def _holder(r: Req):
+    """Where the request's applied variants live: the entry of a chain,
+    the shared run of a DAG request."""
+    return r if r.run is None else r.run
 
 
 class Stats:
@@ -333,10 +459,10 @@ def _round(kind, budgets, variants_on, mode, now, ready, busy, plans):
         if kind == "fcfs":
             order = sorted(ready, key=lambda r: (r.arrival, r.rid, r.layer))
         elif kind == "edf":
-            order = sorted(ready, key=lambda r: (r.deadline - plans[r.m].rm[r.layer + 1],
+            order = sorted(ready, key=lambda r: (r.deadline - plans[r.m].crit_after[r.layer],
                                                  r.rid, r.layer))
         else:
-            order = sorted(ready, key=lambda r: (r.deadline - now - plans[r.m].rm[r.layer],
+            order = sorted(ready, key=lambda r: (r.deadline - now - plans[r.m].crit_from[r.layer],
                                                  r.rid, r.layer))
         for r in order:
             if not idle:
@@ -356,11 +482,17 @@ def _round(kind, budgets, variants_on, mode, now, ready, busy, plans):
         p = plans[r.m]
         if budgets:
             return r.vdl_abs[l] if r.vdl_abs is not None else r.arrival + p.vdl_rel[l]
-        return r.deadline - p.rm[l + 1]
+        return r.deadline - p.crit_after[l]
 
     def var_ok(r, l):
         p = plans[r.m]
-        return variants_on and l in p.loss and p.valid(r.applied | {l})
+        return variants_on and l in p.loss and p.valid(_holder(r).applied | {l})
+
+    def binding(r, l):
+        """Eq. 8's next layer: the first successor with the least
+        ``vdl - min_lat`` (-1 at the sink)."""
+        p = plans[r.m]
+        return min(p.succs[l], key=lambda s: vdl(r, s) - p.min_lat[s], default=-1)
 
     def slack(r):
         return vdl(r, r.layer) - (tau + plans[r.m].lat[r.layer]).min()
@@ -404,8 +536,9 @@ def _round(kind, budgets, variants_on, mode, now, ready, busy, plans):
                 finish = tau[k] + c
                 if mode == "ef" and finish > (tau + row).min() + 1e-15:
                     continue
-                if l + 1 < p.L:
-                    s_f = vdl(r, l + 1) - finish - p.lat[l + 1].min()
+                s = binding(r, l)
+                if s >= 0:
+                    s_f = vdl(r, s) - finish - p.min_lat[s]
                 else:
                     s_f = r.deadline - finish
                 delta = s_f - s_star
@@ -426,6 +559,11 @@ def simulate(config: dict, traffic: dict, seed: int, dtype=np.float64,
     the fields of the program's ``SimResult.fingerprint()``."""
     dt = dtype
     base = plans if plans is not None else plans_for(config, traffic, dt)
+    if traffic.get("faults", "none") not in (None, "", "none"):
+        dags = [m["model"] for p, m in zip(base, config["models"]) if p.graph is not None]
+        if dags:
+            raise DagFaultsUnsupported(f"faults with the DAG plans of {dags}: the reference "
+                                       "covers faults on layer chains only")
     kind, budgets, variants_on, mode = _scheduler(config["scheduler"])
     duration = dt(traffic["horizon_s"])
     na = base[0].na
@@ -462,11 +600,18 @@ def simulate(config: dict, traffic: dict, seed: int, dtype=np.float64,
     def schedule(now):
         nonlocal rounds
         rounds += 1
+        swept = False
         for r in list(ready):
-            if now + eff[r.m].rm[r.layer] > r.deadline + 1e-12:
+            if now + eff[r.m].crit_from[r.layer] > r.deadline + 1e-12:
                 ready.remove(r)
+                if r.run is not None:
+                    if r.run.dropped:
+                        continue
+                    r.run.dropped = swept = True
                 stats[r.m].missed += 1
                 stats[r.m].dropped += 1
+        if swept:  # the dropped requests' other entries go uncounted
+            ready[:] = [r for r in ready if r.run is None or not r.run.dropped]
         if not ready:
             return
         snapshot = busy.copy()
@@ -476,7 +621,8 @@ def simulate(config: dict, traffic: dict, seed: int, dtype=np.float64,
             c = p.lat_var[l, k] if use_var else p.lat[l, k]
             ready.remove(r)
             if use_var:
-                r.applied = r.applied | {l}
+                holder = _holder(r)
+                holder.applied = holder.applied | {l}
                 stats[r.m].variants += 1
             if faulted and r.evicted:
                 r.evicted = False
@@ -508,11 +654,17 @@ def simulate(config: dict, traffic: dict, seed: int, dtype=np.float64,
         now, ec, ev, payload = heapq.heappop(heap)
         if ev == 0:
             m = payload
-            r = Req(next(rids), m, now, now + base[m].deadline)
-            if retighten and chain[m] is not None:
-                r.vdl_abs = now + chain[m]
+            graph = base[m].graph
             stats[m].released += 1
-            ready.append(r)
+            if graph is None:
+                r = Req(next(rids), m, now, now + base[m].deadline)
+                if retighten and chain[m] is not None:
+                    r.vdl_abs = now + chain[m]
+                ready.append(r)
+            else:
+                rid, run = next(rids), Run(graph)
+                ready.extend(Req(rid, m, now, now + base[m].deadline, s, run)
+                             for s in graph.sources)
         elif ev == 3:
             k, code, val = payload
             if code == "down":
@@ -553,22 +705,38 @@ def simulate(config: dict, traffic: dict, seed: int, dtype=np.float64,
             pass  # a finish orphaned by an eviction or a re-time
         else:
             r, _ = running.pop(payload)
-            r.layer += 1
-            if r.layer >= base[r.m].L:
+            p = base[r.m]
+            if r.run is None:
+                r.layer += 1
+                done = r.layer >= p.L
+                if not done:
+                    ready.append(r)
+            elif r.run.dropped:
+                done = False  # its busy time is counted, its drop too
+            else:
+                done = r.layer == p.graph.sink
+                for s in p.succs[r.layer]:
+                    r.run.pending[s] -= 1
+                    if not r.run.pending[s]:
+                        ready.append(Req(r.rid, r.m, r.arrival, r.deadline, s, r.run))
+            if done:
                 st = stats[r.m]
                 st.completed += 1
                 if now > r.deadline + 1e-12:
                     st.missed += 1
-                st.retained += base[r.m].retained(r.applied)
-            else:
-                ready.append(r)
+                st.retained += p.retained(_holder(r).applied)
         if heap and abs(heap[0][0] - now) < 1e-15:
             continue
         schedule(now)
 
     live = [0] * len(base)
+    runs = set()  # a DAG request counts once, and not once it has dropped
     for r in ready + [r for r, _ in running.values()]:
-        live[r.m] += 1
+        if r.run is None:
+            live[r.m] += 1
+        elif not r.run.dropped and r.run not in runs:
+            runs.add(r.run)
+            live[r.m] += 1
     return {"rounds": rounds, "busy": [float(x) for x in busy_t],
             "busy_h": [float(x) for x in busy_h],
             "models": [st.row(n) for st, n in zip(stats, live)], "spans": spans}

@@ -73,9 +73,13 @@ def test_traffic_builds_the_catalogued_scenario(name):
     plans, tasks = sc.plans(PLATFORMS[cell.config["platform"]], theta=cell.config["theta"],
                             arrival=arrival)
     assert tasks == prog.tasks
-    for p, q in zip(plans, prog.plans):
+    for p, q, m in zip(plans, prog.plans, cell.config["models"]):
         assert (p.model.name, len(p.model.layers), p.deadline) == \
             (q.model.name, len(q.model.layers), q.deadline)
+        # the layer graph the reference reads is the catalog's (a chain has none)
+        graph = None if p.dag is None else [list(ps) for ps in p.dag.preds]
+        assert graph == m.get("preds")
+        assert q.dag == p.dag
         assert (p.lat == q.lat).all() and (p.vdl_rel == q.vdl_rel).all()
         assert (p.lat_var == q.lat_var).all()
         assert {l: v.loss for l, v in p.variants.items()} == \
@@ -131,6 +135,24 @@ def test_a_traffic_that_declares_no_catalog_fails_by_name(catalog, key):
     del traffic[key]
     with pytest.raises(AssertionError, match=repr(key)):
         check_catalogued(traffic)
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_make_config_writes_the_committed_configuration(config):
+    """``make_config.tables`` gives each configuration's committed tables,
+    with ``preds`` exactly for the models whose layers form a graph."""
+    import make_config
+    from repro.costmodel import dnn_zoo
+
+    with open(os.path.join(ROOT, config["file"])) as f:
+        committed = json.load(f)
+    accs, models = make_config.tables(committed["platform"],
+                                      [(m["model"], m["resolution"]) for m in committed["models"]])
+    assert accs == committed["accelerators"]
+    assert json.loads(json.dumps(models)) == committed["models"]
+    for m in committed["models"]:
+        dag = getattr(dnn_zoo, m["model"])(m["resolution"]).dag
+        assert ("preds" in m) == (dag is not None and not dag.is_linear)
 
 
 @pytest.mark.parametrize("name", CELLS)
