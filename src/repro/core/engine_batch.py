@@ -118,9 +118,10 @@ _INF = float("inf")
 
 #: The slots the round reads when the host proves the ready set fits in
 #: them (:func:`ready_span_bound`).  On a v5e the round's device time per
-#: loop iteration is flat from 96 to 128 padded slots (63.3 and 63.2 us)
-#: and 7.3x that at 192 (461 us; PERF.md section 5), so a window of 128
-#: costs what the narrowest shapes cost.
+#: loop iteration is flat from 96 to 128 padded slots (63.3 and 63.3 us)
+#: and 1.3x that at 192 (81.5 us; PERF.md section 5); a 256-slot program
+#: reading a 128-slot window spends 65.9 us, what the narrowest shapes
+#: cost.
 ROUND_WINDOW = 128
 #: The window starts on a multiple of this many slots: it is one of a few
 #: static slices, chosen per lane by a select.  A per-lane dynamic slice
@@ -342,21 +343,14 @@ def _run_trials(
                 vdl = F.sub(dr, T.rm[m, l + 1])
                 vdln = jnp.where(has_next, F.sub(dr, T.rm[m, l + 2]), dr)
             nm = jnp.where(has_next, T.minl[m, l + 1], ZERO)
-            rb = jnp.where(pred, r, NRi)
-            hit = NRa == rb
-            # the two [NR, NA] cache planes: one-hot select rewrites the
-            # whole plane (cheap while it fits in cache), a row scatter
-            # writes 3 elements but pays the vmapped-scatter thunk; the
-            # crossover sits around the 128-slot bucket (measured)
-            if NR <= 128:
-                c_lat = jnp.where(hit[:, None], lat_row[None, :], st.c_lat)
-                c_latv = jnp.where(hit[:, None], latv_row[None, :], st.c_latv)
-            else:
-                c_lat = st.c_lat.at[rb].set(lat_row, mode="drop")
-                c_latv = st.c_latv.at[rb].set(latv_row, mode="drop")
+            hit = NRa == jnp.where(pred, r, NRi)
+            # the two [NA, NR] cache planes take a one-hot column select at
+            # every width: with the slots in the lanes it rewrites NA lane
+            # rows per 128 slots, and a row scatter would pin the planes
+            # to an NA-minor layout on the TPU past 128 slots
             return st._replace(
-                c_lat=c_lat,
-                c_latv=c_latv,
+                c_lat=jnp.where(hit[None, :], lat_row[:, None], st.c_lat),
+                c_latv=jnp.where(hit[None, :], latv_row[:, None], st.c_latv),
                 c_vdl=jnp.where(hit, vdl, st.c_vdl),
                 c_vdln=jnp.where(hit, vdln, st.c_vdln),
                 c_nm=jnp.where(hit, nm, st.c_nm),
@@ -371,15 +365,16 @@ def _run_trials(
         # the emission buffer a compile-time structure instead of a device
         # array, so applying emissions needs no compaction scatters.
         def col_adds(plane, tau):
-            """``[plane[:, k] + tau[k] for k]``.  Natively per column: a
-            static-k slice fuses into its elementwise consumers, so the
-            round never materializes an [NR, NA] f64 temporary.  In
-            software binary64 as ONE [NR, NA] add: each software add is a
-            large program, and NA copies of it multiply compile time."""
+            """``[plane[k] + tau[k] for k]`` over an [NA, NR] plane: one
+            [NR] row per accelerator.  Natively per row: a static-k slice
+            fuses into its elementwise consumers, so the round never
+            materializes an [NA, NR] f64 temporary.  In software binary64
+            as ONE [NA, NR] add: each software add is a large program, and
+            NA copies of it multiply compile time."""
             if soft:
-                full = F.add(plane, tau[None, :])
-                return [full[:, k] for k in range(NA)]
-            return [F.add(plane[:, k], tau[k]) for k in range(NA)]
+                full = F.add(plane, tau[:, None])
+                return [full[k] for k in range(NA)]
+            return [F.add(plane[k], tau[k]) for k in range(NA)]
 
         # Both kernels read the round's rows (all NR slots, or the W-slot
         # window): ``st``'s per-request planes, ``ready``, and the arrival
@@ -427,7 +422,7 @@ def _run_trials(
                     kv = F.argmin(vv).astype(I32)
                     use_var = ~any_o
                     k_sel = jnp.where(any_o, ko, kv)
-                    c = jnp.where(use_var, st.c_latv[i, k_sel], st.c_lat[i, k_sel])
+                    c = jnp.where(use_var, st.c_latv[k_sel, i], st.c_lat[k_sel, i])
                     picks.append((valid, i, k_sel, use_var, c))
                     hitk = (NAa == k_sel) & valid
                     c1 = jnp.where(hitk, c, c1)
@@ -445,7 +440,7 @@ def _run_trials(
                     for kk in range(1, NA):
                         f0 = F.minimum(f0, fo_k[kk])
                     s_star = F.sub(st.c_vdl, f0)
-                    cv = st.c_latv[:, k]
+                    cv = st.c_latv[k]
                     if mode == "ef":
                         fv_k = col_adds(st.c_latv, tau)
                         ev = fv_k[0]
@@ -471,7 +466,7 @@ def _run_trials(
                     tb = jnp.where(F.eq(d_sel, best), keys, INF)
                     i = F.argmin(tb).astype(I32)  # earliest in stage-1 order
                     use_var = ~orig_wins
-                    c = jnp.where(use_var, st.c_latv[i, k], st.c_lat[i, k])
+                    c = jnp.where(use_var, st.c_latv[k, i], st.c_lat[k, i])
                     picks.append((valid, i, jnp.asarray(k, I32), use_var, c))
                     tau = jnp.where((NAa == k) & valid, F.add(tau, c), tau)
                     alive = alive & ~((rows == i) & valid)
@@ -495,12 +490,12 @@ def _run_trials(
                 i = F.argmin(mk).astype(I32)
                 ok_i = F.lt(mk[i], INF)
                 if kind == "dream":
-                    vals = jnp.where(idle, F.add(tau0, st.c_lat[i]), INF)
+                    vals = jnp.where(idle, F.add(tau0, st.c_lat[:, i]), INF)
                 else:   # fcfs/edf: lowest latency, first-min ascending k
-                    vals = jnp.where(idle, st.c_lat[i], INF)
+                    vals = jnp.where(idle, st.c_lat[:, i], INF)
                 k = F.argmin(vals).astype(I32)
                 valid = ok_i & F.lt(vals[k], INF)
-                c = st.c_lat[i, k]
+                c = st.c_lat[k, i]
                 picks.append((valid, i, k, fK, c))
                 idle = idle & ~((NAa == k) & valid)
                 alive = alive & ~((rows == i) & valid)
@@ -733,8 +728,8 @@ def _run_trials(
                     rm_v = rm_f[m_all, jnp.minimum(l_all, LP1i)]
                     ek_v = F.sub(d_abs, rm_f[m_all, jnp.minimum(l_all + 1, LP1i)])
                     stk = st._replace(
-                        c_lat=F.mul(st.c_lat, mult[None, :]),
-                        c_latv=F.mul(st.c_latv, mult[None, :]),
+                        c_lat=F.mul(st.c_lat, mult[:, None]),
+                        c_latv=F.mul(st.c_latv, mult[:, None]),
                         c_vdl=vdl_v, c_vdln=vdln_v, c_nm=nm_v,
                         c_rm=rm_v, c_ek=ek_v,
                     )
@@ -758,10 +753,10 @@ def _run_trials(
                     first = jnp.argmax(ready).astype(I32)
                     base = jnp.minimum(first - first % ROUND_ALIGN, NR - W)
 
-                    def rows_w(x):
-                        out = x[NR - W:]
+                    def rows_w(x):  # the slot axis is the last, [NR] or [NA, NR]
+                        out = x[..., NR - W:]
                         for b0 in range(0, NR - W, ROUND_ALIGN):
-                            out = jnp.where(base == b0, x[b0:b0 + W], out)
+                            out = jnp.where(base == b0, x[..., b0:b0 + W], out)
                         return out
 
                     stw = stk._replace(**{f: rows_w(getattr(stk, f)) for f in (
@@ -863,7 +858,7 @@ def _run_trials(
             cnt=jnp.asarray(0, I32), rounds=jnp.asarray(0, I32),
             done_ctr=jnp.asarray(0, I32),
             state=z(NR, I32), layer=z(NR, I32),
-            c_lat=F.full((NR, NA), _INF), c_latv=F.full((NR, NA), _INF),
+            c_lat=F.full((NA, NR), _INF), c_latv=F.full((NA, NR), _INF),
             c_vdl=fz(NR), c_vdln=fz(NR), c_nm=fz(NR),
             c_rm=F.full(NR, _INF), c_ek=fz(NR),
             ret=F.full(NR, 1.0), app_seq=jnp.full((NR, LP), -1, I32),

@@ -301,6 +301,30 @@ def test_saturation_keeps_every_slot():
 
 
 @pytest.mark.parametrize("arith", ["native", "soft"])
+def test_unwindowed_round_past_128_slots_matches_soa(arith, monkeypatch):
+    """Saturation traffic padded to 192 slots keeps every slot in its
+    round (``win == nr_pad``): the latency planes are bound and read at
+    full width past 128 slots, and every lane stays fingerprint-identical
+    to SoA in both arithmetics."""
+    from repro.core import f64, obs
+
+    plans, tasks = SATURATION_SCENARIOS["saturation_5x"].plans(PLATFORMS[PLATFORM])
+    procs = _procs(tasks, "poisson")
+    if arith == "soft":
+        monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
+    with obs.record() as rec:
+        batch = simulate_batch(plans, tasks, 0.15, make_scheduler("terastal"),
+                               SEEDS, processes=procs)
+    monkeypatch.undo()
+    c = rec.batches[0]["counters"]
+    assert c["nr_pad"] == c["round_slots"] == 192
+    ref = [simulate(plans, tasks, 0.15, make_scheduler("terastal"), seed=s,
+                    processes=procs, engine="soa").fingerprint() for s in SEEDS]
+    for s, res, want in zip(SEEDS, batch, ref):
+        assert res.fingerprint() == want, (arith, s)
+
+
+@pytest.mark.parametrize("arith", ["native", "soft"])
 @pytest.mark.parametrize("sched_spec", [
     "terastal", "terastal(backfill_mode=paper)", "edf",
 ])

@@ -1,13 +1,13 @@
 """Compile rehearsals for a TPU v5e chip that is described, not attached.
 
 Each test compiles one program of the main path at its real size for one
-chip of a ``v5e:2x2`` topology: the batch engine's ``_run_trials`` at the
-headline campaign cell, the jitted Terastal round at NJ 256, the three
-Pallas kernels compiled (not interpreted) at model widths, and the
-llama3.2-1b decode step at published widths.  What the TPU compiler
-refuses — a block shape off the tiling, a Mosaic shape cast, a program
-that does not fit the chip — fails here.  Nothing runs, so these say
-nothing about results or times.
+chip of a ``v5e:2x2`` topology: the batch engine's ``_run_trials`` at
+three padded widths (and the layout of its latency planes there), the
+jitted Terastal round at NJ 256, the three Pallas kernels compiled (not
+interpreted) at model widths, and the llama3.2-1b decode step at
+published widths.  What the TPU compiler refuses — a block shape off the
+tiling, a Mosaic shape cast, a program that does not fit the chip —
+fails here.  Nothing runs, so these say nothing about results or times.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every pytest
@@ -17,6 +17,7 @@ worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,34 +56,90 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-def test_run_trials_compiles(one_chip, monkeypatch):
-    """The device-resident trial engine, in the software binary64 it runs
-    on a TPU, at the BENCH_batch headline cell (saturation_5x / 4k_1ws2os
-    / terastal / poisson, B=32, 0.1 s), which reads every slot in its
-    round, and at Table II rates over 2 s (multicam_light, Poisson), 256
-    slots whose round reads a 128-slot window.  About a minute each: the
-    software binary64 makes a large program."""
+# _run_trials in the software binary64 it runs on a TPU, B=32: the
+# BENCH_batch headline cell (saturation_5x / 4k_1ws2os / terastal /
+# poisson, 0.1 s, 96 slots), the same cell at a horizon that pads to 192
+# slots and keeps every one in its round, and Table II rates over 2 s
+# (multicam_light, Poisson), 256 slots whose round reads a 128-slot
+# window: (cell, horizon, padded slots, round slots)
+_RUN_TRIALS_PROGRAMS = [
+    ("saturation_5x", 0.1, 96, 96),
+    ("saturation_5x", 0.17, 192, 192),
+    ("multicam_light", 2.0, 256, "window"),
+]
+
+
+@pytest.fixture(scope="module")
+def run_trials_programs(one_chip):
+    """Each of ``_RUN_TRIALS_PROGRAMS`` staged and compiled once for the
+    module: ``(args, static, compiled)``.  About half a minute each."""
     from repro.core import f64
     from repro.core.campaign import _plans_for
-    from repro.core.engine_batch import ROUND_WINDOW, _run_trials, stage_batch
+    from repro.core.engine_batch import _run_trials, stage_batch
     from repro.core.scheduler import make_scheduler
     from repro.core.simulator import make_arrival_process
 
     proc = make_arrival_process("poisson")
-    # this process's backend is the CPU: stage as the TPU would
-    monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
-    for cell, horizon, nr, win in [("saturation_5x", 0.1, None, None),
-                                   ("multicam_light", 2.0, 256, ROUND_WINDOW)]:
-        plans, tasks = _plans_for(cell, "4k_1ws2os", 0.90, True)
-        staged = stage_batch(plans, tasks, horizon, make_scheduler("terastal"),
-                             list(range(32)), processes=[t.arrival or proc for t in tasks])
-        with jax.enable_x64(True):
-            args = _specs(staged.args, one_chip)
-            assert args[1].shape[0] == 32 and args[1].dtype == jnp.int64
-            if nr is not None:
-                assert args[2].shape[-1] == nr and staged.static["win"] == win
-            compiled = _run_trials.lower(*args, **staged.static).compile()
+    programs = []
+    with pytest.MonkeyPatch.context() as mp:
+        # this process's backend is the CPU: stage as the TPU would
+        mp.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
+        for cell, horizon, _, _ in _RUN_TRIALS_PROGRAMS:
+            plans, tasks = _plans_for(cell, "4k_1ws2os", 0.90, True)
+            staged = stage_batch(plans, tasks, horizon, make_scheduler("terastal"),
+                                 list(range(32)),
+                                 processes=[t.arrival or proc for t in tasks])
+            with jax.enable_x64(True):
+                args = _specs(staged.args, one_chip)
+                compiled = _run_trials.lower(*args, **staged.static).compile()
+            programs.append((args, staged.static, compiled))
+    return programs
+
+
+def test_run_trials_compiles(run_trials_programs):
+    """The device-resident trial engine compiles for one chip at each of
+    ``_RUN_TRIALS_PROGRAMS``, and its temporaries fit well inside it."""
+    from repro.core.engine_batch import ROUND_WINDOW
+
+    for (_, _, nr, win), (args, static, compiled) in zip(_RUN_TRIALS_PROGRAMS,
+                                                         run_trials_programs):
+        assert args[1].shape[0] == 32 and args[1].dtype == jnp.int64
+        assert args[2].shape[-1] == nr
+        assert static["win"] == (ROUND_WINDOW if win == "window" else win)
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# an instruction whose result is a [B, a, b] array, with its layout's
+# minor-to-major order: the first entry is the dimension in the lanes
+_HLO_3D = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[32,(\d+),(\d+)\]\{(\d+),")
+
+
+def _planes_with_na_in_lanes(hlo: str, na: int) -> tuple[int, int]:
+    """(ops shaped like a latency plane, those with the NA axis minor).
+    A plane is ``[B, NA, slots]`` or ``[B, slots, NA]``, slots >= 32."""
+    planes = na_minor = 0
+    for line in hlo.splitlines():
+        m = _HLO_3D.match(line)
+        if m is None:
+            continue
+        a, b, minor = (int(g) for g in m.groups())
+        if na not in (a, b) or max(a, b) < 32:
+            continue
+        planes += 1
+        na_minor += minor == (1 if a == na else 2)
+    return planes, na_minor
+
+
+def test_run_trials_latency_planes_keep_slots_in_lanes(run_trials_programs):
+    """No op shaped like a per-request latency plane puts the NA
+    accelerators in the 128 lanes, at 96 slots, 192 slots and 256 slots
+    read through the 128-slot window: with NA = 3 minor, one u32 op at
+    192 slots touches 768 vregs where slots in the lanes touch 24."""
+    for (cell, horizon, nr, _), (args, _, compiled) in zip(_RUN_TRIALS_PROGRAMS,
+                                                           run_trials_programs):
+        planes, na_minor = _planes_with_na_in_lanes(compiled.as_text(),
+                                                    args[0].lat.shape[-1])
+        assert planes > 0 and na_minor == 0, (cell, horizon, nr, planes, na_minor)
 
 
 def test_terastal_round_compiles(one_chip):
