@@ -4,35 +4,26 @@ The deep-queue regime (saturation scenarios: overloaded multi-camera
 cells, 3-8x offered load, mixed release processes) is where the
 scheduling round itself — not event bookkeeping — bounds campaign
 throughput.  This benchmark measures one Terastal round at controlled
-queue depths for all three kernel implementations:
+queue depths for both SoA kernel implementations:
 
-* ``scalar`` — the pre-existing interpreted kernel
-  (``engine_soa._kern_terastal``), the "current kernel" baseline;
+* ``scalar`` — the interpreted kernel (``engine_soa._kern_terastal``),
+  the baseline, which the engine runs below ``VEC_MIN_NJ``;
 * ``vec`` — the vectorized deep-round kernel
-  (``engine_soa._kern_terastal_vec``), what ``REPRO_ROUND_KERNEL=python``
-  dispatches to above ``VEC_MIN_NJ``;
-* ``jax`` — the jitted ``scheduler_jax.terastal_round`` through the
-  engine's ``_jax_round`` staging path (``REPRO_ROUND_KERNEL=jax``).
+  (``engine_soa._kern_terastal_vec``), which it runs at or above it.
 
 Round states are *captured from real saturation trials* (block clones
 snapshotted mid-simulation at target depths), so the instance mix —
 idle-accelerator counts, stage-2 frequency, variant availability — is
-the true deep-queue distribution, not a synthetic best case.  All three
+the true deep-queue distribution, not a synthetic best case.  Both
 kernels are re-run on identical clones; outputs are asserted equal
 instance-by-instance, and a full-simulation differential section pins
-``SimResult`` equality (reference engine vs SoA x round kernels x
-backfill modes) on fig5/fig7/fig8-shaped cells and the saturation grid.
+``SimResult`` equality (reference engine vs SoA x backfill modes) on
+fig5/fig7/fig8-shaped cells and the saturation grid.
 
-The python->jax crossover for ``REPRO_ROUND_KERNEL=auto`` is measured
-here (the smallest depth where the jitted round beats the vectorized
-one) and recorded in the JSON; on CPU-only hosts per-call dispatch
-(~1ms) keeps it at infinity — auto == python — which is an honest
-negative result, not a wiring gap.  ``REPRO_ROUND_CROSSOVER`` pins it
-manually on hosts where the measurement differs.
-
-Writes ``BENCH_round.json``.  CI runs ``--smoke`` as a dedicated step
-that FAILS on a floor regression (unlike the informational run.py smoke
-claims): aggregate vec rounds/sec over deep rounds (NJ >= 64) must stay
+Times are host time on the CPU (``time.perf_counter``), recorded as
+``clock`` in the JSON.  Writes ``BENCH_round.json``.  CI runs
+``--smoke`` as a dedicated step that FAILS on a floor regression
+(unlike the informational run.py smoke claims): aggregate vec rounds/sec over deep rounds (NJ >= 64) must stay
 >= MIN_DEEP_SPEEDUP x the scalar kernel.  Honest per-NJ scorecard: the
 vectorized round has a ~13us flat numpy-dispatch floor, so the 3x line
 is crossed between NJ ~ 64 and 96 (~2.6x at exactly 64, ~4-7x at
@@ -91,21 +82,18 @@ def _capture_instances(buckets, per_bucket: int, duration: float, seeds):
             targets[n].append((B.clone(), now, list(busy), idle_mask, n_idle, mode))
         return orig(B, now, busy, idle_mask, n_idle, mode)
 
-    old_env = os.environ.get("REPRO_ROUND_VEC_MIN")
-    os.environ["REPRO_ROUND_VEC_MIN"] = "2"
+    vec_min = engine_soa.VEC_MIN_NJ
+    engine_soa.VEC_MIN_NJ = 2
     engine_soa._kern_terastal_vec = capture
     try:
         for sc, pn in SATURATION_CELLS:
             plans, tasks = _plans_for(sc, pn, 0.90, True)
             for seed in seeds:
                 simulate(plans, tasks, duration, make_scheduler("terastal"),
-                         seed=seed, engine="soa", round_kernel="python")
+                         seed=seed, engine="soa")
     finally:
         engine_soa._kern_terastal_vec = orig
-        if old_env is None:
-            del os.environ["REPRO_ROUND_VEC_MIN"]
-        else:
-            os.environ["REPRO_ROUND_VEC_MIN"] = old_env
+        engine_soa.VEC_MIN_NJ = vec_min
     return {nj: inst for nj, inst in targets.items() if inst}
 
 
@@ -121,38 +109,26 @@ def _time_kernel(fn, instances, reps: int) -> float:
     return (time.perf_counter() - t0) / (reps * len(instances)) * 1e6
 
 
-def _measure(targets, reps: int, with_jax: bool):
+def _measure(targets, reps: int):
     from repro.core import engine_soa
 
     rows = []
     for nj in sorted(targets):
         inst = targets[nj]
-        # identical outputs on every captured instance, all kernels
+        # identical outputs on every captured instance, both kernels
         for B, now, busy, idle_mask, n_idle, mode in inst:
             a = engine_soa._kern_terastal(B, now, busy, idle_mask, n_idle, mode)
             b = engine_soa._kern_terastal_vec(B, now, busy, idle_mask, n_idle, mode)
             assert a == b, f"scalar/vec round mismatch at NJ={nj}"
         t_scalar = _time_kernel(engine_soa._kern_terastal, inst, reps)
         t_vec = _time_kernel(engine_soa._kern_terastal_vec, inst, reps)
-        row = {
+        rows.append({
             "nj": nj,
             "instances": len(inst),
             "us_scalar": round(t_scalar, 1),
             "us_vec": round(t_vec, 1),
             "speedup_vec": round(t_scalar / t_vec, 2),
-        }
-        if with_jax:
-            jx = [(B, now, busy, idle_mask, len(busy), mode)
-                  for B, now, busy, idle_mask, n_idle, mode in inst]
-            for (B, now, busy, idle_mask, n_idle, mode), ja in zip(inst, jx):
-                got = engine_soa._jax_round(*ja)  # also warms the bucket
-                ref = engine_soa._kern_terastal(B, now, busy, idle_mask,
-                                                n_idle, mode)
-                assert got == ref, f"jax round mismatch at NJ={nj}"
-            t_jax = _time_kernel(engine_soa._jax_round, jx, max(1, reps // 10))
-            row["us_jax"] = round(t_jax, 1)
-            row["speedup_jax"] = round(t_scalar / t_jax, 2)
-        rows.append(row)
+        })
     return rows
 
 
@@ -171,9 +147,9 @@ def _aggregate_deep(rows) -> Optional[float]:
 # ------------------------------------------------- simulation parity ----
 
 
-def _differential(small: bool, with_jax: bool):
-    """SimResult equality: reference engine vs SoA x round kernels, both
-    backfill-mode ablations, on fig-shaped and saturation cells."""
+def _differential(small: bool):
+    """SimResult equality: reference engine vs SoA, both backfill-mode
+    ablations, on fig-shaped and saturation cells."""
     from repro.core.campaign import _plans_for
     from repro.core.scheduler import make_scheduler
     from repro.core.simulator import make_arrival_process, simulate
@@ -192,7 +168,6 @@ def _differential(small: bool, with_jax: bool):
             ("saturation_8x", "6k_1ws2os", None, 0.8),
         ]
         scheds += ["terastal_no_variants", "terastal_no_budgeting"]
-    kernels = ["python"] + (["jax"] if with_jax else [])
     checked = 0
     for sc, pn, arr, dur in cells:
         plans, tasks = _plans_for(sc, pn, 0.90, True)
@@ -201,14 +176,12 @@ def _differential(small: bool, with_jax: bool):
             ref = simulate(
                 plans, tasks, dur, make_scheduler(sched), seed=0,
                 processes=procs, engine="reference").fingerprint()
-            for kern in kernels:
-                got = simulate(
-                    plans, tasks, dur, make_scheduler(sched), seed=0,
-                    processes=procs, engine="soa",
-                    round_kernel=kern).fingerprint()
-                if got != ref:
-                    return checked, False, f"{sc}/{sched}/{kern}"
-                checked += 1
+            got = simulate(
+                plans, tasks, dur, make_scheduler(sched), seed=0,
+                processes=procs, engine="soa").fingerprint()
+            if got != ref:
+                return checked, False, f"{sc}/{sched}"
+            checked += 1
     return checked, True, ""
 
 
@@ -217,7 +190,6 @@ def _differential(small: bool, with_jax: bool):
 
 def run(duration: float = None) -> List[dict]:
     from benchmarks._scale import bench_duration, bench_mode
-    from repro.core import engine_soa
 
     mode = bench_mode()
     smoke = mode == "smoke"
@@ -227,28 +199,17 @@ def run(duration: float = None) -> List[dict]:
     per_bucket = {"smoke": 8, "fast": 12}.get(mode, 24)
     reps = {"smoke": 30, "fast": 60}.get(mode, 120)
     seeds = (0, 1) if mode == "full" else (0,)
-    # the jitted-round path needs jax; measure it except when a host
-    # explicitly opts out (keeps the bench usable on jax-less builds)
-    with_jax = not os.environ.get("REPRO_BENCH_NO_JAX")
 
     targets = _capture_instances(buckets, per_bucket, duration, seeds)
-    rows = _measure(targets, reps, with_jax)
+    rows = _measure(targets, reps)
     agg = _aggregate_deep(rows)
 
-    # python->jax crossover for REPRO_ROUND_KERNEL=auto: the smallest
-    # measured depth where the jitted round wins; +inf when it never does
-    crossover: Optional[float] = None
-    if with_jax:
-        wins = [r["nj"] for r in rows
-                if "us_jax" in r and r["us_jax"] < r["us_vec"]]
-        crossover = float(min(wins)) if wins else float("inf")
-        engine_soa.set_round_crossover(crossover)
-
-    n_diff, identical, where = _differential(mode != "full", with_jax)
+    n_diff, identical, where = _differential(mode != "full")
 
     summary = {
         "benchmark": "scheduler_round",
         "mode": mode,
+        "clock": "host CPU time (time.perf_counter), no accelerator",
         "grid": {
             "cells": [list(c) for c in SATURATION_CELLS],
             "buckets": list(targets),
@@ -260,9 +221,6 @@ def run(duration: float = None) -> List[dict]:
         "aggregate_deep_speedup_vec": agg,
         "deep_min_nj": DEEP_MIN_NJ,
         "min_deep_speedup_enforced": MIN_DEEP_SPEEDUP,
-        "jax_crossover_nj": (None if crossover is None
-                             else ("inf" if crossover == float("inf")
-                                   else crossover)),
         "differential": {"simulations": n_diff, "bit_identical": identical,
                          "first_mismatch": where},
     }
@@ -271,7 +229,6 @@ def run(duration: float = None) -> List[dict]:
         f.write("\n")
     return rows + [{
         "aggregate_deep_speedup_vec": agg,
-        "jax_crossover_nj": summary["jax_crossover_nj"],
         "bit_identical": identical,
         "differential_simulations": n_diff,
         "first_mismatch": where,
@@ -287,8 +244,7 @@ def claims(rows: List[dict]):
          f"scalar kernel at NJ >= {DEEP_MIN_NJ} (saturation instance mix)",
          agg is not None and agg >= MIN_DEEP_SPEEDUP,
          f"aggregate {agg}x over deep buckets"),
-        ("SimResults bit-identical: reference vs SoA x round kernels x "
-         "backfill modes",
+        ("SimResults bit-identical: reference vs SoA x backfill modes",
          bool(tail["bit_identical"]),
          f"{tail['differential_simulations']} simulations compared"
          + (f"; first mismatch {tail.get('first_mismatch')}" if not
@@ -305,7 +261,6 @@ def check_json(path: str = JSON_PATH):
         summary = json.load(f)
     tail = {
         "aggregate_deep_speedup_vec": summary["aggregate_deep_speedup_vec"],
-        "jax_crossover_nj": summary.get("jax_crossover_nj"),
         "bit_identical": summary["differential"]["bit_identical"],
         "differential_simulations": summary["differential"]["simulations"],
         "first_mismatch": summary["differential"].get("first_mismatch"),

@@ -16,9 +16,10 @@ Phases:
 * ``engine``  — ``TrialExecutor`` runs ``engine="batch"`` specs, 32 seeds,
   in three cells; every lane's ``SimResult.fingerprint()`` must equal
   ``simulate(engine="soa")``'s.
-* ``round``   — the jitted Terastal round on round states cloned from
-  saturation trials at NJ 64 and in the NJ-256 bucket, against the
-  Python kernel: assignments, variants and emission order exactly.
+* ``round``   — the jitted Terastal round, staged by ``pack_view``, on
+  live scheduler views of reference-engine saturation trials at NJ 64
+  and in the NJ-256 bucket, against ``TerastalScheduler.schedule``:
+  requests, accelerators, variants and emission order exactly.
 * ``kernels`` — the three Pallas kernels compiled for the chip (a
   ``tpu_custom_call`` in the program, never interpreted) at model widths,
   against their jnp oracles run on the host CPU backend, at the
@@ -190,37 +191,67 @@ def phase_engine(dev, cpu):
 # ----------------------------------------------------------- round ----
 
 
-def phase_round(dev, cpu):
-    from benchmarks.bench_scheduler_round import _capture_instances
-    from repro.core import engine_soa
-    from repro.core.scheduler_jax import bucket_nj
+class _Captured(Exception):
+    """Ends the round phase's capture once both groups are full."""
 
-    # one instance per depth in the NJ-256 bucket, eight at NJ 64
-    targets = _capture_instances((64,) + tuple(range(193, 257)), 8, 1.0, (0,))
-    groups = {64: targets.get(64, [])[:8],
-              256: [i for nj, inst in sorted(targets.items()) if bucket_nj(nj) == 256
-                    for i in inst[:1]][-8:]}
+
+def phase_round(dev, cpu):
+    from benchmarks.bench_scheduler_round import SATURATION_CELLS
+    from repro.core.campaign import _plans_for
+    from repro.core.scheduler import make_scheduler
+    from repro.core.scheduler_jax import pack_view, terastal_round
+    from repro.core.simulator import simulate
+
+    # eight live views at NJ 64, and one per depth in the NJ-256 bucket
+    groups = {64: dict(nj=[], walls=[], mismatches=0),
+              256: dict(nj=[], walls=[], mismatches=0)}
+
+    def controller(view, sched):
+        inp, reqs = pack_view(view, sched)
+        out = terastal_round(inp, mode=sched.backfill_mode)
+        nj = len(reqs)
+        acc, var, seq = (np.asarray(a)[:nj] for a in
+                         (out.assign_acc, out.assign_var, out.assign_seq))
+        hit = np.flatnonzero(acc >= 0)
+        return [(reqs[i].rid, int(acc[i]), bool(var[i]))
+                for i in hit[np.argsort(seq[hit])]]
+
+    for sc, pn in SATURATION_CELLS:
+        plans, tasks = _plans_for(sc, pn, 0.90, True)
+        sched = make_scheduler("terastal")
+        schedule = sched.schedule
+
+        def hook(view):
+            nj = len(view.ready)
+            g = groups[64] if nj == 64 else groups[256] if 193 <= nj <= 256 else None
+            if g is not None and len(g["nj"]) < 8 and (nj == 64 or nj not in g["nj"]):
+                got, dt = _timed(controller, view, sched)
+                if "compile_s" in g:
+                    g["walls"].append(dt)
+                else:
+                    g["compile_s"] = dt
+                ref = schedule(view)
+                g["mismatches"] += got != [(a.req.rid, a.acc, a.use_variant) for a in ref]
+                g["nj"].append(nj)
+                if all(len(x["nj"]) >= 8 for x in groups.values()):
+                    raise _Captured
+                return ref
+            return schedule(view)
+
+        sched.schedule = hook
+        try:
+            simulate(plans, tasks, 1.0, sched, seed=0, engine="reference")
+        except _Captured:
+            break
     rows = []
-    for nj_bucket, inst in groups.items():
-        if not inst:
-            rows.append(dict(phase="round", nj_bucket=nj_bucket, instances=0, ok=False))
-            continue
-        first_s = None
-        walls, mismatches = [], 0
-        for B_, now, busy, idle_mask, n_idle, mode in inst:
-            got, dt = _timed(engine_soa._jax_round, B_, now, busy, idle_mask, len(busy), mode)
-            if first_s is None:
-                first_s = dt
-            else:
-                walls.append(dt)
-            ref = engine_soa._kern_terastal(B_, now, busy, idle_mask, n_idle, mode)
-            mismatches += got != ref
+    for nj_bucket, g in groups.items():
         rows.append(dict(
-            phase="round", nj_bucket=nj_bucket, nj=[b[0].n for b in inst],
-            instances=len(inst), compile_s=first_s,
-            wall_s=float(np.median(walls)) if walls else None,
-            compared="(slot, acc, variant, cost) in emission order vs the Python kernel",
-            mismatches=mismatches, ok=mismatches == 0))
+            phase="round", nj_bucket=nj_bucket, nj=g["nj"], instances=len(g["nj"]),
+            compile_s=g.get("compile_s"),
+            wall_s=float(np.median(g["walls"])) if g["walls"] else None,
+            compared="(rid, acc, variant) in emission order, pack_view + terastal_round "
+                     "vs TerastalScheduler.schedule on live reference-engine views",
+            mismatches=g["mismatches"], ok=bool(g["nj"]) and g["mismatches"] == 0))
     return rows
 
 

@@ -83,11 +83,6 @@ class TrialSpec:
     # pool tasks; unsupported axes raise BatchUnsupportedError rather
     # than silently falling back.
     engine: str = "auto"
-    # Terastal round kernel for deep ready queues: "auto" | "python" |
-    # "jax" — see repro.core.engine_soa.ROUND_KERNELS.  Like ``engine``,
-    # bit-identical by construction (pinned by the round-kernel
-    # differential tests); a perf knob, never a result knob.
-    round_kernel: str = "auto"
     # Accelerator fault-model call-spec (see repro.core.faults):
     # "scenario" (the default) resolves to the scenario's own
     # ``Scenario.faults`` — "none" for every pre-fault-axis catalog, so
@@ -197,9 +192,9 @@ def _init_worker(keys: Sequence[Tuple[str, str, float, bool]]) -> None:
 
 
 def _runs_in_parent(spec: TrialSpec) -> bool:
-    """Specs that run a device program: the batch engine, and the jitted
-    Terastal round.  They run in the parent, never in a pool worker."""
-    return spec.engine == "batch" or spec.round_kernel == "jax"
+    """Specs that run a device program (the batch engine) run in the
+    parent, never in a pool worker."""
+    return spec.engine == "batch"
 
 
 #: test hook (tests/test_executor_crash.py): when set, :func:`run_trial`
@@ -277,7 +272,6 @@ def run_trial(spec: TrialSpec) -> TrialResult:
         budget_policy=spec.budget_policy,
         admission=spec.admission,
         engine=spec.engine,
-        round_kernel=spec.round_kernel,
         faults=_resolve_faults(spec),
     )
     agg = {"released": 0, "completed": 0, "dropped": 0, "variants_applied": 0,
@@ -732,7 +726,6 @@ class Campaign:
     thetas: Sequence[float] = (0.90,)
     enable_variants: bool = True
     engine: str = "auto"  # simulator engine for every trial in the grid
-    round_kernel: str = "auto"  # Terastal round kernel (engine_soa.ROUND_KERNELS)
     # Fault-model axis: "scenario" defers to each scenario's own default
     # (fault-free outside FAULT_SCENARIOS); explicit call-specs compare
     # fault shapes on one workload.
@@ -776,7 +769,6 @@ class Campaign:
                                                 budget_policy=pol,
                                                 admission=adm,
                                                 engine=self.engine,
-                                                round_kernel=self.round_kernel,
                                                 faults=flt,
                                             )
                                         )

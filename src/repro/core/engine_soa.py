@@ -74,16 +74,10 @@ queue-depth threshold the engine switches representation and kernel:
   round-local tau of *all* accelerators through ``s*``, so heap keys
   go stale on every assignment and exact revalidation costs more than
   the vectorized rescan.)
-* rounds deeper than a calibrated crossover can ride the **jitted
-  kernel** (``scheduler_jax.terastal_round``): the block mirrors stage
-  into ``pack_arrays``'s persistent pow2 bucket buffers (batched
-  host->device copies) and the outputs come back in one device sync,
-  in the exact reference emission order via ``assign_seq``.  Kernel
-  choice: ``REPRO_ROUND_KERNEL`` in {python, jax, auto}; "auto" uses
-  the jitted round only above :func:`round_crossover` (env
-  ``REPRO_ROUND_CROSSOVER``, or set from measurement by
-  ``benchmarks/bench_scheduler_round.py`` — on CPU-only hosts the
-  measured crossover is typically infinity and auto == python).
+* a third route, the jitted ``scheduler_jax.terastal_round`` fed from
+  the deep mirrors, was removed: it never won a measurement (979-1436 us
+  a round against 18-20 us for the vectorized round at NJ 24-192, CPU
+  host time, ``BENCH_round.json``).
 
 Bit-parity is enforced by differential tests (``tests/test_engine_soa.py``):
 every ``SimResult`` field — per-model counters, ``retained_sum`` floats,
@@ -95,7 +89,6 @@ boundary (``tests/test_round_kernels.py``).
 
 from __future__ import annotations
 
-import os
 from bisect import bisect_left, insort
 from heapq import heappop, heappush
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -146,86 +139,15 @@ _ONE = (0,)
 
 # ------------------------------------------------- round-kernel dispatch ----
 
-#: Terastal round-kernel choices: "python" (scalar/vectorized kernels,
-#: depth-dispatched), "jax" (force the jitted ``terastal_round`` for
-#: every block round), "auto" (python below :func:`round_crossover`,
-#: jitted above).  Per-trial override: ``simulate(round_kernel=...)`` /
-#: ``TrialSpec.round_kernel``; process-wide: ``REPRO_ROUND_KERNEL``.
-ROUND_KERNELS = ("auto", "python", "jax")
-
 #: ready-queue depth at which a Terastal/DREAM round switches from the
 #: scalar kernel to the vectorized one (and the block activates its deep
 #: mirrors).  Calibrated on captured saturation-round states (see
 #: ``benchmarks/bench_scheduler_round.py``): the vectorized round costs
 #: a ~13us flat floor of numpy dispatch, which the scalar kernel crosses
 #: between NJ ~ 24 and 32; by NJ >= 64 the vectorized round is 2.6-7x
-#: faster and essentially depth-independent.  ``REPRO_ROUND_VEC_MIN``
-#: overrides (tests use it to force either path at any depth).
+#: faster and essentially depth-independent.  Read at call time, so tests
+#: can patch it to force either path at any depth.
 VEC_MIN_NJ = 24
-
-_round_crossover: Optional[float] = None
-
-#: memoized (raw env string, parsed value) for :func:`round_crossover` —
-#: campaign trials call it once per simulation, and every pool worker
-#: re-resolves it from a fresh process, so the parse is cached on the
-#: raw string (``REPRO_ROUND_CROSSOVER=inf`` in particular hits this
-#: fast path instead of re-parsing per trial).
-_crossover_env: Tuple[Optional[str], float] = (None, _INF)
-
-
-def _vec_min() -> int:
-    env = os.environ.get("REPRO_ROUND_VEC_MIN")
-    return int(env) if env else VEC_MIN_NJ
-
-
-def round_crossover() -> float:
-    """NJ above which ``REPRO_ROUND_KERNEL=auto`` rides the jitted round.
-
-    Resolution order: ``REPRO_ROUND_CROSSOVER`` env (a number, or
-    ``inf``), else the value installed by :func:`set_round_crossover`
-    (``benchmarks/bench_scheduler_round.py`` measures and installs it at
-    benchmark-smoke time), else +inf — the honest default for CPU-only
-    hosts, where per-round dispatch overhead keeps the jitted kernel
-    behind the vectorized Python round at every measured depth.
-
-    When the resolved value is INF, ``simulate_soa`` drops the jax
-    branch from its per-round dispatch entirely (``jax_on`` below):
-    ``auto`` is then end-to-end identical to ``round_kernel="python"``
-    and never imports ``scheduler_jax`` (pinned by
-    ``tests/test_round_kernels.py::test_auto_inf_crossover_is_python``)."""
-    global _crossover_env
-    env = os.environ.get("REPRO_ROUND_CROSSOVER")
-    if env:
-        raw, val = _crossover_env
-        if raw != env:
-            val = float(env)
-            _crossover_env = (env, val)
-        return val
-    if _round_crossover is not None:
-        return _round_crossover
-    return _INF
-
-
-def set_round_crossover(nj: Optional[float]) -> None:
-    """Install a measured python->jax crossover depth (None clears)."""
-    global _round_crossover
-    _round_crossover = None if nj is None else float(nj)
-
-
-_SJ = None  # lazily imported repro.core.scheduler_jax (pulls in jax)
-
-
-def _jax_mod():
-    """Lazy scheduler_jax import: a process whose rounds stay on the
-    Python kernels never imports jax.  The jitted round scopes its f64
-    arithmetic with ``scheduler_jax.x64``; the process default stays
-    32-bit."""
-    global _SJ
-    if _SJ is None:
-        from repro.core import scheduler_jax
-
-        _SJ = scheduler_jax
-    return _SJ
 
 
 def supports_scheduler(scheduler: Scheduler) -> bool:
@@ -282,7 +204,7 @@ class _ReadyBlock:
         self.deep = False
         self.rid_arr = None  # [cap] int64 (terastal/dream vec kernels)
         self.dl_arr = None  # [cap] (dream vec order)
-        self.vdl_arr = None  # [cap] (terastal vec/jax rounds)
+        self.vdl_arr = None  # [cap] (terastal vec round)
         self.vdl_next_arr = None
         self.next_min_arr = None
         self.lat_arr = None  # [cap, n_acc]
@@ -908,45 +830,6 @@ def _kern_terastal_vec(B, now, busy, idle_mask, n_idle, mode):
     return out
 
 
-def _jax_round(B, now, busy, idle_mask, n_acc, mode):
-    """One Terastal round on the jitted kernel (``REPRO_ROUND_KERNEL=jax``
-    or NJ past the calibrated crossover): stage the deep mirrors into
-    ``pack_arrays``'s persistent bucket buffers in ascending-rid order
-    (stable argsort ties == (slack, rid)), run ``terastal_round``, and
-    fetch all three outputs in one device sync.  ``assign_seq`` restores
-    the reference emission order, which fixes how simultaneous finish
-    events tie-break downstream."""
-    SJ = _jax_mod()
-    n = B.n
-    perm = np.argsort(B.rid_arr[:n])
-    tau = np.array([b if b > now else now for b in busy])
-    idle = np.array([bool(idle_mask >> k & 1) for k in range(n_acc)])
-    inp = SJ.pack_arrays(
-        B.vdl_arr[:n][perm],
-        B.vdl_next_arr[:n][perm],
-        B.next_min_arr[:n][perm],
-        B.lat_arr[:, :n].T[perm],  # mirrors are [n_acc, cap]; pack [NJ, NA]
-        B.latv_arr[:, :n].T[perm],
-        tau,
-        idle,
-    )
-    o = SJ.terastal_round(inp, mode=mode)
-    acc, var, seq = SJ.jax.device_get((o.assign_acc, o.assign_var, o.assign_seq))
-    acc = acc[:n]
-    hit = np.flatnonzero(acc >= 0)
-    if not hit.size:
-        return []
-    emit = hit[np.argsort(seq[:n][hit])]
-    out = []
-    for i in emit:
-        slot = int(perm[i])
-        k = int(acc[i])
-        uv = bool(var[i])
-        row = B.latv[slot] if uv else B.lat[slot]
-        out.append((slot, k, uv, row[k]))
-    return out
-
-
 # --------------------------------------------------------------- engine ----
 
 _ARRIVAL, _FINISH, _TICK, _FAULT = 0, 1, 2, 3  # reference kind codes
@@ -960,21 +843,16 @@ def simulate_soa(
     seed: int,
     processes: Optional[Sequence[Optional[ArrivalProcess]]],
     policy: BudgetPolicy,
-    round_kernel: Optional[str] = None,
     admission: Optional[AdmissionPolicy] = None,
     fault_model: Optional[FaultModel] = None,
 ) -> SimResult:
     """SoA counterpart of ``_simulate_reference`` (same contract).
 
-    ``round_kernel`` selects the Terastal round implementation for deep
-    ready queues (see :data:`ROUND_KERNELS`); ``None`` falls back to the
-    ``REPRO_ROUND_KERNEL`` environment variable, then ``"auto"``.
-
-    An active ``fault_model`` forces the scalar kernels (the deep
-    mirrors, the vectorized round, and the jitted round cache per-slot
-    latency rows that every capability event would have to rewrite
-    wholesale — even an explicit ``round_kernel="jax"`` is downgraded,
-    which is bit-identical by construction, just not deep).  Fault
+    A Terastal or DREAM round runs the scalar kernel below
+    :data:`VEC_MIN_NJ` ready entries and the vectorized one at or above
+    it.  An active ``fault_model`` forces the scalar kernels (the deep
+    mirrors and the vectorized round cache per-slot latency rows that
+    every capability event would have to rewrite wholesale).  Fault
     events swap the hot plan tables for ``effective_plans`` copies and
     rewrite the live slot caches, so scheduling decisions match the
     reference loop float for float."""
@@ -1001,32 +879,11 @@ def simulate_soa(
     need_pref = need_fkey or need_ekey
     policy_inert = type(policy) in _INERT_POLICIES
 
-    # ---- round-kernel dispatch thresholds (deep-queue fast path) --------
-    # "auto" (the TrialSpec default) defers to the env var, mirroring how
-    # REPRO_SIM_ENGINE reaches campaign trials; an explicit python/jax
-    # argument always wins.
-    rk = round_kernel
-    if rk is None or rk == "auto":
-        rk = os.environ.get("REPRO_ROUND_KERNEL") or "auto"
-    if rk not in ROUND_KERNELS:
-        raise ValueError(f"unknown round kernel {rk!r} (have {ROUND_KERNELS})")
-    vec_min = _vec_min()
-    if terastal:
-        if rk == "jax":
-            jax_min = 1.0  # force the jitted round for every block round
-        elif rk == "python":
-            jax_min = _INF
-        else:
-            jax_min = round_crossover()
-        deep_min = jax_min if jax_min < vec_min else vec_min
-    else:
-        jax_min = _INF
-        deep_min = vec_min
-    # crossover-INF fast path: with no finite crossover the jitted round
-    # can never engage, so "auto" skips the per-round jax probe entirely
-    # and is end-to-end identical to round_kernel="python" (never even
-    # imports scheduler_jax — pinned by tests/test_round_kernels.py)
-    jax_on = jax_min != _INF
+    # ---- round-kernel dispatch threshold (deep-queue fast path) ---------
+    # the deep mirrors engage at vec_min ready entries; faults and DAG
+    # plans below raise deep_min to INF (scalar kernels only)
+    vec_min = VEC_MIN_NJ
+    deep_min = vec_min
 
     # hot per-plan scalar tables (cached on the plans, shared across trials)
     LAT = [p.lat_rows for p in plans]
@@ -1046,7 +903,7 @@ def simulate_soa(
     # ---- DAG axis (``repro.core.dag``) ----------------------------------
     # A DAG plan splits one logical request over sibling ready entries
     # (one per precedence-unblocked node) sharing a ``DagRun``.  The deep
-    # mirrors, the vectorized round, and the jitted round are disabled
+    # mirrors and the vectorized round are disabled
     # for the trial (their rid-keyed sort ties and per-slot drop masks
     # assume one entry per request) — the scalar kernels carry DAG sort
     # keys totalized with the node id, matching the reference schedulers.
@@ -1062,7 +919,7 @@ def simulate_soa(
     # at offline values (the original fault axis); ``retighten=true``
     # re-runs the tightening kernel and re-derives the admission tables
     # on every capability event (see ``_fault_refresh``).  The
-    # deep/vectorized/jitted fast paths are disabled for the whole trial
+    # deep/vectorized fast paths are disabled for the whole trial
     # (their mirrors cache rows a fault event would have to rewrite
     # wholesale).
     fm = fault_model if fault_model is not None and fault_model.active else None
@@ -1080,16 +937,12 @@ def simulate_soa(
         run_var = [False] * n_acc  # did the running layer apply a variant
         resume = fm.interrupted == "resume"
         deep_min = _INF
-        jax_min = _INF
-        jax_on = False
     if dag_present:
         # simulate() gates non-static budget policies off for DAG plans
         # before either engine runs (faults now compose — the fault
         # handlers below are DAG-aware), so only the kernel dispatch
         # needs forcing here
         deep_min = _INF
-        jax_min = _INF
-        jax_on = False
 
     # per-model stat accumulators (dict built in reference order at the end)
     released = [0] * n_plans
@@ -1783,9 +1636,7 @@ def simulate_soa(
             if n >= deep_min and not B.deep:
                 _activate_deep()
             if terastal:
-                if jax_on and n >= jax_min:
-                    out = _jax_round(B, now, busy, idle_mask, n_acc, mode)
-                elif B.deep and n >= vec_min:
+                if B.deep and n >= vec_min:
                     out = _kern_terastal_vec(B, now, busy, idle_mask, n_idle, mode)
                 else:
                     out = _kern_terastal(B, now, busy, idle_mask, n_idle, mode)

@@ -303,6 +303,16 @@ def _sched_of(cell: Tuple) -> str:
 _JOURNAL_FORMAT = "terastal-sampler-journal"
 _JOURNAL_VERSION = 1
 
+#: Campaign/TrialSpec fields that older journals store but the current
+#: dataclasses no longer have.  Each chose between bit-identical code
+#: paths and never changed a result, so reading drops it; any other
+#: unknown key is still a mismatch.
+_RETIRED_FIELDS = ("round_kernel",)
+
+
+def _without_retired(fields: Dict) -> Dict:
+    return {k: v for k, v in fields.items() if k not in _RETIRED_FIELDS}
+
 
 def _json_normalize(obj):
     """Canonical JSON value (tuples -> lists) for header comparison."""
@@ -324,16 +334,18 @@ def _rebuilt_header(head: Dict) -> Optional[Dict]:
     """Re-serialize a stored header through the CURRENT dataclasses.
 
     A journal written before a default-valued field existed (e.g.
-    ``Campaign.round_kernel``) stores a header without it; rebuilding
-    fills the default, so such journals stay resumable — exactly when
-    the resumed campaign is otherwise identical.  Returns ``None`` for
-    headers the current dataclasses cannot represent (removed/renamed
+    ``Campaign.faults``) stores a header without it; rebuilding fills
+    the default, and a retired field (:data:`_RETIRED_FIELDS`) is
+    dropped, so such journals stay resumable — exactly when the resumed
+    campaign is otherwise identical.  Returns ``None`` for headers the
+    current dataclasses cannot represent (other unknown or renamed
     fields), which the caller treats as a genuine mismatch."""
     try:
         return _header(
-            Campaign(**head["campaign"]), SamplerConfig(**head["config"])
+            Campaign(**_without_retired(head["campaign"])),
+            SamplerConfig(**head["config"]),
         )
-    except (KeyError, TypeError):
+    except (AttributeError, KeyError, TypeError):
         return None
 
 
@@ -344,7 +356,7 @@ def _result_record(res: TrialResult) -> Dict:
 
 
 def _result_from_record(rec: Dict) -> TrialResult:
-    spec = TrialSpec(**rec["spec"])
+    spec = TrialSpec(**_without_retired(rec["spec"]))
     fields = dict(rec["result"])
     fields["utilization"] = tuple(fields["utilization"])
     return TrialResult(spec=spec, **fields)

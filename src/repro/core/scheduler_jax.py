@@ -32,9 +32,8 @@ min(key=...); first-maximum argmax == strict-improvement replacement),
 property-tested in tests/test_scheduler_jax.py.  The round runs in
 binary64: every add/sub/compare is then the same IEEE op the Python
 kernels execute, so the jitted round is bit-identical on arbitrary
-latency tables, not just dyadic ones — a requirement for the engine
-dispatch path (``REPRO_ROUND_KERNEL=jax``), whose SimResults are pinned
-against the reference engine.  Its float operations go through
+latency tables, not just dyadic ones, so that it can serve as the
+online controller in the reference scheduler's place.  Its float operations go through
 :mod:`repro.core.f64`: ``jnp`` float64 where the backend is IEEE, the
 software binary64 on the TPU (whose float64 is not), with the packers
 staging float arrays as their int64 bit patterns there.
@@ -106,7 +105,7 @@ def _best_case_slack(F, inp: RoundInputs, tau: jax.Array) -> jax.Array:
 
 @x64
 def terastal_round(inp: RoundInputs, mode: str = "ef") -> RoundOutputs:
-    """One jitted Terastal round over :func:`pack_view`/:func:`pack_arrays`
+    """One jitted Terastal round over :func:`pack_view`
     inputs (compiles once per NJ bucket and mode: ``round_jit``), in the
     platform's binary64 (:func:`f64.for_platform`), as the packers
     staged them."""
@@ -318,43 +317,6 @@ def _stage(ready, vdl, vdl_next, next_min, lat, lat_var, tau, idle) -> RoundInpu
     )
 
 
-@x64
-def pack_arrays(
-    vdl: np.ndarray,
-    vdl_next: np.ndarray,
-    next_min: np.ndarray,
-    lat: np.ndarray,
-    lat_var: np.ndarray,
-    tau: np.ndarray,
-    idle: np.ndarray,
-) -> RoundInputs:
-    """Stage already-vectorized per-slot arrays into the persistent
-    bucket buffers — the SoA engine's deep-round path (its ready block
-    keeps these exact arrays as incrementally maintained mirrors, so the
-    host side of a jitted round is a handful of slice copies, not a
-    per-request Python loop like :func:`pack_view`).  Slots must arrive
-    in ascending-rid order (stable argsort ties = ``(slack, rid)``).
-    One host->device staging per field; same pow2 NJ shape buckets."""
-    NJ, NA = lat.shape
-    NJ_pad = bucket_nj(NJ)
-    buf = _buffers(NJ_pad, NA)
-    ready = buf["ready"]
-    ready[:NJ] = True
-    ready[NJ:] = False
-    for name, src, pad in (
-        ("vdl", vdl, 0.0),
-        ("vdl_next", vdl_next, 0.0),
-        ("next_min", next_min, 0.0),
-        ("lat", lat, np.inf),
-        ("lat_var", lat_var, np.inf),
-    ):
-        dst = buf[name]
-        dst[:NJ] = src
-        dst[NJ:] = pad
-    return _stage(ready, buf["vdl"], buf["vdl_next"], buf["next_min"],
-                  buf["lat"], buf["lat_var"], np.asarray(tau, np.float64), idle)
-
-
 # ------------------------------------------- batched trial staging ----
 
 #: persistent seed-major staging buffers for the device-resident trial
@@ -402,7 +364,7 @@ def bucket_ev(n: int) -> int:
 def pack_trials(events: "list[tuple]", deadline_by_model: np.ndarray):
     """Stage B seeds' pre-generated release events into the persistent
     seed-major trial buffers (the batched counterpart of
-    :func:`pack_arrays`).
+    :func:`pack_view`).
 
     ``events`` is ``[(times, models)]`` per seed — the output of
     ``workload.batch_release_events`` — and ``deadline_by_model`` maps

@@ -24,7 +24,6 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
-import os
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple, Union
 
 if TYPE_CHECKING:  # annotation only; the runtime import is lazy in simulate()
@@ -806,7 +805,7 @@ def drop_hopeless(
 #: engines accepted by :func:`simulate`; "auto" picks the SoA engine for
 #: the built-in scheduler classes and falls back to the reference event
 #: loop for custom ``Scheduler`` subclasses (whose ``schedule()`` needs a
-#: :class:`SchedView`).  REPRO_SIM_ENGINE overrides the default.
+#: :class:`SchedView`).
 #: "batch" is the device-resident batched engine
 #: (``repro.core.engine_batch``) — it is NEVER auto-picked: it must be
 #: requested explicitly (it jit-compiles whole-trial programs, which only
@@ -824,7 +823,6 @@ def simulate(
     processes: Optional[Sequence[Optional[ArrivalProcess]]] = None,
     budget_policy: Union["BudgetPolicy", str, None] = None,
     engine: Optional[str] = None,
-    round_kernel: Optional[str] = None,
     admission: Union["AdmissionPolicy", str, None] = None,
     faults: Union["FaultModel", str, None] = None,
 ) -> SimResult:
@@ -860,28 +858,14 @@ def simulate(
     faster, bit-identical — pinned by the differential tests),
     ``"reference"`` is the retained original event loop (the oracle),
     ``"auto"``/``None`` picks SoA whenever the scheduler is one of the
-    built-in classes it has a kernel for.  The REPRO_SIM_ENGINE
-    environment variable overrides ``None``/``"auto"`` (so a campaign —
-    whose TrialSpecs carry the default ``"auto"`` — can be forced onto
-    one engine without touching call sites); an explicit ``"soa"`` or
-    ``"reference"`` argument always wins.
-
-    ``round_kernel`` selects the SoA engine's Terastal round
-    implementation for deep ready queues — ``"python"`` (scalar and
-    vectorized kernels, depth-dispatched), ``"jax"`` (force the jitted
-    ``scheduler_jax.terastal_round``), or ``"auto"``/``None`` (python
-    below the calibrated crossover; see ``engine_soa.round_crossover``).
-    ``REPRO_ROUND_KERNEL`` overrides ``None``.  All choices are
-    bit-identical (pinned by the differential suites); the knob exists
-    for performance and for the differential tests themselves.  Ignored
-    by the reference engine.
+    built-in classes it has a kernel for.
     """
     from repro.core.admission import make_admission_policy
     from repro.core.budget_online import make_budget_policy
     from repro.core.faults import make_fault_model
 
-    if engine is None or engine == "auto":
-        engine = os.environ.get("REPRO_SIM_ENGINE") or "auto"
+    if engine is None:
+        engine = "auto"
     if engine not in SIM_ENGINES:
         raise ValueError(f"unknown engine {engine!r} (have {SIM_ENGINES})")
     fault_model = make_fault_model(faults)
@@ -928,8 +912,7 @@ def simulate(
         if supported:
             return engine_soa.simulate_soa(
                 plans, tasks, duration, scheduler, seed, processes, policy,
-                round_kernel=round_kernel, admission=adm,
-                fault_model=fault_model,
+                admission=adm, fault_model=fault_model,
             )
     return _simulate_reference(
         plans, tasks, duration, scheduler, seed, processes, policy, adm,

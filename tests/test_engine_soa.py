@@ -117,32 +117,6 @@ def test_engine_dispatch_and_fallback():
     assert set(SIM_ENGINES) == {"auto", "soa", "reference", "batch"}
 
 
-def test_env_var_selects_engine(monkeypatch):
-    plans, tasks = SCENARIOS["ar_social"].plans(PLATFORMS["4k_1ws2os"])
-    monkeypatch.setenv("REPRO_SIM_ENGINE", "reference")
-    ref = simulate(plans, tasks, 0.3, make_scheduler("fcfs"), seed=0)
-    monkeypatch.setenv("REPRO_SIM_ENGINE", "soa")
-    soa = simulate(plans, tasks, 0.3, make_scheduler("fcfs"), seed=0)
-    assert _fingerprint(ref) == _fingerprint(soa)
-    # the override also reaches campaign trials, whose TrialSpecs carry
-    # the explicit default "auto" (debugging escape hatch): with the env
-    # forcing the reference engine, the SoA engine must not be entered
-    calls = {"n": 0}
-    orig_soa = engine_soa.simulate_soa
-
-    def counting_soa(*a, **kw):
-        calls["n"] += 1
-        return orig_soa(*a, **kw)
-
-    monkeypatch.setattr(engine_soa, "simulate_soa", counting_soa)
-    monkeypatch.setenv("REPRO_SIM_ENGINE", "reference")
-    simulate(plans, tasks, 0.3, make_scheduler("fcfs"), seed=0, engine="auto")
-    assert calls["n"] == 0
-    # ... while an explicit engine argument beats the env var
-    simulate(plans, tasks, 0.3, make_scheduler("fcfs"), seed=0, engine="soa")
-    assert calls["n"] == 1
-
-
 # ------------------------- scheduler-invocation hot path (batching) ----
 
 

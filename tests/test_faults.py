@@ -237,18 +237,6 @@ def test_catalog_cells_bit_identical(name):
     assert ref.faulted_spans >= 1
 
 
-def test_soa_jax_round_kernel_downgrades_under_faults():
-    """An explicit round_kernel='jax' must silently fall back to the
-    scalar rounds when faults are active (capability events mutate the
-    latency tables mid-trial) and stay bit-identical."""
-    plans, tasks = _cell("multicam_heavy")
-    a = simulate(plans, tasks, 0.6, make_scheduler("terastal"), seed=0,
-                 faults=FAULT_GRID[0], engine="soa", round_kernel="jax")
-    b = simulate(plans, tasks, 0.6, make_scheduler("terastal"), seed=0,
-                 faults=FAULT_GRID[0], engine="reference")
-    assert a.fingerprint() == b.fingerprint()
-
-
 # ------------------------------------------------- fault observables ----
 
 

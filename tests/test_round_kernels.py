@@ -1,11 +1,13 @@
-"""Deep-queue round kernels: scalar / vectorized / jitted parity at the
-pow2 bucket boundaries, ready-block growth past the initial cap, and the
-round-kernel dispatch plumbing (env var, TrialSpec axis, crossover).
+"""Deep-queue round kernels: scalar / vectorized parity and the jitted
+round against the reference scheduler at the pow2 bucket boundaries,
+ready-block growth past the initial cap, and the SoA engine's depth
+dispatch between the scalar and vectorized kernels.
 
-The parity tests run on block states CAPTURED from real saturation
-trials (clones snapshotted mid-simulation at exact target depths), so
-the instances carry the true deep-queue structure — mixed layers,
-variants, partially busy accelerators — rather than synthetic rounds.
+The parity tests run on round states CAPTURED from real saturation
+trials (block clones snapshotted mid-simulation, or live scheduler
+views, at exact target depths), so the instances carry the true
+deep-queue structure — mixed layers, variants, partially busy
+accelerators — rather than synthetic rounds.
 """
 
 import os
@@ -15,9 +17,8 @@ import pytest
 
 from repro.core import make_scheduler, simulate
 from repro.core import engine_soa
-from repro.core.campaign import TrialSpec, run_trial
 from repro.core.engine_soa import _ReadyBlock
-from repro.core.workload import SATURATION_SCENARIOS, get_scenario
+from repro.core.workload import SATURATION_SCENARIOS
 from repro.costmodel.maestro import PLATFORMS
 
 #: either side of the pow2 shape buckets 16 and 64 (bucket_nj boundaries)
@@ -41,8 +42,8 @@ def _capture(mode: str, targets, per_target=3, duration=1.5):
         return orig(B, now, busy, idle_mask, n_idle, kmode)
 
     engine_soa._kern_terastal_vec = capture
-    old_env = os.environ.get("REPRO_ROUND_VEC_MIN")
-    os.environ["REPRO_ROUND_VEC_MIN"] = "2"
+    vec_min = engine_soa.VEC_MIN_NJ
+    engine_soa.VEC_MIN_NJ = 2
     try:
         for cell in ("saturation_5x", "saturation_3x"):
             if all(len(v) >= per_target for v in got.values()):
@@ -50,14 +51,57 @@ def _capture(mode: str, targets, per_target=3, duration=1.5):
             plans, tasks = SATURATION_SCENARIOS[cell].plans(PLATFORMS["4k_1ws2os"])
             simulate(plans, tasks, duration,
                      make_scheduler(f"terastal(backfill_mode={mode})"),
-                     seed=0, engine="soa", round_kernel="python")
+                     seed=0, engine="soa")
     finally:
         engine_soa._kern_terastal_vec = orig
-        if old_env is None:
-            del os.environ["REPRO_ROUND_VEC_MIN"]
-        else:
-            os.environ["REPRO_ROUND_VEC_MIN"] = old_env
+        engine_soa.VEC_MIN_NJ = vec_min
     return got
+
+
+class _Captured(Exception):
+    """Ends a capture run once every target depth has its instances."""
+
+
+def _check_views(mode: str, targets, per_target, check, duration=1.5):
+    """Run saturation trials on the reference engine with the given
+    backfill mode and hand each live scheduler view at a target depth to
+    ``check(nj, view, scheduler, schedule)`` before the round is applied
+    (views are live: the engine mutates their requests afterwards).  For
+    a mode other than "ef", ``per_target`` more views are checked, at any
+    depth, where that mode's stage 2 decides differently from "ef"'s —
+    few rounds at the boundary depths do."""
+    seen = dict.fromkeys(targets, 0)
+    if mode != "ef":
+        seen["contrast"] = 0
+        ef = make_scheduler("terastal")
+
+    def key(assignments):
+        return [(a.req.rid, a.acc, a.use_variant) for a in assignments]
+
+    for cell in ("saturation_5x", "saturation_3x"):
+        sched = make_scheduler(f"terastal(backfill_mode={mode})")
+        orig = sched.schedule
+
+        def hook(view):
+            nj = len(view.ready)
+            at = nj if seen.get(nj, per_target) < per_target else None
+            if (at is None and seen.get("contrast", per_target) < per_target
+                    and key(orig(view)) != key(ef.schedule(view))):
+                at = "contrast"
+            if at is not None:
+                check(nj, view, sched, orig)
+                seen[at] += 1
+                if min(seen.values()) >= per_target:
+                    raise _Captured
+            return orig(view)
+
+        sched.schedule = hook
+        plans, tasks = SATURATION_SCENARIOS[cell].plans(PLATFORMS["4k_1ws2os"])
+        try:
+            simulate(plans, tasks, duration, sched, seed=0, engine="reference")
+        except _Captured:
+            break
+    return seen
 
 
 @pytest.mark.parametrize("mode", ["ef", "paper", "positive"])
@@ -78,25 +122,38 @@ def test_vec_kernel_parity_at_bucket_boundaries(mode):
 
 
 @pytest.mark.parametrize("soft", [False, True], ids=["native", "soft"])
-@pytest.mark.parametrize("mode", ["ef", "paper"])
+@pytest.mark.parametrize("mode", ["ef", "paper", "positive"])
 def test_jax_round_parity_at_bucket_boundaries(mode, soft, monkeypatch):
-    """The jitted round (through the engine's staging path) matches the
-    scalar kernel on the same captured states — including the emission
-    order reconstructed from assign_seq, which fixes finish-event
-    tie-breaking downstream.  f64 end to end: the latency tables here
-    are arbitrary floats, not the dyadic grid of the property test.
+    """The jitted round, staged by ``pack_view``, matches
+    ``TerastalScheduler.schedule`` on live views captured from the
+    reference engine at every boundary depth: same requests,
+    accelerators, variant flags and costs, in the same emission order
+    (reconstructed from assign_seq).  f64 end to end: the latency tables
+    here are arbitrary floats, not the dyadic grid of the property test.
     ``soft`` runs the round in the software binary64 the TPU uses."""
     from repro.core import f64
+    from repro.core.scheduler_jax import pack_view, terastal_round
 
     if soft:
         monkeypatch.setattr(f64, "for_platform", lambda platform=None: f64.SOFT)
-    targets = (15, 16, 17) if mode == "paper" else BOUNDARY_NJ
-    states = _capture(mode, targets, per_target=2)
-    for nj, instances in states.items():
-        for B, now, busy, idle_mask, n_idle, kmode in instances:
-            ref = engine_soa._kern_terastal(B, now, busy, idle_mask, n_idle, kmode)
-            jx = engine_soa._jax_round(B, now, busy, idle_mask, len(busy), kmode)
-            assert jx == ref, (mode, nj)
+
+    def check(nj, view, sched, schedule):
+        inp, reqs = pack_view(view, sched)
+        out = terastal_round(inp, mode=sched.backfill_mode)
+        acc, var, seq = (np.asarray(a)[:nj] for a in
+                         (out.assign_acc, out.assign_var, out.assign_seq))
+        hit = np.flatnonzero(acc >= 0)
+        jx = []
+        for i in hit[np.argsort(seq[hit])]:
+            r, k, uv = reqs[i], int(acc[i]), bool(var[i])
+            lat = view.plans[r.model_idx].lat_var if uv else view.plans[r.model_idx].lat
+            jx.append((r.rid, k, uv, float(lat[r.next_layer, k])))
+        ref = [(a.req.rid, a.acc, a.use_variant, a.est_latency)
+               for a in schedule(view)]
+        assert jx == ref, (mode, nj)
+
+    seen = _check_views(mode, BOUNDARY_NJ, 2, check)
+    assert min(seen.values()) >= 2, seen
 
 
 # ------------------------------------------------------------ block grow ----
@@ -178,81 +235,38 @@ def test_saturation_trial_exercises_growth_and_stays_bit_identical():
 # --------------------------------------------------------------- dispatch ----
 
 
-def test_round_kernel_env_and_arg_validation(monkeypatch):
-    plans, tasks = get_scenario("ar_social").plans(PLATFORMS["4k_1ws2os"])
-    with pytest.raises(ValueError, match="unknown round kernel"):
-        simulate(plans, tasks, 0.2, make_scheduler("terastal"), seed=0,
-                 engine="soa", round_kernel="cuda")
-    monkeypatch.setenv("REPRO_ROUND_KERNEL", "nope")
-    with pytest.raises(ValueError, match="unknown round kernel"):
-        simulate(plans, tasks, 0.2, make_scheduler("terastal"), seed=0,
-                 engine="soa")
-    # explicit argument beats the env var
-    monkeypatch.setenv("REPRO_ROUND_KERNEL", "python")
-    res = simulate(plans, tasks, 0.2, make_scheduler("terastal"), seed=0,
-                   engine="soa", round_kernel="python")
-    assert res.rounds is not None
-
-
-def test_round_kernel_env_reaches_auto_trials(monkeypatch):
-    """TrialSpecs carry the explicit default "auto", so the env var must
-    apply THROUGH it (the REPRO_SIM_ENGINE precedent) — forcing jax
-    process-wide has to reach campaign trials, not only direct callers."""
-    plans, tasks = SATURATION_SCENARIOS["saturation_3x"].plans(
+@pytest.mark.parametrize("vec_min", [2, 10**9], ids=["vec", "scalar"])
+def test_soa_depth_dispatch_is_bit_identical(vec_min, monkeypatch):
+    """The SoA engine picks its Terastal round from the observed depth
+    alone: at or above ``VEC_MIN_NJ`` the vectorized kernel, below it the
+    scalar one.  Either way a saturation trial matches the reference
+    engine's fingerprint."""
+    plans, tasks = SATURATION_SCENARIOS["saturation_5x"].plans(
         PLATFORMS["4k_1ws2os"])
     calls = {"n": 0}
-    orig = engine_soa._jax_round
+    orig = engine_soa._kern_terastal_vec
 
-    def counting(*a, **kw):
+    def counting(*a):
         calls["n"] += 1
-        return orig(*a, **kw)
+        return orig(*a)
 
-    monkeypatch.setattr(engine_soa, "_jax_round", counting)
-    monkeypatch.setenv("REPRO_ROUND_KERNEL", "jax")
-    simulate(plans, tasks, 0.1, make_scheduler("terastal"), seed=0,
-             engine="soa", round_kernel="auto")
-    assert calls["n"] > 0  # env reached the "auto" trial
-    # ... but an explicit python argument still beats the env var
-    calls["n"] = 0
-    simulate(plans, tasks, 0.1, make_scheduler("terastal"), seed=0,
-             engine="soa", round_kernel="python")
-    assert calls["n"] == 0
-
-
-def test_round_kernel_axis_threads_through_campaign():
-    """TrialSpec.round_kernel reaches the engine and never changes any
-    result — the axis is a perf knob with bit-identical outputs."""
-    base = TrialSpec("saturation_3x", "4k_1ws2os", "terastal", duration=0.5)
-    auto = run_trial(base)
-    python = run_trial(TrialSpec("saturation_3x", "4k_1ws2os", "terastal",
-                                 duration=0.5, round_kernel="python"))
-    assert auto.rounds > 0  # SimResult.rounds telemetry flows through
-    assert (auto.mean_miss_rate, auto.released, auto.completed, auto.dropped,
-            auto.utilization, auto.rounds) == \
-           (python.mean_miss_rate, python.released, python.completed,
-            python.dropped, python.utilization, python.rounds)
+    monkeypatch.setattr(engine_soa, "_kern_terastal_vec", counting)
+    monkeypatch.setattr(engine_soa, "VEC_MIN_NJ", vec_min)
+    soa = simulate(plans, tasks, 0.3, make_scheduler("terastal"), seed=0,
+                   engine="soa")
+    ref = simulate(plans, tasks, 0.3, make_scheduler("terastal"), seed=0,
+                   engine="reference")
+    assert soa.fingerprint() == ref.fingerprint()
+    if vec_min == 2:
+        assert calls["n"] > 0
+    else:
+        assert calls["n"] == 0
 
 
-def test_round_crossover_resolution(monkeypatch):
-    monkeypatch.delenv("REPRO_ROUND_CROSSOVER", raising=False)
-    engine_soa.set_round_crossover(None)
-    assert engine_soa.round_crossover() == float("inf")  # honest default
-    engine_soa.set_round_crossover(128)
-    assert engine_soa.round_crossover() == 128.0
-    monkeypatch.setenv("REPRO_ROUND_CROSSOVER", "96")
-    assert engine_soa.round_crossover() == 96.0  # env wins
-    monkeypatch.setenv("REPRO_ROUND_CROSSOVER", "inf")
-    assert engine_soa.round_crossover() == float("inf")
-    engine_soa.set_round_crossover(None)
-
-
-def test_auto_inf_crossover_is_python():
-    """REPRO_ROUND_CROSSOVER=inf + round_kernel="auto" takes the
-    dead-weight fast path: the trial is bit-identical to an explicit
-    "python" kernel AND the jax machinery is never imported — the whole
-    point of the fast path is that auto costs nothing when the measured
-    crossover says jax never wins.  Subprocess, so the import-set
-    assertion sees a clean module table."""
+def test_soa_trial_never_imports_scheduler_jax():
+    """A SoA trial runs on the Python kernels alone: the jax machinery is
+    never imported, and the trial matches the reference engine.
+    Subprocess, so the import-set assertion sees a clean module table."""
     import subprocess
     import sys
 
@@ -264,20 +278,17 @@ def test_auto_inf_crossover_is_python():
         "from repro.costmodel.maestro import PLATFORMS\n"
         "plans, tasks = SATURATION_SCENARIOS['saturation_3x'].plans("
         "PLATFORMS['4k_1ws2os'])\n"
-        "auto = simulate(plans, tasks, 0.3, make_scheduler('terastal'),"
-        " seed=0, engine='soa', round_kernel='auto')\n"
+        "soa = simulate(plans, tasks, 0.3, make_scheduler('terastal'),"
+        " seed=0, engine='soa')\n"
         "assert 'repro.core.scheduler_jax' not in sys.modules, "
-        "'auto imported the jax machinery despite crossover=inf'\n"
+        "'a SoA trial imported the jax machinery'\n"
         "assert 'jax' not in sys.modules\n"
-        "py = simulate(plans, tasks, 0.3, make_scheduler('terastal'),"
-        " seed=0, engine='soa', round_kernel='python')\n"
-        "assert auto.fingerprint() == py.fingerprint()\n"
+        "ref = simulate(plans, tasks, 0.3, make_scheduler('terastal'),"
+        " seed=0, engine='reference')\n"
+        "assert soa.fingerprint() == ref.fingerprint()\n"
         "print('OK')\n"
     )
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(root, "src"),
-               REPRO_ROUND_CROSSOVER="inf")
-    env.pop("REPRO_ROUND_KERNEL", None)
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr

@@ -183,28 +183,20 @@ def test_journal_refuses_foreign_campaign(tmp_path):
         run_adaptive(_camp(seeds=(0, 1)), parallel=False, journal=path)
 
 
-def test_journal_written_before_new_default_field_resumes(tmp_path, monkeypatch):
-    """A journal from before a default-valued Campaign/TrialSpec field
-    existed (e.g. ``round_kernel``) must still resume: the header is
-    re-serialized through the current dataclasses, which fill the
-    defaults.  Genuinely different campaigns keep being refused."""
-    from repro.core import campaign as campaign_mod
-
-    path = str(tmp_path / "journal.jsonl")
-    camp = _camp(seeds=(0, 1))
-    first = run_adaptive(camp, parallel=False, journal=path)
-
-    # age the journal: strip the new field from the header and every
-    # recorded trial spec, exactly what a pre-PR5 writer produced
+def _rewrite_journal(path, edit):
+    """Apply ``edit(obj, is_header)`` to every line of a journal."""
     with open(path) as f:
         lines = [json.loads(line) for line in f.read().splitlines()]
-    del lines[0]["campaign"]["round_kernel"]
-    for rec in lines[1:]:
-        del rec["spec"]["round_kernel"]
-        del rec["result"]["rounds"]
+    for n, obj in enumerate(lines):
+        edit(obj, n == 0)
     with open(path, "w") as f:
         for obj in lines:
             f.write(json.dumps(obj) + "\n")
+
+
+def _counting_run_trial(monkeypatch):
+    from repro.core import campaign as campaign_mod
+    import repro.core.sampling as sampling_mod
 
     calls = {"n": 0}
     orig = campaign_mod.run_trial
@@ -214,13 +206,61 @@ def test_journal_written_before_new_default_field_resumes(tmp_path, monkeypatch)
         return orig(spec)
 
     monkeypatch.setattr(campaign_mod, "run_trial", counting)
-    import repro.core.sampling as sampling_mod
     monkeypatch.setattr(sampling_mod, "run_trial", counting, raising=False)
+    return calls
+
+
+def test_journal_written_before_new_default_field_resumes(tmp_path, monkeypatch):
+    """A journal from before a default-valued Campaign/TrialSpec field
+    existed (e.g. ``faults``) must still resume: the header is
+    re-serialized through the current dataclasses, which fill the
+    defaults.  Genuinely different campaigns keep being refused."""
+    path = str(tmp_path / "journal.jsonl")
+    camp = _camp(seeds=(0, 1))
+    first = run_adaptive(camp, parallel=False, journal=path)
+
+    # age the journal: strip the newer fields from the header and every
+    # recorded trial, exactly what a writer from before them produced
+    def age(obj, is_header):
+        if is_header:
+            del obj["campaign"]["faults"]
+        else:
+            del obj["spec"]["faults"]
+            del obj["result"]["rounds"]
+
+    _rewrite_journal(path, age)
+    calls = _counting_run_trial(monkeypatch)
     resumed = run_adaptive(camp, parallel=False, journal=path)
     assert calls["n"] == 0  # fully replayed from the aged journal
     assert [_cell_of(t.spec) for t in resumed.trials] == \
            [_cell_of(t.spec) for t in first.trials]
     # verdicts are a pure function of replayed results: identical
+    assert [dataclasses.asdict(v) for v in resumed.verdicts] == \
+           [dataclasses.asdict(v) for v in first.verdicts]
+
+
+@pytest.mark.parametrize("key", ["round_kernel", "warp_factor"])
+def test_journal_with_extra_field_resumes_only_if_retired(tmp_path, monkeypatch, key):
+    """Journals written while ``round_kernel`` existed store it in the
+    header's campaign and in every trial spec: such a journal resumes
+    with no trial re-run and the uninterrupted run's results.  Any other
+    unknown key is still a different campaign, and is refused."""
+    path = str(tmp_path / "journal.jsonl")
+    camp = _camp(seeds=(0, 1))
+    first = run_adaptive(camp, parallel=False, journal=path)
+
+    def add(obj, is_header):
+        (obj["campaign"] if is_header else obj["spec"])[key] = "python"
+
+    _rewrite_journal(path, add)
+    calls = _counting_run_trial(monkeypatch)
+    if key != "round_kernel":
+        with pytest.raises(ValueError, match="different campaign"):
+            run_adaptive(camp, parallel=False, journal=path)
+        return
+    resumed = run_adaptive(camp, parallel=False, journal=path)
+    assert calls["n"] == 0
+    assert resumed.trials == first.trials
     assert [dataclasses.asdict(v) for v in resumed.verdicts] == \
            [dataclasses.asdict(v) for v in first.verdicts]
 
